@@ -39,6 +39,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
+from repro.compile_cache import use_compile_cache
 from repro.search.runner import CellSpec, run_cell
 
 # One lane per seed: same scenario shape, different arrival realization —
@@ -135,6 +136,7 @@ def main(argv=None) -> dict:
         ap.error(f"--lanes must name at least one lane count "
                  f"(got {args.lanes!r})")
 
+    use_compile_cache()
     report = bench_manyworld(lane_counts, serial_cells=args.serial_cells)
     report["generated_unix_s"] = int(time.time())
     # Merge, don't overwrite: the entry lives alongside the sched-

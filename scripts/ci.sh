@@ -248,9 +248,8 @@ print(f"predictive gate OK: mean pending {pred['mean_pending_s']}s vs "
 EOF
 
 echo "== many-world lane gates (parity smoke + speedup + regression) =="
-# The lane evaluator's end-to-end gates.  All of them need JAX — without
-# it `workers="lanes"` falls back to serial `run_cell` (covered by
-# tier-1), so the perf comparison would be measuring nothing.
+# The lane evaluator's end-to-end gates.  All of them need JAX, which
+# the lane path requires.
 if ! python -c "import jax" >/dev/null 2>&1; then
     echo "many-world gates skipped (JAX not importable)"
 else

@@ -82,8 +82,11 @@ def main(argv=None) -> dict:
 
     import numpy as np
 
+    from repro.compile_cache import use_compile_cache
     from repro.forecast import Ar1Baseline, WindowConfig, make_dataset
     from repro.forecast import model as fmodel
+
+    use_compile_cache()
 
     if args.smoke:
         families = tuple((args.families or ",".join(SMOKE_FAMILIES))

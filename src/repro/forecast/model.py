@@ -75,6 +75,22 @@ def _batches(rng: np.random.Generator, n: int, batch: int, steps: int):
         yield rng.integers(0, n, size=batch)
 
 
+def make_train_step(arch: ArchConfig, opt_cfg: OptimizerConfig):
+    """The jitted AdamW step on log-space squared error:
+    ``(params, opt_state, xb, yb) -> (params, opt_state, loss)``."""
+    def loss_fn(p, xb, yb):
+        pred = apply_forecast(p, xb, arch)
+        return jnp.mean((pred - yb) ** 2)
+
+    @jax.jit
+    def step(p, s, xb, yb):
+        loss, grads = jax.value_and_grad(loss_fn)(p, xb, yb)
+        p, s, _ = adamw_update(opt_cfg, p, grads, s)
+        return p, s, loss
+
+    return step
+
+
 def train_forecaster(X: np.ndarray, y: np.ndarray, *,
                      window: WindowConfig,
                      X_val: Optional[np.ndarray] = None,
@@ -95,17 +111,7 @@ def train_forecaster(X: np.ndarray, y: np.ndarray, *,
                               warmup_steps=max(1, steps // 10),
                               total_steps=steps, weight_decay=0.0)
     opt_state = init_opt_state(params)
-
-    def loss_fn(p, xb, yb):
-        pred = apply_forecast(p, xb, arch)
-        return jnp.mean((pred - yb) ** 2)
-
-    @jax.jit
-    def step(p, s, xb, yb):
-        loss, grads = jax.value_and_grad(loss_fn)(p, xb, yb)
-        p, s, _ = adamw_update(opt_cfg, p, grads, s)
-        return p, s, loss
-
+    step = make_train_step(arch, opt_cfg)
     Xl = np.log1p(np.asarray(X, np.float32))
     yl = np.log1p(np.asarray(y, np.float32))
     rng = np.random.default_rng(seed)

@@ -19,11 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept either
-# so the kernels load on both sides of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 DEFAULT_CHUNK = 64
 NEG_BIG = -1e30
 
@@ -111,7 +106,7 @@ def mlstm_chunkwise(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((dk,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, i_raw, f_raw)

@@ -23,11 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept either
-# so the kernels load on both sides of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 DEFAULT_BLOCK_R = 512
 DEFAULT_CHUNK_T = 256
 
@@ -72,7 +67,7 @@ def rglru_scan(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((1, ct, br), lambda bb, rr, tt: (bb, tt, rr)),
         out_shape=jax.ShapeDtypeStruct((B, T, R), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, br), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -2,11 +2,10 @@
 
 An explicitly-flagged fast path that runs thousands of void/void
 static-cluster experiment *lanes* as one jit-compiled program — see
-`repro.manyworld.lanes` for the engine and its relaxed-semantics
-contract, `repro.manyworld.select` for the masked-extremum select
-kernels (jnp / Pallas), and `repro.manyworld.evaluator` for the
-``run_cells(..., workers="lanes")`` backend that reconstructs serial
-bit-identical result rows.  Importing this package does **not** import
+`repro.manyworld.lanes` for the engine, its relaxed-semantics contract
+and its integer IEEE-754 float discipline, and
+`repro.manyworld.evaluator` for the ``run_cells(..., workers="lanes")``
+backend that reconstructs serial bit-identical result rows.  Importing this package does **not** import
 JAX; the engine modules import it lazily on first use.
 """
 from repro.manyworld.lanes import (LaneBatch, next_pow2, run_lane_batch,

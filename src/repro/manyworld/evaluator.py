@@ -10,8 +10,7 @@ every *lane-eligible* cell inside batched JAX programs
 void/void static-cluster regime (:func:`lane_eligible`).  Anything
 outside it (autoscalers, reschedulers, chaos, the object engine) falls
 back to the serial ``run_cell`` transparently, so a mixed cell list still
-returns one complete row list.  If JAX is unavailable the whole list
-falls back serially with a warning.
+returns one complete row list.
 
 **Exactness.**  For eligible cells the rows are bit-identical to
 ``run_cell`` (except ``wall_s``, which is wall time and is reported as
@@ -41,7 +40,6 @@ import dataclasses
 import math
 import statistics
 import time
-import warnings
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -56,8 +54,10 @@ SAMPLE_PERIOD_S = 20.0
 def lane_eligible(cell) -> bool:
     """True when ``cell`` is inside the lane engine's relaxed envelope:
     a void/void static cluster (no autoscaler, no rescheduler, no chaos)
-    on the array engine with a supported scheduler.  Weight validation is
-    left to the serial path so invalid specs raise the serial error."""
+    on the array engine with a lane scheduler (``lanes.SCHEDULERS``:
+    k8s-default and weighted run on the serial reference on every
+    backend).  A weighted spec stays serial, so an invalid one raises the
+    serial error."""
     if cell.autoscaler != "void" or cell.rescheduler != "void":
         return False
     if cell.chaos:
@@ -68,13 +68,7 @@ def lane_eligible(cell) -> bool:
         return False
     if cell.initial_workers < 1:
         return False
-    w = cell.scheduler_weights
-    if w is not None:
-        if cell.scheduler != "weighted" or len(w) != 3:
-            return False          # serial raises; keep that behavior
-        if not (sum(w) > 0.0) or min(w) < 0.0:
-            return False
-    return True
+    return cell.scheduler_weights is None
 
 
 def _template_of(cell):
@@ -303,27 +297,18 @@ class _EmptyTrace:
     mem_mb = np.zeros(0)
 
 
-def run_cells_lanes(cells: Sequence, backend: Optional[str] = None,
-                    ) -> List[dict]:
+def run_cells_lanes(cells: Sequence) -> List[dict]:
     """Evaluate ``cells`` with the lane engine; serial-identical rows in
     submission order.  Ineligible cells run through the serial
-    ``run_cell`` unchanged; if JAX is missing everything does."""
-    from repro.search.runner import (_RESULT_FIELDS, CellError, _get_trace,
-                                     _infeasible, run_cell)
+    ``run_cell`` unchanged."""
+    from repro.search.runner import (CellError, _get_trace, _infeasible,
+                                     run_cell)
     cells = list(cells)
-    try:
-        import jax  # noqa: F401
-        have_jax = True
-    except Exception:             # pragma: no cover - env without jax
-        have_jax = False
-        warnings.warn("repro.manyworld: JAX unavailable; workers='lanes' "
-                      "falling back to the serial cell runner")
-
     rows: List[Optional[dict]] = [None] * len(cells)
     buckets = {}                  # (sched, p_pad, n_pad) -> [(idx, lane)]
     for idx, cell in enumerate(cells):
         try:
-            if not (have_jax and lane_eligible(cell)):
+            if not lane_eligible(cell):
                 rows[idx] = run_cell(cell)
                 continue
             trace = _get_trace(cell.scenario, cell.seed, cell.n_jobs)
@@ -340,9 +325,8 @@ def run_cells_lanes(cells: Sequence, backend: Optional[str] = None,
                 continue
             lane = trace.to_lane_arrays()
             lane["n_nodes"] = cell.initial_workers
-            lane["alloc_cpu"] = float(template.allocatable.cpu_m)
+            lane["alloc_cpu"] = template.allocatable.cpu_m
             lane["alloc_mem"] = float(template.allocatable.mem_mb)
-            lane["weights"] = cell.scheduler_weights
             key = (cell.scheduler, next_pow2(trace.n),
                    next_pow2(cell.initial_workers))
             buckets.setdefault(key, []).append((idx, cell, trace, template,
@@ -356,7 +340,7 @@ def run_cells_lanes(cells: Sequence, backend: Optional[str] = None,
         t0 = time.perf_counter()
         batch = _lanes.stack_lanes([e[4] for e in entries], sched,
                                    p_pad=p_pad)
-        out = _lanes.run_lane_batch(batch, backend=backend)
+        out = _lanes.run_lane_batch(batch)
         share = (time.perf_counter() - t0) / len(entries)
         for li, (idx, cell, trace, template, _lane) in enumerate(entries):
             o = {key: val[li] for key, val in out.items()

@@ -14,8 +14,8 @@ the cycle hot path of the serial engine lowered to fixed shapes:
   (summation order matters; a segment-sum would not);
 * a bind inner loop walks the pending snapshot in FIFO (row) order, one
   pod per lane per step: feasibility mask, scheduler score, first-extremum
-  select (``repro.manyworld.select``; Pallas kernel or jnp backend), then
-  the serial accounting ops ``used += req`` / ``free = alloc - used``.
+  select (:func:`masked_argmin`), then the serial accounting ops
+  ``used += req`` / ``free = alloc - used``.
 
 **Relaxed-semantics envelope.**  Lanes model the void/void static-cluster
 regime only: no autoscaler, no rescheduler, no chaos, homogeneous READY
@@ -26,15 +26,19 @@ serial engine exactly; ``repro.manyworld.evaluator`` reconstructs full
 ARCHITECTURE.md "Many-world lanes" for the contract and the enumerated
 divergences.
 
-**Float discipline.**  All arithmetic the serial engine does in float64
-is done in float64 (``jax.experimental.enable_x64``).  Integer request
-columns become float64 — exact below 2^53, so comparisons and the k8s
-fraction divides are bit-identical.  XLA's CPU backend contracts
-``a*b + c`` into a fused multiply-add, which would change score bits
-vs NumPy; every product feeding an add goes through :func:`_fence`
-(a data-dependent ``where`` LLVM cannot contract across).  Masked
-scatter updates add ``±0.0`` on inactive lanes, which is a bitwise
-no-op because the engine's ``used`` values are never ``-0.0``.
+**Float discipline.**  The serial engine's float64 values — arrival and
+completion times, memory requests and the per-node ``used_mem`` running
+sums — enter the program as their IEEE-754 bit patterns in int64, and
+the program computes on the patterns: :func:`f64_add` is IEEE-754
+binary64 addition (round to nearest, ties to even) in integer ops, and
+comparisons are integer comparisons of :func:`_order_key`.  No float64
+operation runs on the device.  XLA:TPU emulates float64 with pairs of
+float32, which neither holds nor adds a float64 as IEEE-754 does, and the
+lane rows then drift from the serial rows (ROADMAP item 1.3).  CPU
+requests are whole milli-cores and stay integers.  Policies whose scores
+need float multiply and divide (k8s-default, weighted) are outside the
+lane envelope.  The program needs ``jax.enable_x64`` for its int64
+columns only.
 """
 from __future__ import annotations
 
@@ -44,16 +48,34 @@ from typing import Optional
 
 import numpy as np
 
-from repro.manyworld import select as _select
-
 CYCLE_PERIOD_S = 10.0
 HORIZON_S = 48 * 3600.0          # SimConfig.max_sim_time_s default
 MAX_CYCLES = int(HORIZON_S / CYCLE_PERIOD_S)   # cycle at t == horizon runs
 
-SCHEDULERS = ("best-fit", "worst-fit", "first-fit", "k8s-default", "weighted")
+SCHEDULERS = ("best-fit", "worst-fit", "first-fit")
 
 # bind_seq fill for "no completion candidate" (any value > every real seq).
 _SEQ_INF = np.int32(2**31 - 1)
+
+# IEEE-754 binary64 fields of an int64 bit pattern.
+_SIGN = np.int64(-2**63)
+_MAG = np.int64(2**63 - 1)
+_FRAC = np.int64(2**52 - 1)
+_IMPL = np.int64(2**52)
+_KEY_MAX = np.int64(2**63 - 1)          # masked_argmin fill: above every key
+
+
+def f64_bits(x) -> np.ndarray:
+    """float64 values -> their IEEE-754 bit patterns as int64."""
+    return np.ascontiguousarray(x, np.float64).view(np.int64)
+
+
+_INF_BITS = f64_bits(np.inf)[()]
+_EPS_BITS = f64_bits(1e-9)[()]           # serial fits slack on memory
+_HORIZON_BITS = f64_bits(HORIZON_S)[()]
+# Cycle start times k * 10.0, k = 0 .. MAX_CYCLES, as the serial clock
+# computes them.
+_T_BITS = f64_bits(np.arange(MAX_CYCLES + 1) * CYCLE_PERIOD_S)
 
 
 def next_pow2(n: int) -> int:
@@ -76,15 +98,14 @@ class LaneBatch:
 
     scheduler: str
     arrival_t: np.ndarray     # (L, P) f64, +inf padded
-    cpu_m: np.ndarray         # (L, P) f64
+    cpu_m: np.ndarray         # (L, P) i64
     mem_mb: np.ndarray        # (L, P) f64
     duration_s: np.ndarray    # (L, P) f64
     is_batch: np.ndarray      # (L, P) bool
     valid: np.ndarray         # (L, P) bool
     n_nodes: np.ndarray       # (L,)  i32
-    alloc_cpu: np.ndarray     # (L,)  f64
+    alloc_cpu: np.ndarray     # (L,)  i64
     alloc_mem: np.ndarray     # (L,)  f64
-    weights: np.ndarray       # (L, 3) f64 (weighted scheduler; else pack)
 
     @property
     def n_lanes(self) -> int:
@@ -102,8 +123,9 @@ class LaneBatch:
 def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None
                 ) -> LaneBatch:
     """Stack per-lane dicts (``TraceStore.to_lane_arrays`` output plus
-    cluster scalars ``n_nodes`` / ``alloc_cpu`` / ``alloc_mem`` and an
-    optional ``weights`` 3-tuple) into one padded :class:`LaneBatch`."""
+    cluster scalars ``n_nodes`` / ``alloc_cpu`` / ``alloc_mem``) into one
+    padded :class:`LaneBatch`.  CPU requests and allocatable must be whole
+    milli-cores, as ``Resources.cpu_m`` is."""
     if scheduler not in SCHEDULERS:
         raise ValueError(f"unsupported lane scheduler {scheduler!r}")
     n_max = max((int(d["arrival_t"].size) for d in lanes), default=0)
@@ -112,19 +134,22 @@ def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None
         raise ValueError(f"p_pad={P} < largest lane ({n_max} pods)")
     L = len(lanes)
     arr = np.full((L, P), np.inf)
-    cpu = np.zeros((L, P))
+    cpu = np.zeros((L, P), np.int64)
     mem = np.zeros((L, P))
     dur = np.zeros((L, P))
     isb = np.zeros((L, P), bool)
     val = np.zeros((L, P), bool)
     n_nodes = np.zeros(L, np.int32)
-    a_cpu = np.zeros(L)
+    a_cpu = np.zeros(L, np.int64)
     a_mem = np.zeros(L)
-    wts = np.zeros((L, 3))
     for i, d in enumerate(lanes):
         n = int(d["arrival_t"].size)
+        c = np.append(np.asarray(d["cpu_m"], np.float64), d["alloc_cpu"])
+        if not np.array_equal(c, np.floor(c)):
+            raise ValueError("lane CPU requests and allocatable must be "
+                             "whole milli-cores")
         arr[i, :n] = d["arrival_t"]
-        cpu[i, :n] = d["cpu_m"]
+        cpu[i, :n] = c[:n]
         mem[i, :n] = d["mem_mb"]
         dur[i, :n] = d["duration_s"]
         isb[i, :n] = d["is_batch"]
@@ -132,70 +157,105 @@ def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None
         n_nodes[i] = d["n_nodes"]
         a_cpu[i] = d["alloc_cpu"]
         a_mem[i] = d["alloc_mem"]
-        w = d.get("weights")
-        wts[i] = (1.0, 0.0, 0.0) if w is None else tuple(w)
     return LaneBatch(scheduler, arr, cpu, mem, dur, isb, val,
-                     n_nodes, a_cpu, a_mem, wts)
+                     n_nodes, a_cpu, a_mem)
 
 
-def _fence(t):
-    """Contraction fence: route a product through a data-dependent select
-    so LLVM cannot fuse it into a following add (``a*b + c -> fma`` would
-    change score bits vs the serial NumPy path).  ``isfinite`` is always
-    True for real scores, so the value is unchanged."""
-    import jax.numpy as jnp
-    return jnp.where(jnp.isfinite(t), t, jnp.inf)
+def f64_add(a, b):
+    """IEEE-754 binary64 ``a + b`` on int64 bit patterns, in integer ops.
 
-
-def _wave_scores(sched: str, free_cpu, free_mem, alloc_cpu, alloc_mem,
-                 pc, pm, weights):
-    """Per-node scores for one pod per lane, **negated for max-mode** so a
-    single masked-argmin select serves every policy.  Formulas are the
-    serial ``Scheduler.wave_scores`` ops verbatim (same order, float64);
-    ``pc``/``pm`` are the pod's request broadcast to ``(L, 1)``.
+    Round to nearest, ties to even; exact for every pair of finite
+    inputs, subnormals and signed zeros included.  Infinities and NaNs are
+    outside its domain: the lane program never adds one, and none of its
+    sums overflows.  Significands carry three guard bits (guard, round,
+    sticky), which is enough for a correctly rounded sum.
     """
     import jax.numpy as jnp
+    from jax import lax
+    swap = (b & _MAG) > (a & _MAG)
+    x = jnp.where(swap, b, a)                       # larger magnitude
+    y = jnp.where(swap, a, b)
+    sx, sy = x < 0, y < 0
+    ex = (x >> 52) & 0x7FF
+    ey = (y >> 52) & 0x7FF
+    # A subnormal has no implicit bit and the scale of exponent 1.
+    mx = jnp.where(ex > 0, (x & _FRAC) | _IMPL, x & _FRAC) << 3
+    my = jnp.where(ey > 0, (y & _FRAC) | _IMPL, y & _FRAC) << 3
+    ex = jnp.maximum(ex, 1)
+    d = jnp.minimum(ex - jnp.maximum(ey, 1), 63)
+    my_al = my >> d
+    my_al = my_al | ((my_al << d) != my).astype(my.dtype)    # sticky
+    m = jnp.where(sx == sy, mx + my_al, mx - my_al)
+    # Carry out of the top: one step right, keeping the sticky bit.
+    carry = m >= (1 << 56)
+    m = jnp.where(carry, (m >> 1) | (m & 1), m)
+    e = jnp.where(carry, ex + 1, ex)
+    # Cancellation: left until the leading bit is bit 55, but no further
+    # than the subnormal scale.
+    sh = jnp.clip(lax.clz(m) - 8, 0, e - 1)
+    m = m << sh
+    e = e - sh
+    g = m & 7
+    m = m >> 3
+    m = m + ((g > 4) | ((g == 4) & ((m & 1) == 1))).astype(m.dtype)
+    ovf = m >= (1 << 53)
+    m = jnp.where(ovf, m >> 1, m)
+    e = jnp.where(ovf, e + 1, e)
+    bits = (jnp.where(m >= _IMPL, e, 0) << 52) | (m & _FRAC)
+    # An exact zero is +0, except (-0) + (-0).
+    neg = jnp.where(m == 0, sx & sy, sx)
+    return jnp.where(neg, bits | _SIGN, bits)
+
+
+def _order_key(bits):
+    """int64 key that orders float64 bit patterns as their values order
+    (``+0`` and ``-0`` share the key 0).  Non-negative patterns are their
+    own keys."""
+    import jax.numpy as jnp
+    return jnp.where(bits < 0, -(bits & _MAG), bits)
+
+
+def masked_argmin(keys, mask):
+    """First index of the masked minimum, per lane.
+
+    ``keys`` is ``(L, N)`` int64, ``mask`` ``(L, N)`` bool; returns
+    ``(L,)`` int32.  Infeasible nodes are filled with the largest key and
+    ``jnp.argmin`` breaks ties to the first occurrence, like the serial
+    NumPy ``argmin`` over its ``+inf``-filled buffer.  Rows whose mask is
+    all-False return an arbitrary index: callers gate on
+    ``mask.any(axis=1)``, as the serial path gates on ``buf[i] == fill``.
+    """
+    import jax.numpy as jnp
+    buf = jnp.where(mask, keys, _KEY_MAX)
+    return jnp.argmin(buf, axis=1).astype(jnp.int32)
+
+
+def _wave_keys(sched: str, free_mem):
+    """Per-node score keys for one pod per lane, **negated for max-mode**
+    so one masked-argmin select serves every policy: the serial
+    ``Scheduler.wave_scores`` of best-fit (min free memory), worst-fit
+    (max free memory) and first-fit (first feasible rank)."""
+    import jax.numpy as jnp
     if sched == "best-fit":
-        return free_mem                       # min free_mem
+        return _order_key(free_mem)
     if sched == "worst-fit":
-        return -free_mem                      # max free_mem
-    if sched == "first-fit":
-        return jnp.zeros_like(free_mem)       # first feasible rank
-    # k8s-default / weighted share the request-fraction core (serial:
-    # int64 subtract then true-divide -> f64; these columns are already
-    # f64-exact ints, so subtract/divide bits match).
-    cpu_frac = (free_cpu - pc) / jnp.maximum(alloc_cpu, 1.0)
-    mem_frac = (free_mem - pm) / jnp.maximum(alloc_mem, 1e-9)
-    # Both blend terms are fenced: XLA rewrites the trailing /2.0 into
-    # *0.5 and would contract either term's product into an FMA with the
-    # (lr + bal) add otherwise, shifting the last ulp vs NumPy.
-    least_requested = _fence(10.0 * (cpu_frac + mem_frac) / 2.0)
-    balanced = _fence(10.0 * (1.0 - jnp.abs(cpu_frac - mem_frac)))
-    if sched == "k8s-default":
-        return -((least_requested + balanced) / 2.0)
-    # weighted: w_pack*pack + w_lr*lr + w_bal*bal, left-to-right adds.
-    # pack is fenced like the other composite terms — unfenced, XLA
-    # rewrites the nested w*(10*(1-x)) chain non-IEEE.
-    pack = _fence(10.0 * (1.0 - mem_frac))
-    s = (_fence(weights[:, 0:1] * pack)
-         + _fence(weights[:, 1:2] * least_requested)
-         ) + _fence(weights[:, 2:3] * balanced)
-    return -s
+        return -_order_key(free_mem)
+    return jnp.zeros_like(free_mem)
 
 
-def _program_factory(sched: str, backend: str, n_pad: int):
-    """Build the jitted many-world program for one (scheduler, select
-    backend, padded node count); XLA retraces per (L, P) bucket."""
+def _program_factory(sched: str, n_pad: int):
+    """Build the jitted many-world program for one (scheduler, padded node
+    count); XLA retraces per (L, P) bucket.  ``arr_t`` / ``mem`` / ``dur``
+    / ``alloc_mem`` and the float outputs are float64 bit patterns
+    (module docstring, "Float discipline"); all times are non-negative, so
+    their patterns compare like their values."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    def select(scores, mask):
-        return _select.masked_argmin(scores, mask, backend)
-
-    def run(arr_t, cpu, mem, dur, isb, valid, n_nodes,
-            alloc_cpu, alloc_mem, weights):
+    def run(arr_t, cpu, mem, dur, isb, valid, n_nodes, alloc_cpu, alloc_mem):
         L, P = arr_t.shape
+        t_of = jnp.asarray(_T_BITS)
         li = jnp.arange(L)
         node_active = (jnp.arange(n_pad, dtype=jnp.int32)[None, :]
                        < n_nodes[:, None])                    # (L, N)
@@ -223,22 +283,23 @@ def _program_factory(sched: str, backend: str, n_pad: int):
                 has = due.any(axis=1)
                 # Two-stage extremum: earliest done_time, then lowest
                 # bind_seq among its ties (seq is unique per lane).
-                t1 = jnp.where(due, done_t, jnp.inf)
+                t1 = jnp.where(due, done_t, _INF_BITS)
                 tmin = t1.min(axis=1, keepdims=True)
                 s1 = jnp.where(due & (t1 == tmin), bind_seq, _SEQ_INF)
                 p = jnp.argmin(s1, axis=1)
                 node = jnp.where(has, bind_node[li, p], 0)
-                dc = jnp.where(has, cpu[li, p], 0.0)
-                dm = jnp.where(has, mem[li, p], 0.0)
                 # serial: node._used_* -= req, one pod at a time.
-                used_cpu = used_cpu.at[li, node].add(-dc)
-                used_mem = used_mem.at[li, node].add(-dm)
+                old_c, old_m = used_cpu[li, node], used_mem[li, node]
+                used_cpu = used_cpu.at[li, node].set(
+                    jnp.where(has, old_c - cpu[li, p], old_c))
+                used_mem = used_mem.at[li, node].set(
+                    jnp.where(has, f64_add(old_m, mem[li, p] ^ _SIGN), old_m))
                 pcount = pcount.at[li, node].add(-has.astype(jnp.int32))
                 done_c = done_c.at[li, p].set(done_c[li, p] | has)
                 # _done() check after this POD_DONE event: all arrived at
                 # the *event's* time, every batch row committed, every
                 # service bound.
-                td = jnp.where(has, done_t[li, p], jnp.inf)
+                td = jnp.where(has, done_t[li, p], _INF_BITS)
                 arrived_td = (~valid | (arr_t <= td[:, None])).all(axis=1)
                 batch_done = (~valid | ~isb | done_c).all(axis=1)
                 svc_bound = (~valid | isb | bound).all(axis=1)
@@ -283,20 +344,20 @@ def _program_factory(sched: str, backend: str, n_pad: int):
                 # serial WavePlacer: free = alloc - used (elementwise);
                 # fits = (free_cpu >= cpu) & (free_mem + 1e-9 >= mem).
                 free_cpu = ac - used_cpu
-                free_mem = am - used_mem
-                mask = ((free_cpu >= pc) & ((free_mem + 1e-9) >= pm)
-                        & node_active)
-                scores = _wave_scores(sched, free_cpu, free_mem, ac, am,
-                                      pc, pm, weights)
-                r = select(scores, mask)
+                free_mem = f64_add(am, used_mem ^ _SIGN)
+                mem_fits = (_order_key(f64_add(free_mem, _EPS_BITS))
+                            >= _order_key(pm))
+                mask = (free_cpu >= pc) & mem_fits & node_active
+                r = masked_argmin(_wave_keys(sched, free_mem), mask)
                 feas = mask.any(axis=1)
                 do = has & feas
                 blk = has & ~feas
                 r_g = jnp.where(do, r, 0).astype(jnp.int32)
-                add_c = jnp.where(do, pc[:, 0], 0.0)
-                add_m = jnp.where(do, pm[:, 0], 0.0)
-                used_cpu = used_cpu.at[li, r_g].add(add_c)
-                used_mem = used_mem.at[li, r_g].add(add_m)
+                old_c, old_m = used_cpu[li, r_g], used_mem[li, r_g]
+                used_cpu = used_cpu.at[li, r_g].set(
+                    jnp.where(do, old_c + pc[:, 0], old_c))
+                used_mem = used_mem.at[li, r_g].set(
+                    jnp.where(do, f64_add(old_m, pm[:, 0]), old_m))
                 pcount = pcount.at[li, r_g].add(do.astype(jnp.int32))
                 bound = bound.at[li, p].set(bound[li, p] | do)
                 bind_node = bind_node.at[li, p].set(
@@ -307,7 +368,8 @@ def _program_factory(sched: str, backend: str, n_pad: int):
                     jnp.where(do, k, bind_cycle[li, p]))
                 # Completion timestamp: now + duration (speed factor 1);
                 # services never complete (+inf).
-                td = jnp.where(do & isb[li, p], t + dur[li, p], jnp.inf)
+                td = jnp.where(do & isb[li, p], f64_add(t, dur[li, p]),
+                               _INF_BITS)
                 done_t = done_t.at[li, p].set(
                     jnp.where(do, td, done_t[li, p]))
                 seq_ctr = seq_ctr + do.astype(jnp.int32)
@@ -358,7 +420,7 @@ def _program_factory(sched: str, backend: str, n_pad: int):
 
         def cycle_body(st):
             k = st[0]
-            t = k.astype(jnp.float64) * CYCLE_PERIOD_S
+            t = t_of[k]
             # POD_DONE events at times <= t all fire before CYCLE(t).
             mid = completions(t, st[1:14])
             out = wave(t, k, mid + st[14:])
@@ -370,18 +432,18 @@ def _program_factory(sched: str, backend: str, n_pad: int):
 
         init = (
             jnp.zeros((), jnp.int32),                      # k
-            jnp.zeros((L, n_pad)),                         # used_cpu
-            jnp.zeros((L, n_pad)),                         # used_mem
+            jnp.zeros((L, n_pad), cpu.dtype),              # used_cpu
+            jnp.zeros((L, n_pad), mem.dtype),              # used_mem (+0.0)
             jnp.zeros((L, n_pad), jnp.int32),              # pcount
             jnp.zeros((L, P), bool),                       # done_c
-            jnp.full((L, P), jnp.inf),                     # done_t
+            jnp.full((L, P), _INF_BITS),                   # done_t
             jnp.zeros((L, P), bool),                       # bound
             jnp.full((L, P), -1, jnp.int32),               # bind_node
             jnp.full((L, P), -1, jnp.int32),               # bind_seq
             jnp.full((L, P), -1, jnp.int32),               # bind_cycle
             valid.any(axis=1),                             # active
             jnp.zeros(L, bool),                            # completed
-            jnp.full(L, HORIZON_S),                        # done_time
+            jnp.full(L, _HORIZON_BITS),                    # done_time
             jnp.zeros(L, bool),                            # done_is_cycle
             jnp.zeros(L, jnp.int32),                       # seq_ctr
             jnp.zeros(L, jnp.int32),                       # scale_outs
@@ -404,11 +466,18 @@ def _program_factory(sched: str, backend: str, n_pad: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_cache(sched: str, backend: str, n_pad: int):
-    return _program_factory(sched, backend, n_pad)
+def _jit_cache(sched: str, n_pad: int):
+    return _program_factory(sched, n_pad)
 
 
-def run_lane_batch(batch: LaneBatch, backend: Optional[str] = None) -> dict:
+def program_args(batch: LaneBatch) -> tuple:
+    """The program's host arguments for ``batch``, floats as bit patterns."""
+    return (f64_bits(batch.arrival_t), batch.cpu_m, f64_bits(batch.mem_mb),
+            f64_bits(batch.duration_s), batch.is_batch, batch.valid,
+            batch.n_nodes, batch.alloc_cpu, f64_bits(batch.alloc_mem))
+
+
+def run_lane_batch(batch: LaneBatch) -> dict:
     """Execute one :class:`LaneBatch`; returns numpy lane outputs.
 
     Per lane: ``completed`` / ``done_time`` / ``done_is_cycle`` /
@@ -417,14 +486,11 @@ def run_lane_batch(batch: LaneBatch, backend: Optional[str] = None) -> dict:
     ``bind_seq`` (per-lane bind order), ``bind_cycle`` (bind time is
     exactly ``bind_cycle * 10.0``), ``done_t`` and ``done_committed``.
     """
-    from jax.experimental import enable_x64
-    backend = _select.active_backend(backend)
-    with enable_x64():
-        import jax.numpy as jnp
-        run = _jit_cache(batch.scheduler, backend, batch.n_pad)
-        out = run(jnp.asarray(batch.arrival_t), jnp.asarray(batch.cpu_m),
-                  jnp.asarray(batch.mem_mb), jnp.asarray(batch.duration_s),
-                  jnp.asarray(batch.is_batch), jnp.asarray(batch.valid),
-                  jnp.asarray(batch.n_nodes), jnp.asarray(batch.alloc_cpu),
-                  jnp.asarray(batch.alloc_mem), jnp.asarray(batch.weights))
-        return {key: np.asarray(v) for key, v in out.items()}
+    import jax
+    with jax.enable_x64(True):
+        run = _jit_cache(batch.scheduler, batch.n_pad)
+        out = {key: np.asarray(v)
+               for key, v in run(*program_args(batch)).items()}
+    for key in ("done_t", "done_time", "used_mem"):
+        out[key] = out[key].view(np.float64)
+    return out

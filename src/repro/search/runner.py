@@ -26,6 +26,7 @@ shipping TraceStores through pickle) also keeps task payloads tiny.
 from __future__ import annotations
 
 import dataclasses
+import multiprocessing
 import os
 import time
 from concurrent.futures import ProcessPoolExecutor
@@ -212,10 +213,14 @@ def run_cells(cells: Sequence[CellSpec], workers=1,
 
     ``workers="lanes"`` evaluates the list on the many-world lane engine
     (`repro.manyworld`): void/void static-cluster cells run batched in
-    one JAX program per bucket, anything outside that envelope (and
-    everything, when JAX is absent) falls back to the serial ``run_cell``
-    — same rows, same order, bit-identical metrics (``wall_s`` becomes
-    the lane's share of its batch).
+    one JAX program per bucket, anything outside that envelope falls back
+    to the serial ``run_cell`` — same rows, same order, bit-identical
+    metrics (``wall_s`` becomes the lane's share of its batch).
+
+    Pool workers are *spawned*, never forked: a parent that has run the
+    lanes or a learned forecaster holds the accelerator, and a forked
+    child would inherit that hold.  ``run_cell`` never imports JAX, so a
+    spawned worker stays off the device.
     """
     cells = list(cells)
     if workers == "lanes":
@@ -233,7 +238,9 @@ def run_cells(cells: Sequence[CellSpec], workers=1,
     if max_tasks_per_child is not None:
         kwargs["max_tasks_per_child"] = max_tasks_per_child
     rows: List[dict] = []
-    with ProcessPoolExecutor(max_workers=workers, **kwargs) as pool:
+    with ProcessPoolExecutor(max_workers=workers,
+                             mp_context=multiprocessing.get_context("spawn"),
+                             **kwargs) as pool:
         futures = [(cell, pool.submit(run_cell, cell)) for cell in cells]
         for cell, future in futures:
             try:

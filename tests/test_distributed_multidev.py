@@ -36,6 +36,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
                                         train_state_axes)
     from repro.train.optimizer import OptimizerConfig
     from repro.train.data import SyntheticLM, DataConfig
+    from repro.launch.mesh import make_mesh
 
     cfg = get_config("deepseek-7b", tiny=True)
     data = SyntheticLM(cfg, DataConfig(batch_size=8, seq_len=32))
@@ -47,7 +48,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     ref_state, ref_metrics = jax.jit(step)(state0, batch)
 
     # sharded over (data=2, model=4)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardingCtx(mesh, dict(DEFAULT_RULES))
     state = init_train_state(jax.random.key(0), cfg)
     st_sh = tree_shardings(ctx, jax.eval_shape(lambda: state),
@@ -86,10 +87,7 @@ def test_compressed_grad_sync_close_to_fp32():
 
     f_c = make_compressed_ddp_step(loss_fn, mesh, compress=True)
     f_f = make_compressed_ddp_step(loss_fn, mesh, compress=False)
-    # jax.set_mesh only exists on newer jax; the legacy Mesh context manager
-    # is equivalent here (shard_map already carries the mesh).
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         loss_c, g_c = jax.jit(f_c)(W, X)
         loss_f, g_f = jax.jit(f_f)(W, X)
     np.testing.assert_allclose(float(loss_c), float(loss_f), rtol=1e-6)

@@ -8,7 +8,8 @@ lexicographic node_id order), at the same cycle times, in the same order —
 and the evaluator reconstructs `run_cells` rows whose 17 metric fields are
 bitwise equal to the serial runner's.  The edge battery pins the padding
 and masking behaviors (zero-pod lanes, all-infeasible lanes, non-pow2 lane
-counts, mixed lane sizes in one bucket) and the FMA score fence.
+counts, mixed lane sizes in one bucket) and the integer IEEE-754 add the
+program does its float arithmetic with.
 """
 import math
 
@@ -20,7 +21,6 @@ jax = pytest.importorskip("jax")   # lane engine is JAX-gated by design
 from repro.cloud.adapter import M2_SMALL
 from repro.core import build_simulation, reset_id_counters
 from repro.manyworld import lanes as ml
-from repro.manyworld import select as msel
 from repro.manyworld.evaluator import lane_eligible, run_cells_lanes
 from repro.scenarios.trace import KIND_BATCH
 from repro.search.runner import _RESULT_FIELDS, CellSpec, _get_trace, run_cells
@@ -29,12 +29,11 @@ ALLOC_CPU = float(M2_SMALL.allocatable.cpu_m)
 ALLOC_MEM = float(M2_SMALL.allocatable.mem_mb)
 
 
-def _lane_of(trace, n_nodes, weights=None):
+def _lane_of(trace, n_nodes):
     d = trace.to_lane_arrays()
     d["n_nodes"] = n_nodes
     d["alloc_cpu"] = ALLOC_CPU
     d["alloc_mem"] = ALLOC_MEM
-    d["weights"] = weights
     return d
 
 
@@ -62,10 +61,10 @@ CASES = [
     ("heavy-tail", "best-fit", 4),
     ("heavy-tail", "worst-fit", 1),
     ("heavy-tail", "first-fit", 3),
-    ("heavy-tail", "k8s-default", 4),
-    ("heavy-tail", "weighted", 12),
+    ("heavy-tail", "best-fit", 12),
+    ("heavy-tail", "worst-fit", 12),
     ("capacity-crunch", "best-fit", 2),
-    ("diurnal", "k8s-default", 3),
+    ("diurnal", "first-fit", 3),
     ("mix-ramp", "worst-fit", 12),
 ]
 
@@ -124,6 +123,13 @@ class TestEvaluatorRows:
             CellSpec(scenario="heavy-tail", scheduler="best-fit",
                      autoscaler="void", rescheduler="void", seed=0,
                      n_jobs=40, engine="array", initial_workers=4),
+            CellSpec(scenario="diurnal", scheduler="first-fit",
+                     autoscaler="void", rescheduler="void", seed=0,
+                     n_jobs=24, engine="array", initial_workers=3),
+            CellSpec(scenario="mix-ramp", scheduler="worst-fit",
+                     autoscaler="void", rescheduler="void", seed=2,
+                     n_jobs=40, engine="array", initial_workers=5),
+            # outside the lane schedulers -> serial on every backend
             CellSpec(scenario="diurnal", scheduler="k8s-default",
                      autoscaler="void", rescheduler="void", seed=0,
                      n_jobs=24, engine="array", initial_workers=3),
@@ -154,6 +160,9 @@ class TestEvaluatorRows:
         base = dict(scenario="heavy-tail", scheduler="best-fit",
                     autoscaler="void", rescheduler="void", engine="array")
         assert lane_eligible(CellSpec(**base))
+        # Float multiply/divide scores stay on the serial reference.
+        assert not lane_eligible(CellSpec(**{**base, "scheduler": "k8s-default"}))
+        assert not lane_eligible(CellSpec(**{**base, "scheduler": "weighted"}))
         assert lane_eligible(CellSpec(**{**base, "engine": None}))
         assert not lane_eligible(CellSpec(**{**base, "autoscaler": "binding"}))
         assert not lane_eligible(CellSpec(**{**base, "rescheduler": "non-binding"}))
@@ -226,7 +235,10 @@ class TestPaddingAndMasking:
         with pytest.raises(ValueError, match="p_pad"):
             ml.stack_lanes([lane], "best-fit", p_pad=16)
         with pytest.raises(ValueError, match="scheduler"):
-            ml.stack_lanes([lane], "round-robin")
+            ml.stack_lanes([lane], "k8s-default")
+        with pytest.raises(ValueError, match="milli-cores"):
+            ml.stack_lanes([{**lane, "cpu_m": lane["cpu_m"] + 0.5}],
+                           "best-fit")
 
     def test_next_pow2(self):
         assert [ml.next_pow2(n) for n in (0, 1, 2, 3, 40, 64, 65)] \
@@ -235,74 +247,84 @@ class TestPaddingAndMasking:
 
 class TestSelectKernels:
     def test_backends_agree_with_numpy(self):
-        """jnp and pallas backends both implement first-occurrence masked
-        argmin, including tie rows and all-masked rows (callers gate on
-        mask.any — the index just has to be in range)."""
+        """The lane select is NumPy's first-occurrence masked argmin over
+        int64 score keys, including tie rows and all-masked rows (callers
+        gate on mask.any — the index just has to be in range)."""
         rng = np.random.default_rng(7)
-        scores = rng.standard_normal((17, 13))
-        scores[3, 4] = scores[3, 9] = scores[3].min() - 1.0   # exact tie
+        keys = rng.integers(-2**62, 2**62, (17, 13), dtype=np.int64)
+        keys[3, 4] = keys[3, 9] = keys[3].min() - 1            # exact tie
         mask = rng.random((17, 13)) < 0.6
         mask[5] = False                                        # all masked
         mask[3, 4] = mask[3, 9] = True
-        from jax.experimental import enable_x64
-        with enable_x64():
-            import jax.numpy as jnp
-            s, m = jnp.asarray(scores), jnp.asarray(mask)
-            got_j = np.asarray(msel.masked_argmin(s, m, "jnp"))
-            got_p = np.asarray(msel.masked_argmin(s, m, "pallas"))
-        buf = np.where(mask, scores, np.inf)
+        from jax import enable_x64
+        with enable_x64(True):
+            got = np.asarray(ml.masked_argmin(keys, mask))
+        buf = np.where(mask, keys, np.iinfo(np.int64).max)
         ref = buf.argmin(axis=1)
         rows = mask.any(axis=1)
-        assert np.array_equal(got_j[rows], ref[rows])
-        assert np.array_equal(got_p[rows], ref[rows])
-        assert got_j[3] == 4 and got_p[3] == 4                 # first tie
-
-    def test_backend_env_flag(self, monkeypatch):
-        monkeypatch.setenv(msel.ENV_FLAG, "pallas")
-        assert msel.active_backend() == "pallas"
-        assert msel.active_backend("jnp") == "jnp"             # arg wins
-        monkeypatch.setenv(msel.ENV_FLAG, "cuda")
-        with pytest.raises(ValueError, match="cuda"):
-            msel.active_backend()
+        assert np.array_equal(got[rows], ref[rows])
+        assert got[3] == 4                                     # first tie
+        assert 0 <= got[5] < 13
 
 
-class TestScoreFence:
-    @pytest.mark.parametrize("sched,weights", [
-        ("k8s-default", None), ("weighted", (0.2, 0.5, 0.3))])
-    def test_scores_match_numpy_bits(self, sched, weights):
-        """The `_fence` around products feeding adds must keep XLA's CPU
-        backend from contracting them into FMAs: jitted lane scores must
-        equal the serial NumPy formula bit-for-bit."""
-        rng = np.random.default_rng(3)
-        free_cpu = rng.integers(0, 941, (8, 6)).astype(np.float64)
-        free_mem = rng.random((8, 6)) * 3584.0
-        pc, pm = 250.0, 433.3
-        w = np.tile(np.array(weights or (1.0, 0.0, 0.0)), (8, 1))
-        from jax.experimental import enable_x64
-        with enable_x64():
-            import jax
-            import jax.numpy as jnp
-            # alloc / requests enter as runtime args, like the lane
-            # program's traced operands — baked-in constants would let
-            # XLA fold divisions into reciprocal multiplies, which the
-            # real program never exposes itself to.
-            f = jax.jit(lambda fc, fm, ac, am, c, m, wt: ml._wave_scores(
-                sched, fc, fm, ac, am, c, m, wt))
-            got = np.asarray(f(jnp.asarray(free_cpu), jnp.asarray(free_mem),
-                               jnp.full((8, 1), 940.0),
-                               jnp.full((8, 1), 3584.0),
-                               jnp.float64(pc), jnp.float64(pm),
-                               jnp.asarray(w)))
-        cpu_frac = (free_cpu - pc) / np.maximum(940.0, 1)
-        mem_frac = (free_mem - pm) / np.maximum(3584.0, 1e-9)
-        lr = 10.0 * (cpu_frac + mem_frac) / 2.0
-        bal = 10.0 * (1.0 - np.abs(cpu_frac - mem_frac))
-        if sched == "k8s-default":
-            ref = (lr + bal) / 2.0
-        else:
-            pack = 10.0 * (1.0 - mem_frac)
-            ref = (w[:, 0:1] * pack + w[:, 1:2] * lr) + w[:, 2:3] * bal
-        assert np.array_equal(got, -ref)       # lane scores are negated
+def _f64_cases(name, rng, n=4096):
+    """Operand pairs for one family of float64 additions."""
+    if name == "random-bits":       # every finite exponent, both signs
+        bits = rng.integers(0, 2**63 - 1, n, dtype=np.int64)
+        finite = ((bits >> 52) & 0x7FF) != 0x7FF
+        bits = np.where(finite, bits, bits & ~(np.int64(1) << 62))
+        neg = rng.random(n) < 0.5
+        a = np.where(neg, bits | np.int64(-2**63), bits).view(np.float64)
+        return a, np.roll(a, 1)
+    e = rng.integers(-60, 60, n)
+    a = rng.standard_normal(n) * 2.0 ** e
+    if name == "cancellation":      # near-equal magnitudes, opposite signs
+        return a, -a * (1 + rng.integers(-4, 5, n) * 2.0**-52)
+    if name == "mixed-scale":       # alignment shifts up to and past 53
+        return a, rng.standard_normal(n) * 2.0 ** (e + rng.integers(-60, 60, n))
+    if name == "subnormal":
+        sub = rng.integers(0, 2**52, n, dtype=np.int64).view(np.float64)
+        sub = sub * rng.choice([1.0, -1.0], n)
+        return sub, np.roll(sub, 3)
+    if name == "lane-values":       # pod memory sizes, slack, signed zeros
+        vals = np.array([307.2, 614.4, 921.6, 1024.0, 1433.6, 2415.616,
+                         3584.0, 1e-9, 0.0, -0.0])
+        pick = lambda: (vals[rng.integers(0, vals.size, n)]  # noqa: E731
+                        * rng.choice([1.0, -1.0], n))
+        return pick(), pick()
+    assert name == "times"          # cycle start + batch duration
+    return (rng.integers(0, ml.MAX_CYCLES + 1, n) * 10.0,
+            np.clip(rng.lognormal(np.log(120.0), 1.0, n), 1.0, 7200.0))
+
+
+class TestF64Add:
+    @pytest.mark.parametrize("family", [
+        "random-bits", "cancellation", "mixed-scale", "subnormal",
+        "lane-values", "times"])
+    def test_matches_numpy_bits(self, family):
+        """The integer IEEE-754 add equals NumPy's float64 add bit for bit
+        on every finite sum (round to nearest, ties to even; subnormals
+        and signed zeros included)."""
+        a, b = _f64_cases(family, np.random.default_rng(3))
+        with np.errstate(over="ignore"):
+            ref = a + b
+        ok = np.isfinite(ref)
+        from jax import enable_x64
+        with enable_x64(True):
+            got = np.asarray(jax.jit(ml.f64_add)(ml.f64_bits(a),
+                                                  ml.f64_bits(b)))
+        assert np.array_equal(got[ok], ml.f64_bits(ref)[ok])
+
+    def test_order_key_orders_like_floats(self):
+        rng = np.random.default_rng(5)
+        x = np.concatenate([rng.standard_normal(4096) * 1e3,
+                            [0.0, -0.0, 5e-324, -5e-324, np.inf]])
+        from jax import enable_x64
+        with enable_x64(True):
+            k = np.asarray(ml._order_key(ml.f64_bits(x)))
+        i, j = rng.integers(0, x.size, (2, 20000))
+        assert np.array_equal(k[i] < k[j], x[i] < x[j])
+        assert np.array_equal(k[i] == k[j], x[i] == x[j])
 
 
 class TestLaneExports:

@@ -6,12 +6,15 @@
   order);
 * a failing cell raises `CellError` naming the cell, and a hard worker
   death (``os._exit`` via the ``REPRO_SEARCH_TEST_CRASH`` hook) also
-  surfaces as `CellError` instead of hanging the pool.
+  surfaces as `CellError` instead of hanging the pool;
+* pool workers are spawned and never import JAX, so none of them can
+  inherit or take the accelerator a parent process holds.
 """
 import pytest
 
 from repro.search import (CellError, CellSpec, default_space, run_cells,
                           run_search)
+from repro.search import runner
 from repro.search.runner import _CRASH_ENV
 
 # Small enough that the whole module stays in CI seconds; 2 scenario
@@ -75,6 +78,33 @@ def test_worker_crash_surfaces_error_not_hang(monkeypatch):
     monkeypatch.setenv(_CRASH_ENV, crash.label)
     with pytest.raises(CellError, match=crash.scenario):
         run_cells([crash] + CELLS[1:3], workers=2)
+
+
+def test_pool_workers_are_spawned_and_never_import_jax(tmp_path,
+                                                      monkeypatch):
+    """A stand-in ``jax`` package first on the workers' import path marks
+    and refuses any import of JAX in a worker, and the pool must start
+    its workers by spawning (a forked worker would inherit a parent that
+    has already loaded JAX)."""
+    marker = tmp_path / "jax_imported"
+    fake = tmp_path / "path" / "jax"
+    fake.mkdir(parents=True)
+    (fake / "__init__.py").write_text(
+        f"open({str(marker)!r}, 'w').close()\n"
+        "raise ImportError('a cell worker imported jax')\n")
+    monkeypatch.syspath_prepend(str(tmp_path / "path"))
+    methods = []
+
+    class RecordingPool(runner.ProcessPoolExecutor):
+        def __init__(self, *args, mp_context=None, **kwargs):
+            methods.append(mp_context.get_start_method())
+            super().__init__(*args, mp_context=mp_context, **kwargs)
+
+    monkeypatch.setattr(runner, "ProcessPoolExecutor", RecordingPool)
+    rows = run_cells(CELLS[:3], workers=2)
+    assert [r["label"] for r in rows] == [c.label for c in CELLS[:3]]
+    assert methods == ["spawn"]
+    assert not marker.exists()
 
 
 def test_search_same_seed_identical_front_serial_vs_parallel():
