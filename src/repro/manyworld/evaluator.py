@@ -33,14 +33,26 @@ semantics over the lane outputs:
 Buckets: lanes group by ``(scheduler, pod-pad, node-pad)`` with
 power-of-two pads, so the jit cache stays small while mixed workloads
 share compilations.
+
+**Spans and counts.**  Each call is one ``lanes.call`` span of
+``lanes.PROFILER`` (and of a JAX profiler trace, when one runs), with
+children ``lanes.prepare`` (trace fetch, lane arrays, bucketing) and, per
+bucket, ``lanes.stack``, ``lanes.dispatch``, ``lanes.wait``,
+``lanes.fetch`` (those three in ``lanes.run_lane_batch``) and
+``lanes.rebuild`` (the rows).  :func:`lane_calls` returns the records of
+the last calls: self time per span name, the program's step counts
+(``lanes.COUNTERS``) summed over buckets, and the lane, bucket and
+lane-program compilation counts.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import itertools
 import math
 import statistics
 import time
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -49,6 +61,45 @@ from repro.manyworld.lanes import (CYCLE_PERIOD_S, HORIZON_S, SCHEDULERS,
                                    next_pow2)
 
 SAMPLE_PERIOD_S = 20.0
+
+#: Records of the last calls of :func:`run_cells_lanes`, oldest first.
+_CALLS: collections.deque = collections.deque(maxlen=1024)
+_CALL_IDS = itertools.count()
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+#: Traces of the lane program in this process (a persistent-cache hit is
+#: traced too), counted from the first call of :func:`run_cells_lanes`.
+_compiles = 0
+_listening = False
+
+
+def _on_duration_event(event, _secs, fun_name="", **_kw) -> None:
+    global _compiles
+    if event == _TRACE_EVENT and fun_name.startswith("lane_program_"):
+        _compiles += 1
+
+
+def _count_compiles() -> None:
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _listening = True
+
+
+def lane_calls(n: int) -> List[Dict]:
+    """The records of the last ``n`` calls of :func:`run_cells_lanes` in
+    this process, oldest first.  Each holds ``call`` (a monotone id),
+    ``lanes`` (cells run on the device), ``buckets``, ``compiles`` (lane
+    program traces in the call), ``wall_s`` (the ``lanes.call`` span),
+    ``self_s`` (self time per span name: the root's is what no child
+    covers) and ``counts``: ``lanes.COUNTERS`` summed over the buckets,
+    and ``lane_steps``, each bucket's lanes times its steps (outer cycles
+    plus inner iterations), the most lanes that could have had work."""
+    if n <= 0:
+        return []
+    return [dict(rec, self_s=dict(rec["self_s"]), counts=dict(rec["counts"]))
+            for rec in list(_CALLS)[-n:]]
 
 
 def lane_eligible(cell) -> bool:
@@ -301,11 +352,49 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
     """Evaluate ``cells`` with the lane engine; serial-identical rows in
     submission order.  Ineligible cells run through the serial
     ``run_cell`` unchanged."""
-    from repro.search.runner import (CellError, _get_trace, _infeasible,
-                                     run_cell)
     cells = list(cells)
     rows: List[Optional[dict]] = [None] * len(cells)
-    buckets = {}                  # (sched, p_pad, n_pad) -> [(idx, lane)]
+    _count_compiles()
+    compiles0 = _compiles
+    span = _lanes.PROFILER.span
+    counts = dict.fromkeys(_lanes.COUNTERS + ("lane_steps",), 0)
+    n_lanes = 0
+    with span("lanes.call") as root:
+        with span("lanes.prepare"):
+            buckets = _prepare(cells, rows)
+        for (sched, p_pad, _n_pad), entries in buckets.items():
+            t0 = time.perf_counter()
+            with span("lanes.stack"):
+                batch = _lanes.stack_lanes([e[4] for e in entries], sched,
+                                           p_pad=p_pad)
+            out = _lanes.run_lane_batch(batch)
+            share = (time.perf_counter() - t0) / len(entries)
+            with span("lanes.rebuild"):
+                got = {key: int(out.pop(key)) for key in _lanes.COUNTERS}
+                for key, val in got.items():
+                    counts[key] += val
+                counts["lane_steps"] += len(entries) * (
+                    got["n_cycles"] + got["wave_steps"]
+                    + got["completion_steps"])
+                n_lanes += len(entries)
+                _rebuild(entries, out, share, rows)
+    self_s = _lanes.PROFILER.self_times(root)
+    _CALLS.append({"call": next(_CALL_IDS), "lanes": n_lanes,
+                   "buckets": len(buckets),
+                   "compiles": _compiles - compiles0,
+                   "wall_s": sum(self_s.values()), "self_s": self_s,
+                   "counts": counts})
+    assert all(r is not None for r in rows)
+    return rows
+
+
+def _prepare(cells: list, rows: list) -> dict:
+    """Fill ``rows`` for the cells that need no lane, and bucket the rest:
+    ``(scheduler, pod-pad, node-pad) -> [(idx, cell, trace, template,
+    lane arrays)]``."""
+    from repro.search.runner import (CellError, _get_trace, _infeasible,
+                                     run_cell)
+    buckets = {}
     for idx, cell in enumerate(cells):
         try:
             if not lane_eligible(cell):
@@ -335,24 +424,18 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
             raise
         except Exception as exc:
             raise CellError(f"cell {cell.label} failed: {exc!r}") from exc
+    return buckets
 
-    for (sched, p_pad, _n_pad), entries in buckets.items():
-        t0 = time.perf_counter()
-        batch = _lanes.stack_lanes([e[4] for e in entries], sched,
-                                   p_pad=p_pad)
-        out = _lanes.run_lane_batch(batch)
-        share = (time.perf_counter() - t0) / len(entries)
-        for li, (idx, cell, trace, template, _lane) in enumerate(entries):
-            o = {key: val[li] for key, val in out.items()
-                 if key not in ("n_cycles",)}
-            try:
-                row = _base_row(cell, trace, infeasible=False)
-                row.update(_lane_metrics(cell, trace, template, o))
-                row["wall_s"] = share
-                rows[idx] = row
-            except Exception as exc:
-                raise CellError(
-                    f"cell {cell.label} failed: {exc!r}") from exc
 
-    assert all(r is not None for r in rows)
-    return rows
+def _rebuild(entries: list, out: dict, share: float, rows: list) -> None:
+    """One bucket's rows from its lane outputs (counters taken out)."""
+    from repro.search.runner import CellError
+    for li, (idx, cell, trace, template, _lane) in enumerate(entries):
+        o = {key: val[li] for key, val in out.items()}
+        try:
+            row = _base_row(cell, trace, infeasible=False)
+            row.update(_lane_metrics(cell, trace, template, o))
+            row["wall_s"] = share
+            rows[idx] = row
+        except Exception as exc:
+            raise CellError(f"cell {cell.label} failed: {exc!r}") from exc

@@ -17,6 +17,16 @@ the cycle hot path of the serial engine lowered to fixed shapes:
   select (:func:`masked_argmin`), then the serial accounting ops
   ``used += req`` / ``free = alloc - used``.
 
+**Names and counts.**  The jitted program is named after its scheduler
+(``lane_program_best_fit``: the ``XLA Modules`` line of a profiler
+trace), and its loop bodies run under the named scopes ``completions``,
+``wave`` and ``cycle_end``.  Beside its outputs it returns the steps it
+took (:data:`COUNTERS`): the outer cycles, the iterations of each inner
+loop, and how many lanes had work in them.  The counts only describe the
+run; nothing reads them back into a decision.  :func:`run_lane_batch`
+times its dispatch, wait and copy to the host as spans of
+:data:`PROFILER`, the lane path's process-wide span recorder.
+
 **Relaxed-semantics envelope.**  Lanes model the void/void static-cluster
 regime only: no autoscaler, no rescheduler, no chaos, homogeneous READY
 fleet billed from t=0, speed factor 1.  Everything else — event ordering,
@@ -48,11 +58,26 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs.profiler import PhaseProfiler
+
 CYCLE_PERIOD_S = 10.0
 HORIZON_S = 48 * 3600.0          # SimConfig.max_sim_time_s default
 MAX_CYCLES = int(HORIZON_S / CYCLE_PERIOD_S)   # cycle at t == horizon runs
 
 SCHEDULERS = ("best-fit", "worst-fit", "first-fit")
+
+#: Scalar step counts the program returns beside its lane outputs:
+#: ``n_cycles`` outer cycles; ``wave_steps`` / ``completion_steps``
+#: iterations of the bind and completion loops over the whole run;
+#: ``busy_lane_steps`` lanes with a pending candidate or a due completion,
+#: summed over those iterations; ``active_lane_cycles`` active lanes,
+#: summed over the outer cycles.  The two lane sums are int64: lanes times
+#: steps can pass 2**31 at policy-search sizes.
+COUNTERS = ("n_cycles", "wave_steps", "completion_steps", "busy_lane_steps",
+            "active_lane_cycles")
+
+#: The lane path's spans (``repro.manyworld.evaluator`` opens the rest).
+PROFILER = PhaseProfiler(max_spans=1 << 14)
 
 # bind_seq fill for "no completion candidate" (any value > every real seq).
 _SEQ_INF = np.int32(2**31 - 1)
@@ -262,11 +287,12 @@ def _program_factory(sched: str, n_pad: int):
         ac = alloc_cpu[:, None]
         am = alloc_mem[:, None]
 
-        def completions(t, st):
+        def completions(t, st, steps, busy):
             """Commit due batch completions one pod per lane per step, in
             (done_time, bind_seq) order — the serial POD_DONE event order
             (heap pops ascending time; push order == bind order within a
-            timestamp)."""
+            timestamp).  ``steps`` and ``busy`` count the iterations and
+            the lanes that committed in them."""
             def due_of(c):
                 done_c, done_t, bound, active = c[3], c[4], c[5], c[9]
                 return (valid & isb & bound & ~done_c
@@ -278,7 +304,7 @@ def _program_factory(sched: str, n_pad: int):
             def body(c):
                 (used_cpu, used_mem, pcount, done_c, done_t, bound,
                  bind_node, bind_seq, bind_cycle, active, completed,
-                 done_time, done_is_cycle) = c
+                 done_time, done_is_cycle, steps, busy) = c
                 due = due_of(c)
                 has = due.any(axis=1)
                 # Two-stage extremum: earliest done_time, then lowest
@@ -309,17 +335,22 @@ def _program_factory(sched: str, n_pad: int):
                 active = active & ~now_done
                 return (used_cpu, used_mem, pcount, done_c, done_t, bound,
                         bind_node, bind_seq, bind_cycle, active, completed,
-                        done_time, done_is_cycle)
+                        done_time, done_is_cycle, steps + 1,
+                        busy + has.sum(dtype=busy.dtype))
 
-            return lax.while_loop(cond, body, st)
+            with jax.named_scope("completions"):
+                out = lax.while_loop(cond, body, st + (steps, busy))
+            return out[:13], out[13], out[14]
 
-        def wave(t, k, st):
+        def wave(t, k, st, steps, busy):
             """One scheduling cycle's wave: walk the pending snapshot in
             row (FIFO) order, one pod per lane per step.  Blocked pods are
             counted (the serial void/void fallback bumps one scale-out
             request per blocked pod) and skipped — decision-identical to
             the serial blocked_keys latch, which only memoizes the same
-            outcome (working frees never grow inside a cycle)."""
+            outcome (working frees never grow inside a cycle).  ``steps``
+            and ``busy`` count the iterations and the lanes that attempted
+            a pod in them."""
             (used_cpu, used_mem, pcount, done_c, done_t, bound,
              bind_node, bind_seq, bind_cycle, active, completed,
              done_time, done_is_cycle, seq_ctr, scale_outs) = st
@@ -335,7 +366,7 @@ def _program_factory(sched: str, n_pad: int):
             def body(c):
                 (used_cpu, used_mem, bound, bind_node, bind_seq,
                  bind_cycle, done_t, pcount, attempted, placed, blocked,
-                 seq_ctr) = c
+                 seq_ctr, steps, busy) = c
                 cand = cand_of(c)
                 has = cand.any(axis=1)
                 p = jnp.argmax(cand, axis=1)       # first pending row
@@ -378,53 +409,62 @@ def _program_factory(sched: str, n_pad: int):
                 attempted = attempted.at[li, p].set(attempted[li, p] | has)
                 return (used_cpu, used_mem, bound, bind_node, bind_seq,
                         bind_cycle, done_t, pcount, attempted, placed,
-                        blocked, seq_ctr)
+                        blocked, seq_ctr, steps + 1,
+                        busy + has.sum(dtype=busy.dtype))
 
             zeros_i = jnp.zeros(L, jnp.int32)
-            (used_cpu, used_mem, bound, bind_node, bind_seq, bind_cycle,
-             done_t, pcount, _att, placed, blocked, seq_ctr
-             ) = lax.while_loop(
-                cond, body,
-                (used_cpu, used_mem, bound, bind_node, bind_seq,
-                 bind_cycle, done_t, pcount, jnp.zeros_like(bound),
-                 zeros_i, zeros_i, seq_ctr))
+            with jax.named_scope("wave"):
+                (used_cpu, used_mem, bound, bind_node, bind_seq, bind_cycle,
+                 done_t, pcount, _att, placed, blocked, seq_ctr, steps, busy
+                 ) = lax.while_loop(
+                    cond, body,
+                    (used_cpu, used_mem, bound, bind_node, bind_seq,
+                     bind_cycle, done_t, pcount, jnp.zeros_like(bound),
+                     zeros_i, zeros_i, seq_ctr, steps, busy))
             scale_outs = scale_outs + blocked
 
             # -- post-cycle bookkeeping (serial order: wave stats, the
             # _done() check after the CYCLE event, then stuck detection).
-            all_arrived = (~valid | (arr_t <= t)).all(axis=1)
-            pending_after = (arrived & ~bound).any(axis=1)
-            running_batch = (valid & isb & bound & ~done_c).any(axis=1)
-            batch_done = (~valid | ~isb | done_c).all(axis=1)
-            svc_bound = (~valid | isb | bound).all(axis=1)
-            has_pods = valid.any(axis=1)
-            done_b = (active & has_pods & all_arrived & batch_done
-                      & svc_bound)
-            completed = completed | done_b
-            done_time = jnp.where(done_b, t, done_time)
-            done_is_cycle = done_is_cycle | done_b
-            active = active & ~done_b
-            # _permanently_stuck: static cluster, everything arrived,
-            # nothing placed, something blocked, nothing running.
-            stuck_now = (active & all_arrived & (placed == 0)
-                         & (blocked > 0) & ~running_batch & pending_after)
-            active = active & ~stuck_now
-            # Quiescent: all arrived, nothing pending, nothing running,
-            # not done (zero-pod lanes) — state can never change again;
-            # the lane just samples to the horizon (host-side).
-            quies = active & all_arrived & ~pending_after & ~running_batch
-            active = active & ~quies
+            with jax.named_scope("cycle_end"):
+                all_arrived = (~valid | (arr_t <= t)).all(axis=1)
+                pending_after = (arrived & ~bound).any(axis=1)
+                running_batch = (valid & isb & bound & ~done_c).any(axis=1)
+                batch_done = (~valid | ~isb | done_c).all(axis=1)
+                svc_bound = (~valid | isb | bound).all(axis=1)
+                has_pods = valid.any(axis=1)
+                done_b = (active & has_pods & all_arrived & batch_done
+                          & svc_bound)
+                completed = completed | done_b
+                done_time = jnp.where(done_b, t, done_time)
+                done_is_cycle = done_is_cycle | done_b
+                active = active & ~done_b
+                # _permanently_stuck: static cluster, everything arrived,
+                # nothing placed, something blocked, nothing running.
+                stuck_now = (active & all_arrived & (placed == 0)
+                             & (blocked > 0) & ~running_batch & pending_after)
+                active = active & ~stuck_now
+                # Quiescent: all arrived, nothing pending, nothing running,
+                # not done (zero-pod lanes) — state can never change again;
+                # the lane just samples to the horizon (host-side).
+                quies = active & all_arrived & ~pending_after & ~running_batch
+                active = active & ~quies
             return (used_cpu, used_mem, pcount, done_c, done_t, bound,
                     bind_node, bind_seq, bind_cycle, active, completed,
-                    done_time, done_is_cycle, seq_ctr, scale_outs)
+                    done_time, done_is_cycle, seq_ctr, scale_outs,
+                    steps, busy)
 
         def cycle_body(st):
-            k = st[0]
+            k, state = st[0], st[1:16]
+            wave_steps, completion_steps, busy, active_cycles = st[16:]
+            active_cycles += state[9].sum(dtype=active_cycles.dtype)
             t = t_of[k]
             # POD_DONE events at times <= t all fire before CYCLE(t).
-            mid = completions(t, st[1:14])
-            out = wave(t, k, mid + st[14:])
-            return (k + 1,) + out
+            mid, completion_steps, busy = completions(
+                t, state[:13], completion_steps, busy)
+            *state, wave_steps, busy = wave(t, k, mid + state[13:],
+                                            wave_steps, busy)
+            return (k + 1, *state, wave_steps, completion_steps, busy,
+                    active_cycles)
 
         def cycle_cond(st):
             k, active = st[0], st[10]
@@ -447,21 +487,28 @@ def _program_factory(sched: str, n_pad: int):
             jnp.zeros(L, bool),                            # done_is_cycle
             jnp.zeros(L, jnp.int32),                       # seq_ctr
             jnp.zeros(L, jnp.int32),                       # scale_outs
+            jnp.zeros((), jnp.int32),                      # wave_steps
+            jnp.zeros((), jnp.int32),                      # completion_steps
+            jnp.zeros((), jnp.int64),                      # busy_lane_steps
+            jnp.zeros((), jnp.int64),                      # active_lane_cycles
         )
         (k, used_cpu, used_mem, pcount, done_c, done_t, bound,
          bind_node, bind_seq, bind_cycle, active, completed, done_time,
-         done_is_cycle, seq_ctr, scale_outs) = lax.while_loop(
-            cycle_cond, cycle_body, init)
+         done_is_cycle, seq_ctr, scale_outs, wave_steps, completion_steps,
+         busy, active_cycles) = lax.while_loop(cycle_cond, cycle_body, init)
         return {
             "bound": bound, "done_committed": done_c,
             "bind_node": bind_node, "bind_seq": bind_seq,
             "bind_cycle": bind_cycle, "done_t": done_t,
             "completed": completed, "done_time": done_time,
             "done_is_cycle": done_is_cycle, "scale_outs": scale_outs,
-            "n_cycles": k, "used_cpu": used_cpu, "used_mem": used_mem,
-            "pcount": pcount,
+            "n_cycles": k, "wave_steps": wave_steps,
+            "completion_steps": completion_steps, "busy_lane_steps": busy,
+            "active_lane_cycles": active_cycles,
+            "used_cpu": used_cpu, "used_mem": used_mem, "pcount": pcount,
         }
 
+    run.__name__ = run.__qualname__ = "lane_program_" + sched.replace("-", "_")
     return jax.jit(run)
 
 
@@ -485,12 +532,22 @@ def run_lane_batch(batch: LaneBatch) -> dict:
     serial parity maps ``node_slot`` through ``ClusterArrays.id_rank``),
     ``bind_seq`` (per-lane bind order), ``bind_cycle`` (bind time is
     exactly ``bind_cycle * 10.0``), ``done_t`` and ``done_committed``.
+    Per batch: the scalar step counts of :data:`COUNTERS`.
+
+    Spans of :data:`PROFILER`: ``lanes.dispatch`` (arguments and the jit
+    call), ``lanes.wait`` (until the device is done), ``lanes.fetch`` (the
+    copies to the host).
     """
     import jax
+    span = PROFILER.span
     with jax.enable_x64(True):
-        run = _jit_cache(batch.scheduler, batch.n_pad)
-        out = {key: np.asarray(v)
-               for key, v in run(*program_args(batch)).items()}
-    for key in ("done_t", "done_time", "used_mem"):
-        out[key] = out[key].view(np.float64)
+        with span("lanes.dispatch"):
+            run = _jit_cache(batch.scheduler, batch.n_pad)
+            dev = run(*program_args(batch))
+        with span("lanes.wait"):
+            jax.block_until_ready(dev)
+        with span("lanes.fetch"):
+            out = {key: np.asarray(v) for key, v in dev.items()}
+            for key in ("done_t", "done_time", "used_mem"):
+                out[key] = out[key].view(np.float64)
     return out

@@ -420,7 +420,7 @@ def save_bundle(bundle: Dict, path: str) -> None:
         meta["profile_n_spans_seen"] = prof["n_spans_seen"]
         for key in ("count", "total_s", "min_s", "max_s", "hist"):
             arrays[f"ph_{key}"] = np.asarray(prof[key])
-        for key in ("name", "t0", "dur_s", "sim_s"):
+        for key in ("name", "t0", "dur_s", "sim_s", "parent"):
             arrays[f"sp_{key}"] = np.asarray(prof["spans"][key])
     for key in ("node_count_t", "node_count_n", "pending_intervals"):
         if key in bundle:
@@ -445,8 +445,12 @@ def load_bundle(path: str) -> Dict:
             prof = bundle["profile"]
             for key in ("count", "total_s", "min_s", "max_s", "hist"):
                 prof[key] = np.asarray(prof[key])
+            spans = prof["spans"]
             for key in ("name", "t0", "dur_s", "sim_s"):
-                prof["spans"][key] = np.asarray(prof["spans"][key])
+                spans[key] = np.asarray(spans[key])
+            # A bundle saved before the ring had a parent column: -1, none.
+            spans["parent"] = np.asarray(
+                spans.get("parent", [-1] * len(spans["name"])), np.int64)
         for key in ("node_count_t", "node_count_n", "pending_intervals"):
             if key in bundle:
                 bundle[key] = np.asarray(bundle[key])
@@ -470,6 +474,9 @@ def load_bundle(path: str) -> Dict:
                    for key in ("count", "total_s", "min_s", "max_s", "hist")},
                 "spans": {key: z[f"sp_{key}"]
                           for key in ("name", "t0", "dur_s", "sim_s")}}
+            spans = bundle["profile"]["spans"]
+            spans["parent"] = (z["sp_parent"] if "sp_parent" in z else
+                               np.full(len(spans["name"]), -1, np.int64))
         for key in ("node_count_t", "node_count_n", "pending_intervals"):
             if key in z:
                 bundle[key] = z[key]

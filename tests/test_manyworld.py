@@ -21,7 +21,8 @@ jax = pytest.importorskip("jax")   # lane engine is JAX-gated by design
 from repro.cloud.adapter import M2_SMALL
 from repro.core import build_simulation, reset_id_counters
 from repro.manyworld import lanes as ml
-from repro.manyworld.evaluator import lane_eligible, run_cells_lanes
+from repro.manyworld.evaluator import (lane_calls, lane_eligible,
+                                      run_cells_lanes)
 from repro.scenarios.trace import KIND_BATCH
 from repro.search.runner import _RESULT_FIELDS, CellSpec, _get_trace, run_cells
 
@@ -361,3 +362,174 @@ class TestLaneExports:
         assert np.array_equal(snap2["used_mem"], arr.used_mem[rank])
         assert sim.orch.store.lane_columns()["arrival_t"].size \
             < cols["arrival_t"].size            # some rows left PENDING->BOUND
+
+
+def _big_lane():
+    """Two pods larger than a node: blocked in every cycle until stuck."""
+    return {"arrival_t": np.array([0.0, 5.0]),
+            "cpu_m": np.array([2000.0, 2000.0]),
+            "mem_mb": np.array([100.0, 100.0]),
+            "duration_s": np.array([60.0, 60.0]),
+            "is_batch": np.array([True, True]),
+            "n_nodes": 3, "alloc_cpu": ALLOC_CPU, "alloc_mem": ALLOC_MEM}
+
+
+COUNT_CASES = [(scen, sched, nw) for scen, sched, nw in CASES] + [
+    ("paper-bursty", "best-fit", 19), ("paper-slow", "best-fit", 19),
+    ("blocked", "best-fit", 3)]
+
+
+class TestLaneCounters:
+    @pytest.mark.parametrize("scen,sched,nw", COUNT_CASES)
+    def test_one_lane_counts_its_attempts(self, scen, sched, nw):
+        """One lane: a wave step is one attempt (a bind, or a blocked pod
+        counted once in each cycle it waits, as ``scale_outs`` counts it),
+        a completion step one commit, every inner step busy, and the lane
+        active in every outer cycle."""
+        lane = (_big_lane() if scen == "blocked"
+                else _lane_of(_get_trace(scen, 0, 40), nw))
+        out = ml.run_lane_batch(ml.stack_lanes([lane], sched))
+        binds = int(out["bound"][0].sum())
+        blocked = int(out["scale_outs"][0])
+        commits = int(out["done_committed"][0].sum())
+        assert int(out["wave_steps"]) == binds + blocked
+        assert int(out["completion_steps"]) == commits
+        assert int(out["busy_lane_steps"]) == binds + blocked + commits
+        assert int(out["active_lane_cycles"]) == int(out["n_cycles"]) > 0
+        if scen == "blocked":
+            assert binds == 0 and blocked >= 2
+
+    def test_many_lanes_occupancy_at_most_full(self):
+        """Several lanes in lockstep: busy lane-steps are every lane's
+        attempts and commits, each inner loop runs as long as its busiest
+        lane, and busy plus active lane-steps fill at most the lanes times
+        the steps."""
+        lanes = [_lane_of(_get_trace("heavy-tail", s, nj), nw)
+                 for s, nj, nw in ((0, 40, 4), (1, 40, 2), (2, 24, 3))]
+        lanes.append(_big_lane())
+        out = ml.run_lane_batch(ml.stack_lanes(lanes, "best-fit"))
+        L = len(lanes)
+        attempts = out["bound"].sum(axis=1) + out["scale_outs"]
+        commits = out["done_committed"].sum(axis=1)
+        assert int(out["busy_lane_steps"]) == int((attempts + commits).sum())
+        assert int(out["wave_steps"]) >= int(attempts.max())
+        assert int(out["completion_steps"]) >= int(commits.max())
+        steps = int(out["n_cycles"] + out["wave_steps"]
+                    + out["completion_steps"])
+        used = int(out["busy_lane_steps"] + out["active_lane_cycles"])
+        assert 0 < used <= L * steps
+        assert int(out["active_lane_cycles"]) <= L * int(out["n_cycles"])
+
+    def test_program_and_scopes_are_named(self):
+        """The compiled module is named after its scheduler and its ops
+        carry the loop scopes, so a trace says what ran."""
+        lane = _lane_of(_get_trace("heavy-tail", 0, 8), 2)
+        batch = ml.stack_lanes([lane], "worst-fit")
+        with jax.enable_x64(True):
+            text = ml._jit_cache("worst-fit", batch.n_pad).lower(
+                *ml.program_args(batch)).as_text(debug_info=True)
+        assert "lane_program_worst_fit" in text
+        for scope in ("completions", "wave", "cycle_end"):
+            assert f"/{scope}/" in text, scope
+
+
+def _span_cells():
+    """Three lane cells in two buckets (two schedulers) and one serial
+    fallback cell."""
+    base = dict(autoscaler="void", rescheduler="void", engine="array")
+    return [CellSpec(scenario="heavy-tail", scheduler="best-fit", seed=0,
+                     n_jobs=40, initial_workers=4, **base),
+            CellSpec(scenario="heavy-tail", scheduler="best-fit", seed=1,
+                     n_jobs=40, initial_workers=3, **base),
+            CellSpec(scenario="diurnal", scheduler="first-fit", seed=0,
+                     n_jobs=24, initial_workers=3, **base),
+            CellSpec(scenario="heavy-tail", scheduler="k8s-default", seed=0,
+                     n_jobs=16, initial_workers=3, **base)]
+
+
+CHILDREN = {"lanes.prepare", "lanes.stack", "lanes.dispatch", "lanes.wait",
+            "lanes.fetch", "lanes.rebuild"}
+
+
+class TestLaneSpans:
+    def test_one_call_records_one_rooted_tree(self):
+        """One call: one ``lanes.call`` root, its children named as the
+        phases, parented on the root and inside its interval; the call's
+        record sums their self times to the root's duration and its
+        counts to the buckets' program counts."""
+        prof = ml.PROFILER
+        first = prof.n_spans_seen
+        rows = run_cells_lanes(_span_cells())
+        sp = prof.to_payload()["spans"]
+        base = prof.n_spans_seen - len(sp["t0"])
+        ids = np.arange(base, prof.n_spans_seen)
+        mine = ids >= first
+        table = prof.to_payload()["names"]
+        names = [table[int(i)] for i in sp["name"][mine]]
+        t0, dur = sp["t0"][mine], sp["dur_s"][mine]
+        parent, own = sp["parent"][mine], ids[mine]
+        assert names.count("lanes.call") == 1
+        r = names.index("lanes.call")
+        assert parent[r] == -1
+        kids = [j for j in range(len(names)) if j != r]
+        assert {names[j] for j in kids} == CHILDREN
+        assert [names[j] for j in kids].count("lanes.prepare") == 1
+        for phase in CHILDREN - {"lanes.prepare"}:
+            assert [names[j] for j in kids].count(phase) == 2, phase
+        for j in kids:
+            assert parent[j] == own[r]
+            assert t0[r] <= t0[j] and t0[j] + dur[j] <= t0[r] + dur[r]
+        rec = lane_calls(1)[0]
+        assert (rec["lanes"], rec["buckets"]) == (3, 2)
+        assert set(rec["self_s"]) == CHILDREN | {"lanes.call"}
+        assert rec["wall_s"] == pytest.approx(dur[r], rel=1e-9)
+        assert sum(rec["self_s"].values()) == pytest.approx(dur[r])
+        assert all(v >= 0 for v in rec["self_s"].values())
+        assert rec["counts"]["lane_steps"] >= (
+            rec["counts"]["busy_lane_steps"]
+            + rec["counts"]["active_lane_cycles"]) > 0
+        assert len(rows) == 4 and all(r is not None for r in rows)
+
+    def test_second_identical_population_compiles_nothing(self):
+        cells = _span_cells()[:2]
+        ml._jit_cache.cache_clear()
+        run_cells_lanes(cells)
+        run_cells_lanes(cells)
+        first, second = lane_calls(2)
+        assert first["compiles"] == 1 and second["compiles"] == 0
+        assert second["call"] == first["call"] + 1
+        assert first["counts"] == second["counts"]
+        assert lane_calls(0) == []
+
+    def test_spans_sit_on_the_trace_host_plane(self, tmp_path):
+        """Under the JAX profiler the program's spans are host events
+        inside ``lanes.call``, on the clock of the program's operations:
+        on the CPU those run on host threads, between the dispatch and
+        the end of the wait."""
+        import glob
+
+        from jax.profiler import ProfileData
+        cells = _span_cells()[:2]
+        run_cells_lanes(cells)                    # compiled outside
+        with jax.profiler.trace(str(tmp_path)):
+            run_cells_lanes(cells)
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        spans, ops = [], []
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("lanes."):
+                        spans.append((ev.name, ev.start_ns, ev.duration_ns))
+                    elif ev.name.startswith("while"):
+                        ops.append((ev.start_ns, ev.duration_ns))
+        (call,) = [s for s in spans if s[0] == "lanes.call"]
+        c0, c1 = call[1], call[1] + call[2]
+        kids = {s[0]: s for s in spans if s[0] != "lanes.call"}
+        assert set(kids) == CHILDREN
+        assert all(c0 <= s[1] and s[1] + s[2] <= c1 for s in spans)
+        d0 = kids["lanes.dispatch"][1]
+        w1 = kids["lanes.wait"][1] + kids["lanes.wait"][2]
+        assert ops and all(d0 <= s and s + d <= w1 for s, d in ops)

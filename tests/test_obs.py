@@ -109,6 +109,56 @@ class TestProfiler:
             assert e["args"]["sim_s"] == 2.5
 
 
+    def test_span_tree_parents_and_self_times(self):
+        """Nested ``span``s record their parent's id; a ``stop`` span inside
+        a ``span`` takes it as parent; self times subtract the children
+        and sum to the root's duration."""
+        prof = PhaseProfiler(max_spans=16)
+        with prof.span("root") as root:
+            with prof.span("child") as child:
+                t0 = prof.start()
+                prof.stop("leaf", t0)
+            with prof.span("child"):
+                pass
+        with prof.span("other"):
+            pass
+        sp = prof.to_payload()["spans"]
+        names = prof.to_payload()["names"]
+        by_id = {i: (names[int(sp["name"][i])], int(sp["parent"][i]),
+                     float(sp["dur_s"][i])) for i in range(len(sp["t0"]))}
+        assert by_id[root][:2] == ("root", -1)
+        assert by_id[child][:2] == ("child", root)
+        assert [v[:2] for v in by_id.values()].count(("child", root)) == 2
+        assert ("leaf", child) in [v[:2] for v in by_id.values()]
+        assert by_id[max(by_id)][:2] == ("other", -1)
+        own = prof.self_times(root)
+        assert set(own) == {"root", "child", "leaf"}
+        assert sum(own.values()) == pytest.approx(by_id[root][2])
+        kids = sum(v[2] for v in by_id.values() if v[1] == root)
+        assert own["root"] == pytest.approx(by_id[root][2] - kids)
+        assert prof.phases()["child"]["count"] == 2
+
+    def test_self_times_empty_once_the_ring_drops_the_root(self):
+        prof = PhaseProfiler(max_spans=4)
+        with prof.span("root") as root:
+            pass
+        assert set(prof.self_times(root)) == {"root"}
+        for _ in range(4):
+            with prof.span("later"):
+                pass
+        assert prof.self_times(root) == {}
+        # A span whose slot was reused while it was open keeps its
+        # aggregate but leaves the newer span's slot alone.
+        with prof.span("long"):
+            for _ in range(5):
+                t0 = prof.start()
+                prof.stop("inner", t0)
+        assert prof.phases()["long"]["count"] == 1
+        sp = prof.to_payload()["spans"]
+        assert "long" not in [prof.to_payload()["names"][int(i)]
+                              for i in sp["name"]]
+
+
 # -- passive-recording contract on the full stack -----------------------------
 
 class TestBitIdentity:
@@ -168,10 +218,35 @@ class TestBundle:
         live = rec.prof.to_payload()
         assert back["profile"]["names"] == live["names"]
         assert np.array_equal(back["profile"]["count"], live["count"])
-        assert np.array_equal(back["profile"]["spans"]["dur_s"],
-                              live["spans"]["dur_s"])
+        for key in ("dur_s", "parent"):
+            assert np.array_equal(back["profile"]["spans"][key],
+                                  live["spans"][key])
         assert back["meta"]["engine"] == "array"
         assert back["meta"]["autoscaler"] == "predictive"
+
+    @pytest.mark.parametrize("suffix", [".npz", ".json"])
+    def test_bundle_without_parent_column_loads(self, tmp_path, recorded,
+                                                suffix):
+        """A bundle saved before the span ring had a parent column loads
+        with -1 (no parent) for every span."""
+        _result, rec = recorded
+        path = str(tmp_path / f"bundle{suffix}")
+        rec.export(path)
+        if suffix == ".json":
+            with open(path) as fh:
+                raw = json.load(fh)
+            del raw["profile"]["spans"]["parent"]
+            with open(path, "w") as fh:
+                json.dump(raw, fh)
+        else:
+            with np.load(path) as z:
+                arrays = {k: z[k] for k in z.files if k != "sp_parent"}
+            np.savez(path, **arrays)
+        spans = load_bundle(path)["profile"]["spans"]
+        assert spans["parent"].dtype == np.int64
+        assert spans["parent"].tolist() == [-1] * len(spans["name"])
+        assert np.array_equal(spans["dur_s"],
+                              rec.prof.to_payload()["spans"]["dur_s"])
 
     def test_node_count_series_exposed(self, recorded):
         """Satellite: the typed MetricsCollector.node_count_series rides
