@@ -17,16 +17,25 @@ returns one complete row list.
 the lane's share of its batch).  The lane program reproduces the bind
 sequence exactly; this module reconstructs the remaining
 ``ExperimentResult`` metrics host-side by replaying the serial event
-semantics over the lane outputs:
+semantics over the lane outputs, with array work over a bucket's lanes
+at once (:func:`_bucket_metrics`, in blocks of at most ``_REBUILD_BLOCK``
+lane-pods; lanes with no pod take the same path, without the program):
 
 * pending intervals are ``bind_time - submit_time`` per bound row in
   row order (the serial end-of-run column walk);
-* the 20 s utilisation samples are replayed with a pointer walk over the
-  bind/completion events in serial processing order — the event order
-  and the sample-tie rules (arrivals win ties; ``POD_DONE(t)`` precedes
+* the 20 s utilisation samples are replayed over the bind/completion
+  events in serial processing order — the event order and the
+  sample-tie rules (arrivals win ties; ``POD_DONE(t)`` precedes
   ``CYCLE(t)``; ``SAMPLE(t)`` ordering against both depends on push
   time) decide exactly which events each sample sees and which sample is
-  the last one recorded before a completed run breaks;
+  the last one recorded before a completed run breaks.  Node usage is a
+  sequential float64 accumulation in event order, as the serial engine
+  keeps it, and each distinct sample state is tabled once with the
+  number of samples that record it;
+* every sum the serial run rounds once (the sampler's ``math.fsum``,
+  ``statistics.fmean``) is rounded once here: :func:`_exact_sums`, a
+  vectorised correctly rounded sum that falls back to ``math.fsum``
+  wherever it cannot prove its rounding;
 * cost/node-seconds use the serial CostModel formulas for a static fleet
   billed from t=0 (one ``ceil`` per node, left-to-right accumulation).
 
@@ -41,8 +50,8 @@ bucket, ``lanes.stack``, ``lanes.dispatch``, ``lanes.wait``,
 ``lanes.fetch`` (those three in ``lanes.run_lane_batch``) and
 ``lanes.rebuild`` (the rows).  :func:`lane_calls` returns the records of
 the last calls: self time per span name, the program's step counts
-(``lanes.COUNTERS``) summed over buckets, and the lane, bucket and
-lane-program compilation counts.
+(``lanes.COUNTERS``) summed over buckets, the rebuild's own counts, and
+the lane, bucket and lane-program compilation counts.
 """
 from __future__ import annotations
 
@@ -50,9 +59,8 @@ import collections
 import dataclasses
 import itertools
 import math
-import statistics
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -94,8 +102,11 @@ def lane_calls(n: int) -> List[Dict]:
     program traces in the call), ``wall_s`` (the ``lanes.call`` span),
     ``self_s`` (self time per span name: the root's is what no child
     covers) and ``counts``: ``lanes.COUNTERS`` summed over the buckets,
-    and ``lane_steps``, each bucket's lanes times its steps (outer cycles
-    plus inner iterations), the most lanes that could have had work."""
+    ``lane_steps``, each bucket's lanes times its steps (outer cycles
+    plus inner iterations), the most lanes that could have had work,
+    ``sample_states``, the utilisation states the rebuild replayed over
+    all lanes, and ``rebuild_fallbacks``, its sums whose rounding the
+    vectorised sum could not prove (summed by ``math.fsum``)."""
     if n <= 0:
         return []
     return [dict(rec, self_s=dict(rec["self_s"]), counts=dict(rec["counts"]))
@@ -154,63 +165,130 @@ def _base_row(cell, trace, infeasible: bool) -> dict:
     return row
 
 
-def _grid_after(t: float) -> float:
-    """Smallest sample-grid time strictly greater than ``t``."""
-    return (math.floor(t / SAMPLE_PERIOD_S) + 1.0) * SAMPLE_PERIOD_S
+#: Columns summed at a time by :func:`_exact_sums`: a block's working
+#: vectors stay in cache through its passes.
+_SUM_BLOCK = 1 << 14
+#: Lanes times pod pad rebuilt at a time by :func:`_rebuild`: the
+#: rebuild's event and state arrays grow with both, so a bucket of long
+#: traces is rebuilt in blocks of lanes and its memory stays bounded.
+_REBUILD_BLOCK = 1 << 18
 
 
-def _on_grid(t: float) -> bool:
-    return math.fmod(t, SAMPLE_PERIOD_S) == 0.0
+def _exact_sums(x: np.ndarray) -> Tuple[np.ndarray, int]:
+    """``math.fsum(x[:, j])`` for every column ``j`` of the finite float64
+    array ``x``: the exact sum rounded once, ties to even.  Returns the
+    sums and how many columns the vectorised passes could not prove.
 
-
-def _lane_metrics(cell, trace, template, o: dict) -> dict:
-    """Reconstruct one cell's ExperimentResult fields from lane outputs.
-
-    ``o`` holds this lane's slices: per-pod ``bound`` / ``bind_node`` /
-    ``bind_seq`` / ``bind_cycle`` / ``done_t`` / ``done_committed`` and
-    per-lane ``completed`` / ``done_time`` / ``done_is_cycle`` /
-    ``scale_outs``.  Every formula below is the serial one, applied in
-    the serial order.
+    The rows are added with two passes of error-free additions (TwoSum),
+    so that the exact sum is ``s + r + f``: ``s`` the plain sum, ``r`` the
+    sum of its rounding errors, ``f`` the rounding errors of ``r``.  Where
+    ``f`` is all zero the exact sum is ``s + r``, and one IEEE addition
+    rounds it correctly.  Elsewhere ``s + r`` is kept only where its own
+    rounding error plus twice ``sum |f|`` stays clearly inside half the
+    smaller gap to a neighbouring float, which proves that no rounding
+    boundary lies between; every other column is summed by ``math.fsum``.
     """
-    n = trace.n
-    n_nodes = cell.initial_workers
-    alloc_cpu = float(template.allocatable.cpu_m)
-    alloc_mem = float(template.allocatable.mem_mb)
-    price = float(template.price_per_s)
+    res = np.empty(x.shape[1])
+    fallbacks = 0
+    for c in range(0, x.shape[1], _SUM_BLOCK):
+        xb = x[:, c:c + _SUM_BLOCK]
+        s = xb[0].copy()
+        r = np.zeros_like(s)
+        f_abs = np.zeros_like(s)
+        t, z, w, e = (np.empty_like(s) for _ in range(4))
+        for b in xb[1:]:
+            np.add(s, b, out=t)                    # TwoSum(s, b) -> t, e
+            np.subtract(t, s, out=z)
+            np.subtract(s, np.subtract(t, z, out=w), out=w)
+            np.add(w, np.subtract(b, z, out=e), out=e)
+            s, t = t, s
+            np.add(r, e, out=t)                    # TwoSum(r, e) -> t, f
+            np.subtract(t, r, out=z)
+            np.subtract(r, np.subtract(t, z, out=w), out=w)
+            np.add(w, np.subtract(e, z, out=z), out=w)
+            f_abs += np.abs(w, out=w)
+            r, t = t, r
+        sum_b = s + r
+        z = sum_b - s
+        err = (s - (sum_b - z)) + (r - z)
+        a = np.abs(sum_b)
+        gap = np.minimum(np.nextafter(a, np.inf) - a,
+                         a - np.nextafter(a, 0.0))
+        proven = (f_abs == 0.0) | ((a >= 2.0 ** -900) & (
+            np.abs(err) + 2.0 * f_abs < 0.5 * gap * (1.0 - 2.0 ** -20)))
+        slow = np.flatnonzero(~proven)
+        if slow.size:
+            sum_b[slow] = [math.fsum(col)
+                           for col in xb[:, slow].T.tolist()]
+            fallbacks += slow.size
+        res[c:c + _SUM_BLOCK] = sum_b
+    return res, fallbacks
 
-    bound = o["bound"][:n]
-    committed = o["done_committed"][:n]
-    bind_t = o["bind_cycle"][:n].astype(np.float64) * CYCLE_PERIOD_S
-    done_t = o["done_t"][:n]
-    seq = o["bind_seq"][:n]
-    node = o["bind_node"][:n]
-    cpu = trace.cpu_m.astype(np.float64)
-    mem = trace.mem_mb.astype(np.float64)
-    completed = bool(o["completed"])
-    done_time = float(o["done_time"])
+
+def _split(v: np.ndarray):
+    """Veltkamp's split: ``v == hi + lo`` exactly, each half with at most
+    26 significant bits, so ``m * hi`` and ``m * lo`` are exact for any
+    integer ``m < 2**27``."""
+    p = v * 134217729.0                    # 2**27 + 1
+    hi = p - (p - v)
+    return hi, v - hi
+
+
+def _bucket_metrics(entries: list, batch, out: dict):
+    """One bucket's ExperimentResult fields, rebuilt from its lane outputs
+    with array work over the whole bucket.
+
+    ``batch`` is the bucket's :class:`~repro.manyworld.lanes.LaneBatch`
+    (pod columns and cluster scalars), ``out`` the program's per-pod
+    ``bound`` / ``bind_node`` / ``bind_seq`` / ``bind_cycle`` / ``done_t``
+    / ``done_committed`` and per-lane ``completed`` / ``done_time`` /
+    ``done_is_cycle`` / ``scale_outs``.  Returns one field dict per lane,
+    the sample states replayed and the sums that fell back to
+    ``math.fsum``.  Every formula is the serial one, applied in the serial
+    order, and every float sum that the serial run rounds once (``fsum``,
+    ``fmean``) is rounded once here (:func:`_exact_sums`).
+    """
+    SP = SAMPLE_PERIOD_S
+    L, P = batch.valid.shape
+    lanes = np.arange(L)
+    valid = batch.valid
+    n_nodes = batch.n_nodes.astype(np.int64)
+    N = int(n_nodes.max())
+    acpu = np.maximum(batch.alloc_cpu.astype(np.float64), 1.0)
+    price = np.array([float(e[3].price_per_s) for e in entries])
+
+    bound = out["bound"] & valid
+    done = out["done_committed"] & valid
+    bind_t = out["bind_cycle"].astype(np.float64) * CYCLE_PERIOD_S
+    done_t, seq = out["done_t"], out["bind_seq"]
+    completed, te = out["completed"], out["done_time"]
 
     # -- end of run (simulation.run: last_batch_done wins when truthy) --
-    if completed:
-        lbd = float(done_t[committed].max()) if committed.any() else 0.0
-        end = lbd if lbd else done_time
-        te = done_time
-    else:
-        end = HORIZON_S            # samples run the clock to the horizon
-        te = None
-
-    arr0 = float(trace.arrival_time[0]) if n else None
-    start = arr0 if (arr0 is not None and arr0 <= HORIZON_S) else 0.0
+    lbd_raw = np.where(done, done_t, -np.inf).max(axis=1)
+    lbd = np.where(done.any(axis=1), lbd_raw, 0.0)
+    end = np.where(completed, np.where(lbd != 0.0, lbd, te), HORIZON_S)
+    a0 = batch.arrival_t[:, 0]
+    start = np.where(valid[:, 0] & (a0 <= HORIZON_S), a0, 0.0)
 
     # -- pending intervals (store.pending_intervals_all: bound rows only,
     # row order; void/void never rebinds so one interval per pod) --------
-    pend = (bind_t[bound] - trace.arrival_time[bound].astype(np.float64)
-            ).tolist()
+    pend = bind_t - batch.arrival_t
+    n_pend = bound.sum(axis=1)
+    pend_sum, fallbacks = _exact_sums(np.where(bound, pend, 0.0).T.copy())
+    srt = np.sort(np.where(bound, pend, np.inf), axis=1)
+    half = n_pend // 2
+    median = np.where(n_pend % 2 == 1, srt[lanes, half],
+                      (srt[lanes, np.maximum(half - 1, 0)]
+                       + srt[lanes, half]) / 2)
+    top = srt[lanes, np.maximum(n_pend - 1, 0)]
 
     # -- utilisation sample replay --------------------------------------
-    # Events in serial processing order: (time, kind, bind_seq) with
-    # POD_DONE (0) before the cycle's binds (1) at equal times; equal-time
-    # completions fire in scheduling-push order == ascending bind_seq.
-    # Each event carries the first sample time that can see it:
+    # Each lane's events (its completions, then its binds: 2P columns) in
+    # serial processing order, (time, kind, bind_seq): POD_DONE (0)
+    # before the cycle's binds (1) at equal times; equal-time completions
+    # fire in scheduling-push order == ascending bind_seq.  Padding and
+    # pods without the event sort last.  Each event carries the first
+    # sample (grid index k, time 20 k) that can see it:
     # * a bind at cycle tc is visible from the next grid point after tc
     #   (SAMPLE(t) runs before CYCLE(t) for t>0) — except cycle 0, whose
     #   binds sample at t=0 (run() pushes CYCLE(0) before SAMPLE(0));
@@ -218,134 +296,172 @@ def _lane_metrics(cell, trace, template, o: dict) -> dict:
     #   and its POD_DONE was pushed (at its bind cycle tc) before
     #   SAMPLE(td) was (at td-20) — i.e. tc < td-20, or the cycle-0
     #   corner tc==0, td==20 — else from the next grid point after td.
-    SP = SAMPLE_PERIOD_S
-    bi = np.nonzero(bound)[0]
-    tb = bind_t[bi]
-    sv_b = np.where(tb == 0.0, 0.0, (np.floor(tb / SP) + 1.0) * SP)
-    di = np.nonzero(committed)[0]
-    td_a = done_t[di]
-    tc_a = bind_t[di]
-    done_early = ((np.fmod(td_a, SP) == 0.0)
-                  & ((tc_a < td_a - SP) | ((tc_a == 0.0) & (td_a == SP))))
-    sv_d = np.where(done_early, td_a, (np.floor(td_a / SP) + 1.0) * SP)
-    ev_t = np.concatenate([td_a, tb])
-    ev_kind = np.concatenate([np.zeros(di.size, np.int8),
-                              np.ones(bi.size, np.int8)])
-    ev_seq = np.concatenate([seq[di], seq[bi]])
-    order = np.lexsort((ev_seq, ev_kind, ev_t))
-    ev_sv = np.concatenate([sv_d, sv_b])[order].tolist()
-    ev_node = np.concatenate([node[di], node[bi]])[order].tolist()
-    ev_dcpu = np.concatenate([-cpu[di], cpu[bi]])[order].tolist()
-    ev_dmem = np.concatenate([-mem[di], mem[bi]])[order].tolist()
-    ev_dp = np.concatenate([np.full(di.size, -1), np.ones(bi.size)]
-                           )[order].astype(np.int64).tolist()
-    n_ev = len(ev_sv)
+    td = np.where(done, done_t, 0.0)
+    early = ((np.fmod(td, SP) == 0.0)
+             & ((bind_t < td - SP) | ((bind_t == 0.0) & (td == SP))))
+    kd = np.where(early, td / SP, np.floor(td / SP) + 1.0)
+    kb = np.where(bind_t == 0.0, 0.0, np.floor(bind_t / SP) + 1.0)
+    order = np.lexsort((np.concatenate([seq, seq], axis=1),
+                        np.broadcast_to(np.arange(2 * P) >= P, (L, 2 * P)),
+                        np.concatenate([np.where(done, done_t, np.inf),
+                                        np.where(bound, bind_t, np.inf)],
+                                       axis=1)), axis=1)
+    is_done = order < P
+    ev_pod = order % P
 
-    # Which samples were recorded before the run ended?  Non-completed
-    # lanes sample the whole horizon.  A completed lane breaks on its
-    # trigger event at te: every grid point strictly before te is in; the
-    # grid point *at* te is in iff the trigger ran after SAMPLE(te) —
-    # for a CYCLE trigger that is every te>0, for a POD_DONE trigger it
-    # is the complement of the completion-visibility push rule above,
-    # judged on the trigger pod (the last-committed one).
-    if not completed:
-        last_s = HORIZON_S
-    else:
-        if _on_grid(te) and te > 0.0:
-            if o["done_is_cycle"]:
-                last_s = te
-            else:
-                ic = np.nonzero(committed)[0]
-                trig = ic[np.lexsort((seq[ic], done_t[ic]))[-1]]
-                tc = float(bind_t[trig])
-                pod_done_first = (tc < te - SAMPLE_PERIOD_S
-                                  or (tc == 0.0 and te == SAMPLE_PERIOD_S))
-                last_s = te if not pod_done_first else te - SAMPLE_PERIOD_S
-        else:
-            last_s = (math.ceil(te / SAMPLE_PERIOD_S) - 1.0) * SAMPLE_PERIOD_S
-            if _on_grid(te):       # te == 0: CYCLE(0) broke before SAMPLE(0)
-                last_s = te - SAMPLE_PERIOD_S
+    def at_events(x):
+        return np.take_along_axis(x, ev_pod, axis=1)
 
-    ram_vals: List[float] = []
-    cpu_vals: List[float] = []
-    ppn_vals: List[float] = []
-    used_cpu = [0.0] * n_nodes
-    used_mem = [0.0] * n_nodes
-    pods = 0
-    acpu = max(alloc_cpu, 1)       # serial: np.maximum(alloc_cpu, 1)
-    ptr = 0
-    s = 0.0
-    while s <= last_s:
-        while ptr < n_ev and ev_sv[ptr] <= s:
-            nd = ev_node[ptr]
-            used_cpu[nd] += ev_dcpu[ptr]
-            used_mem[nd] += ev_dmem[ptr]
-            pods += ev_dp[ptr]
-            ptr += 1
-        # Serial sampler: exact fsum of per-node IEEE ratios, / n.
-        cur_ram = math.fsum(u / alloc_mem for u in used_mem) / n_nodes
-        cur_cpu = math.fsum(u / acpu for u in used_cpu) / n_nodes
-        cur_ppn = float(pods) / n_nodes
-        # `ev_sv` is non-decreasing in commit order, so the state stays
-        # constant until the next event becomes visible (or the run
-        # ends): emit the whole constant run of samples in one extend.
-        if ptr == n_ev or ev_sv[ptr] > last_s:
-            run_end = last_s
-        else:
-            run_end = ev_sv[ptr] - SAMPLE_PERIOD_S
-        m = int((run_end - s) / SAMPLE_PERIOD_S) + 1
-        ram_vals.extend([cur_ram] * m)
-        cpu_vals.extend([cur_cpu] * m)
-        ppn_vals.extend([cur_ppn] * m)
-        s += m * SAMPLE_PERIOD_S
+    live = np.where(is_done, at_events(done), at_events(bound))
+    # A sample applies the events in order up to the first it cannot see
+    # yet, so an event is applied at the first sample at or after every
+    # visibility time up to it: a running maximum along the lane.
+    ev_k = np.maximum.accumulate(
+        np.where(live, np.where(is_done, at_events(kd), at_events(kb)), 0.0),
+        axis=1).astype(np.int64)
+
+    # The last sample recorded.  Non-completed lanes sample the whole
+    # horizon.  A completed lane breaks on its trigger event at te: every
+    # grid point strictly before te is in; the grid point *at* te is in
+    # iff the trigger ran after SAMPLE(te) — for a CYCLE trigger that is
+    # every te>0, for a POD_DONE trigger it is the complement of the
+    # completion-visibility push rule above, judged on the trigger pod
+    # (the last committed: latest done_t, then highest bind_seq).
+    trig = np.argmax(np.where(done & (done_t == lbd_raw[:, None]), seq, -1),
+                     axis=1)
+    tc = bind_t[lanes, trig]
+    pod_done_first = (tc < te - SP) | ((tc == 0.0) & (te == SP))
+    last_k = np.where(
+        ~completed, HORIZON_S / SP,
+        np.where((np.fmod(te, SP) == 0.0) & (te > 0.0),
+                 np.where(out["done_is_cycle"] | ~pod_done_first,
+                          te / SP, te / SP - 1.0),
+                 np.ceil(te / SP) - 1.0)).astype(np.int64)
+
+    # Sample states: a lane holds one state from grid point 0 and a new
+    # one from each later visibility time up to its last sample, each
+    # recorded for ``m`` samples.  States are numbered lane by lane.
+    applied = live & (ev_k <= last_k[:, None])
+    same_k = np.zeros_like(applied)
+    same_k[:, 1:] = ev_k[:, 1:] == ev_k[:, :-1]
+    new = applied & (ev_k > 0) & ~same_k
+    per_lane = np.where(last_k >= 0, 1 + new.sum(axis=1), 0)
+    first = np.cumsum(per_lane) - per_lane
+    n_seg = int(per_lane.sum())
+    seg = first[:, None] + np.cumsum(new, axis=1)
+    seg_lane = np.repeat(lanes, per_lane)
+    seg_k = np.zeros(n_seg, np.int64)
+    seg_k[seg[new]] = ev_k[new]
+    m = np.append(seg_k[1:] - seg_k[:-1], 0)
+    has_seg = per_lane > 0
+    ends = (first + per_lane - 1)[has_seg]
+    m[ends] = last_k[has_seg] - seg_k[ends] + 1
+
+    # Each state's node usage: ``used[node] += delta`` in event order, a
+    # sequential float64 accumulation, one event column at a time for
+    # every lane at once.  A state is the usage after its last event; the
+    # table holds them in the order the walk closes them, then the zero
+    # states (grid point 0 before any event), whose usage stays zero.
+    closes = applied.copy()
+    closes[:, :-1] &= ~(applied[:, 1:] & same_k[:, 1:])
+    sign = np.where(is_done, -1, 1)
+    mem = at_events(batch.mem_mb)
+    cpu = at_events(batch.cpu_m).astype(np.float64)
+    walk = [x.T.copy() for x in (
+        np.where(live, at_events(out["bind_node"]), 0) * L + lanes[:, None],
+        np.where(live, sign * mem, 0.0), np.where(live, sign * cpu, 0.0))]
+    col, lane_at = np.nonzero(closes.T)
+    zero = first[has_seg & ~(applied & (ev_k == 0)).any(axis=1)]
+    table = np.concatenate([seg[lane_at, col], zero])
+    cuts = np.cumsum(np.bincount(col, minlength=2 * P))
+    used_mem = np.zeros((N, L))
+    used_cpu = np.zeros((N, L))
+    state_mem = np.zeros((N, n_seg))
+    state_cpu = np.zeros((N, n_seg))
+    lo = 0
+    n_cols = applied.any(axis=0).sum()
+    for flat, d_mem, d_cpu, hi in zip(*walk, cuts[:n_cols]):
+        used_mem.ravel()[flat] += d_mem
+        used_cpu.ravel()[flat] += d_cpu
+        state_mem[:, lo:hi] = used_mem[:, lane_at[lo:hi]]
+        state_cpu[:, lo:hi] = used_cpu[:, lane_at[lo:hi]]
+        lo = hi
+    pods = np.zeros(n_seg, np.int64)
+    pods[seg[closes]] = np.cumsum(applied * sign, axis=1)[closes]
+
+    # Serial sampler: exact fsum of per-node IEEE ratios, / n; each
+    # lane's average is fmean over its samples, m copies of each state:
+    # m * v splits into two exact products (:func:`_split`).
+    nn = n_nodes[seg_lane]
+    ram, cpu_r = np.empty(n_seg), np.empty(n_seg)
+    ram[table], n = _exact_sums(state_mem
+                                / batch.alloc_mem[seg_lane[table]])
+    fallbacks += n
+    cpu_r[table], n = _exact_sums(state_cpu / acpu[seg_lane[table]])
+    fallbacks += n
+    hi, lo = _split(np.stack([ram / nn, cpu_r / nn,
+                              pods.astype(np.float64) / nn], axis=1))
+    pos = np.arange(n_seg) - first[seg_lane]
+    terms = np.zeros((2 * max(int(per_lane.max()), 1), L, 3))
+    terms[2 * pos, seg_lane] = m[:, None] * hi
+    terms[2 * pos + 1, seg_lane] = m[:, None] * lo
+    sums, n = _exact_sums(terms.reshape(terms.shape[0], 3 * L))
+    fallbacks += n
+    n_samples = np.bincount(seg_lane, m, minlength=L).astype(np.int64)
+    avgs = np.where((n_samples > 0)[:, None],
+                    sums.reshape(L, 3) / np.maximum(n_samples, 1)[:, None],
+                    0.0)
 
     # -- cost (CostModel: N static nodes billed 0 -> end, ceil'd, summed
     # left-to-right in record order) ------------------------------------
-    secs = float(np.ceil(np.maximum(0.0, np.float64(end))))
-    term = float(np.float64(secs) * np.float64(price))
-    cost = 0.0
-    for _ in range(n_nodes):
-        cost += term
-    node_seconds = int(secs * n_nodes)
+    secs = np.ceil(np.maximum(0.0, end))
+    term = secs * price
+    cost = np.zeros(L)
+    for k in range(N):
+        cost = np.where(k < n_nodes, cost + term, cost)
 
-    return {
-        "completed": completed,
+    has = n_pend > 0
+    zeros = np.zeros(L, np.int64)
+    cols = {
+        "completed": completed.astype(bool),
         "cost": cost,
         "duration_s": end - start,
-        "mean_pending_s": statistics.fmean(pend) if pend else 0.0,
-        "median_pending_s": statistics.median(pend) if pend else 0.0,
-        "max_pending_s": max(pend) if pend else 0.0,
-        "avg_ram_ratio": statistics.fmean(ram_vals) if ram_vals else 0.0,
-        "avg_cpu_ratio": statistics.fmean(cpu_vals) if cpu_vals else 0.0,
-        "avg_pods_per_node": statistics.fmean(ppn_vals) if ppn_vals else 0.0,
-        "max_nodes": n_nodes if ram_vals else 0,
-        "node_seconds": node_seconds,
-        "evictions": 0,
-        "scale_outs": int(o["scale_outs"]),
-        "scale_ins": 0,
-        "failures_injected": 0,
-        "preemption_notices": 0,
-        "lost_work_s": 0.0,
+        "mean_pending_s": np.where(has, pend_sum / np.maximum(n_pend, 1),
+                                   0.0),
+        "median_pending_s": np.where(has, median, 0.0),
+        "max_pending_s": np.where(has, top, 0.0),
+        "avg_ram_ratio": avgs[:, 0],
+        "avg_cpu_ratio": avgs[:, 1],
+        "avg_pods_per_node": avgs[:, 2],
+        "max_nodes": np.where(n_samples > 0, n_nodes, 0),
+        "node_seconds": (secs * n_nodes).astype(np.int64),
+        "evictions": zeros,
+        "scale_outs": out["scale_outs"].astype(np.int64),
+        "scale_ins": zeros,
+        "failures_injected": zeros,
+        "preemption_notices": zeros,
+        "lost_work_s": np.zeros(L),
     }
+    names = list(cols)
+    metrics = [dict(zip(names, vals))
+               for vals in zip(*(cols[k].tolist() for k in names))]
+    return metrics, n_seg, fallbacks
 
 
-def _zero_pod_metrics(cell, template) -> dict:
-    """A lane with an empty trace never completes: the empty static
-    cluster just samples flat zeros to the horizon (handled without JAX)."""
-    o = {"bound": np.zeros(0, bool), "done_committed": np.zeros(0, bool),
-         "bind_cycle": np.zeros(0, np.int32), "done_t": np.zeros(0),
-         "bind_seq": np.zeros(0, np.int32), "bind_node": np.zeros(0, np.int32),
-         "completed": False, "done_time": HORIZON_S, "done_is_cycle": False,
-         "scale_outs": 0}
-    empty = _EmptyTrace()
-    return _lane_metrics(cell, empty, template, o)
-
-
-class _EmptyTrace:
-    n = 0
-    arrival_time = np.zeros(0)
-    cpu_m = np.zeros(0, np.int64)
-    mem_mb = np.zeros(0)
+def _no_steps(batch) -> dict:
+    """The lane outputs of a program that takes no step, as for lanes
+    with no pod: nothing bound or committed, never completed."""
+    L, P = batch.valid.shape
+    return {"bound": np.zeros((L, P), bool),
+            "done_committed": np.zeros((L, P), bool),
+            "bind_node": np.full((L, P), -1, np.int32),
+            "bind_seq": np.full((L, P), -1, np.int32),
+            "bind_cycle": np.full((L, P), -1, np.int32),
+            "done_t": np.full((L, P), np.inf),
+            "completed": np.zeros(L, bool),
+            "done_time": np.full(L, HORIZON_S),
+            "done_is_cycle": np.zeros(L, bool),
+            "scale_outs": np.zeros(L, np.int32)}
 
 
 def run_cells_lanes(cells: Sequence) -> List[dict]:
@@ -357,11 +473,12 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
     _count_compiles()
     compiles0 = _compiles
     span = _lanes.PROFILER.span
-    counts = dict.fromkeys(_lanes.COUNTERS + ("lane_steps",), 0)
+    counts = dict.fromkeys(_lanes.COUNTERS + (
+        "lane_steps", "sample_states", "rebuild_fallbacks"), 0)
     n_lanes = 0
     with span("lanes.call") as root:
         with span("lanes.prepare"):
-            buckets = _prepare(cells, rows)
+            buckets, idle = _prepare(cells, rows)
         for (sched, p_pad, _n_pad), entries in buckets.items():
             t0 = time.perf_counter()
             with span("lanes.stack"):
@@ -377,7 +494,18 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
                     got["n_cycles"] + got["wave_steps"]
                     + got["completion_steps"])
                 n_lanes += len(entries)
-                _rebuild(entries, out, share, rows)
+                _rebuild(entries, batch, out, share, rows, counts)
+        if idle:
+            # Lanes with no pod take no program step: their rows come
+            # from the same rebuild, without JAX.
+            with span("lanes.rebuild"):
+                t0 = time.perf_counter()
+                batch = _lanes.stack_lanes([e[4] for e in idle],
+                                           idle[0][1].scheduler)
+                _rebuild(idle, batch, _no_steps(batch), 0.0, rows, counts)
+                share = (time.perf_counter() - t0) / len(idle)
+                for e in idle:
+                    rows[e[0]]["wall_s"] = share
     self_s = _lanes.PROFILER.self_times(root)
     _CALLS.append({"call": next(_CALL_IDS), "lanes": n_lanes,
                    "buckets": len(buckets),
@@ -388,13 +516,13 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
     return rows
 
 
-def _prepare(cells: list, rows: list) -> dict:
+def _prepare(cells: list, rows: list):
     """Fill ``rows`` for the cells that need no lane, and bucket the rest:
     ``(scheduler, pod-pad, node-pad) -> [(idx, cell, trace, template,
-    lane arrays)]``."""
+    lane arrays)]``; lanes with no pod go apart, to the second list."""
     from repro.search.runner import (CellError, _get_trace, _infeasible,
                                      run_cell)
-    buckets = {}
+    buckets, idle = {}, []
     for idx, cell in enumerate(cells):
         try:
             if not lane_eligible(cell):
@@ -405,37 +533,49 @@ def _prepare(cells: list, rows: list) -> dict:
             if _infeasible(cell, trace):
                 rows[idx] = _base_row(cell, trace, infeasible=True)
                 continue
-            if trace.n == 0:
-                t0 = time.perf_counter()
-                row = _base_row(cell, trace, infeasible=False)
-                row.update(_zero_pod_metrics(cell, template))
-                row["wall_s"] = time.perf_counter() - t0
-                rows[idx] = row
-                continue
             lane = trace.to_lane_arrays()
             lane["n_nodes"] = cell.initial_workers
             lane["alloc_cpu"] = template.allocatable.cpu_m
             lane["alloc_mem"] = float(template.allocatable.mem_mb)
+            entry = (idx, cell, trace, template, lane)
+            if trace.n == 0:
+                idle.append(entry)
+                continue
             key = (cell.scheduler, next_pow2(trace.n),
                    next_pow2(cell.initial_workers))
-            buckets.setdefault(key, []).append((idx, cell, trace, template,
-                                                lane))
+            buckets.setdefault(key, []).append(entry)
         except CellError:
             raise
         except Exception as exc:
             raise CellError(f"cell {cell.label} failed: {exc!r}") from exc
-    return buckets
+    return buckets, idle
 
 
-def _rebuild(entries: list, out: dict, share: float, rows: list) -> None:
-    """One bucket's rows from its lane outputs (counters taken out)."""
+def _rebuild(entries: list, batch, out: dict, share: float, rows: list,
+             counts: dict) -> None:
+    """One bucket's rows from its lane outputs (counters taken out), in
+    blocks of ``_REBUILD_BLOCK`` lane-pods; adds the sample states and
+    ``math.fsum`` fallbacks to ``counts``."""
     from repro.search.runner import CellError
-    for li, (idx, cell, trace, template, _lane) in enumerate(entries):
-        o = {key: val[li] for key, val in out.items()}
+    step = max(1, _REBUILD_BLOCK // batch.p_pad)
+    arrays = [f.name for f in dataclasses.fields(batch)
+              if f.name != "scheduler"]
+    for lo in range(0, len(entries), step):
+        lanes = slice(lo, lo + step)
+        block = entries[lanes]
+        sub = dataclasses.replace(
+            batch, **{name: getattr(batch, name)[lanes] for name in arrays})
         try:
+            metrics, states, fallbacks = _bucket_metrics(
+                block, sub, {key: val[lanes] for key, val in out.items()})
+        except Exception as exc:
+            raise CellError(f"{len(block)} lane cells from "
+                            f"{block[0][1].label} failed: {exc!r}") from exc
+        for (idx, cell, trace, _template, _lane), fields in zip(block,
+                                                              metrics):
             row = _base_row(cell, trace, infeasible=False)
-            row.update(_lane_metrics(cell, trace, template, o))
+            row.update(fields)
             row["wall_s"] = share
             rows[idx] = row
-        except Exception as exc:
-            raise CellError(f"cell {cell.label} failed: {exc!r}") from exc
+        counts["sample_states"] += states
+        counts["rebuild_fallbacks"] += fallbacks

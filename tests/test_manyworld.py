@@ -9,8 +9,10 @@ and the evaluator reconstructs `run_cells` rows whose 17 metric fields are
 bitwise equal to the serial runner's.  The edge battery pins the padding
 and masking behaviors (zero-pod lanes, all-infeasible lanes, non-pow2 lane
 counts, mixed lane sizes in one bucket) and the integer IEEE-754 add the
-program does its float arithmetic with.
+program does its float arithmetic with; hand-built corner lanes and
+bitwise ``math.fsum`` cases pin the host's bucket-wide rebuild.
 """
+import dataclasses
 import math
 
 import numpy as np
@@ -20,10 +22,14 @@ jax = pytest.importorskip("jax")   # lane engine is JAX-gated by design
 
 from repro.cloud.adapter import M2_SMALL
 from repro.core import build_simulation, reset_id_counters
+from repro.core.pods import PodKind, PodSpec
+from repro.core.resources import Resources
+from repro.manyworld import evaluator as ev
 from repro.manyworld import lanes as ml
-from repro.manyworld.evaluator import (lane_calls, lane_eligible,
-                                      run_cells_lanes)
-from repro.scenarios.trace import KIND_BATCH
+from repro.manyworld.evaluator import (_exact_sums, _split, lane_calls,
+                                      lane_eligible, run_cells_lanes)
+from repro.scenarios import register
+from repro.scenarios.trace import KIND_BATCH, TraceStore
 from repro.search.runner import _RESULT_FIELDS, CellSpec, _get_trace, run_cells
 
 ALLOC_CPU = float(M2_SMALL.allocatable.cpu_m)
@@ -533,3 +539,254 @@ class TestLaneSpans:
         d0 = kids["lanes.dispatch"][1]
         w1 = kids["lanes.wait"][1] + kids["lanes.wait"][2]
         assert ops and all(d0 <= s and s + d <= w1 for s, d in ops)
+
+
+# -- the bucket-wide rebuild -------------------------------------------------
+
+TINY = 3584.0 * 2.0 ** -53        # a memory ratio of 2**-53 on m2.small
+#: Hand-built lanes, (nodes, pods as (arrival, cpu_m, mem_mb, duration, or
+#: None for a service)); each pins corners of the sample replay.
+CORNER_LANES = [
+    # binds at cycle 0; completions on the grid: td == 20 bound at cycle 0,
+    # td == 40 bound at 0 (tc < td - 20) and at 30 (not); the POD_DONE of
+    # the pod bound at 30 completes the lane at te == 40
+    (4, [(0.0, 300, 512.0, 20.0), (0.0, 300, 512.0, 40.0),
+         (25.0, 300, 512.0, 10.0)]),
+    # a POD_DONE trigger at te == 60 pushed at cycle 0, before SAMPLE(60)
+    (3, [(0.0, 300, 512.0, 60.0), (5.0, 300, 512.0, 15.0),
+         (7.0, 200, 256.0, 30.0)]),
+    # a CYCLE trigger on the grid: the service bound at cycle 100
+    (4, [(0.0, 300, 512.0, 30.0), (2.0, 100, 100.0, 18.0),
+         (95.0, 200, 256.0, None)]),
+    # a CYCLE trigger off the grid: the service bound at cycle 50
+    (3, [(0.0, 300, 512.0, 30.0), (3.0, 100, 100.0, 7.0),
+         (45.0, 200, 256.0, None)]),
+    # never completes: the fourth service never fits, so the lane samples
+    # to the horizon
+    (3, [(0.0, 800, 512.0, None)] * 3 + [(5.0, 800, 512.0, None)]),
+    (4, []),                                               # no pod
+    # a POD_DONE trigger at te == 20 bound at cycle 0: the corner in which
+    # it still precedes SAMPLE(20)
+    (3, [(0.0, 300, 512.0, 20.0), (0.0, 200, 256.0, 10.0),
+         (3.0, 100, 100.0, 7.0)]),
+    # a node sum just above a rounding midpoint, 1 + 2**-53 + 2**-200: the
+    # vectorised sum cannot prove its rounding and falls back
+    (3, [(0.0, 600, 3584.0, 40.0), (0.0, 600, TINY, 40.0),
+         (0.0, 600, TINY * 2.0 ** -147, 40.0)]),
+]
+FALLBACK_LANE = len(CORNER_LANES) - 1
+
+
+def _corner_trace(seed, _n_jobs):
+    _nodes, pods = CORNER_LANES[seed % len(CORNER_LANES)]
+    specs = [PodSpec(f"p{i}", PodKind.SERVICE if dur is None
+                     else PodKind.BATCH, Resources(cpu, mem),
+                     duration_s=dur or 0.0, moveable=dur is None)
+             for i, (_t, cpu, mem, dur) in enumerate(pods)]
+    return TraceStore(specs, np.arange(len(pods)), [p[0] for p in pods],
+                      duration_s=[p[3] or 0.0 for p in pods],
+                      name="rebuild-corners")
+
+
+@pytest.fixture(scope="module")
+def corner_cells():
+    register("rebuild-corners", _corner_trace, overwrite=True)
+    return [CellSpec(scenario="rebuild-corners", scheduler=sched,
+                     autoscaler="void", rescheduler="void", seed=i,
+                     engine="array", initial_workers=nodes)
+            for sched in ("best-fit", "first-fit")
+            for i, (nodes, _pods) in enumerate(CORNER_LANES)]
+
+
+def _solo_outputs(cell):
+    """One lane's program outputs, run alone, and its pod count."""
+    trace = _get_trace(cell.scenario, cell.seed, cell.n_jobs)
+    if trace.n == 0:
+        return None, 0
+    out = ml.run_lane_batch(ml.stack_lanes(
+        [_lane_of(trace, cell.initial_workers)], cell.scheduler))
+    return {k: v[0] for k, v in out.items() if v.ndim}, trace.n
+
+
+def _replayed_states(o, n):
+    """Sample states of one lane, by the per-lane pointer walk the bucket
+    rebuild replaced: events in (time, kind, bind_seq) order, each seen
+    from its first visible grid point; a new state wherever the walk
+    stops at an unseen event, up to the last sample recorded."""
+    if o is None:
+        return 1                  # no pod: one flat state to the horizon
+    sp = 20.0
+    bind_t = o["bind_cycle"][:n] * 10.0
+    done_t, seq = o["done_t"][:n], o["bind_seq"][:n]
+    done = np.nonzero(o["done_committed"][:n])[0]
+    ev = []
+    for i in done:
+        td, tc = done_t[i], bind_t[i]
+        early = td % sp == 0 and (tc < td - sp or (tc == 0 and td == sp))
+        ev.append((td, 0, seq[i], td if early else
+                   (math.floor(td / sp) + 1) * sp))
+    for i in np.nonzero(o["bound"][:n])[0]:
+        tb = bind_t[i]
+        ev.append((tb, 1, seq[i], 0.0 if tb == 0 else
+                   (math.floor(tb / sp) + 1) * sp))
+    sv = [e[3] for e in sorted(ev)]
+    te = float(o["done_time"])
+    if not o["completed"]:
+        last_s = ml.HORIZON_S
+    elif te % sp == 0 and te > 0 and not o["done_is_cycle"]:
+        trig = max(done, key=lambda i: (done_t[i], seq[i]))
+        tc = bind_t[trig]
+        first = tc < te - sp or (tc == 0 and te == sp)
+        last_s = te - sp if first else te
+    elif te % sp == 0 and te > 0:
+        last_s = te
+    else:
+        last_s = (math.ceil(te / sp) - 1) * sp
+    states, ptr, s = 0, 0, 0.0
+    while s <= last_s:
+        while ptr < len(sv) and sv[ptr] <= s:
+            ptr += 1
+        states += 1
+        s = sv[ptr] if ptr < len(sv) and sv[ptr] <= last_s else last_s + sp
+    return states
+
+
+class TestBucketRebuild:
+    def test_corner_rows_equal_serial(self, corner_cells):
+        """One call over every corner lane under two schedulers, two fleet
+        sizes in one bucket and 3- and 4-pod lanes in one pod pad: rows
+        equal the serial ``run_cell`` rows field by field, and the lanes
+        reach the corners they were built for."""
+        rows = run_cells(corner_cells, workers="lanes")
+        rec = lane_calls(1)[0]
+        serial = run_cells(corner_cells, workers=1)
+        for s, l in zip(serial, rows):
+            for field in _RESULT_FIELDS:
+                assert s[field] == l[field], (s["label"], field)
+                assert type(s[field]) is type(l[field]), (s["label"], field)
+        assert (rec["lanes"], rec["buckets"]) == (14, 2)
+        got = [_solo_outputs(c)[0] for c in corner_cells[:len(CORNER_LANES)]]
+        assert list(got[0]["bind_cycle"][:3]) == [0, 0, 3]
+        assert list(got[0]["done_t"][:3]) == [20.0, 40.0, 40.0]
+        ends = [(bool(o["completed"]), bool(o["done_is_cycle"]),
+                 float(o["done_time"])) for o in got if o is not None]
+        assert ends == [(True, False, 40.0), (True, False, 60.0),
+                        (True, True, 100.0), (True, True, 50.0),
+                        (False, False, ml.HORIZON_S), (True, False, 20.0),
+                        (True, False, 40.0)]
+        assert rows[4]["avg_ram_ratio"] > 0 and rows[5]["max_nodes"] == 4
+
+    def test_sample_states_count_the_replay(self, corner_cells):
+        """``sample_states`` is the per-lane walk's state count summed
+        over the lanes of the call, zero-pod lanes included."""
+        base = dict(autoscaler="void", rescheduler="void", engine="array")
+        cells = corner_cells + [
+            CellSpec(scenario="heavy-tail", scheduler="best-fit", seed=s,
+                     n_jobs=40, initial_workers=nw, **base)
+            for s, nw in ((0, 4), (1, 3), (2, 12))]
+        run_cells_lanes(cells)
+        got = lane_calls(1)[0]["counts"]["sample_states"]
+        assert got == sum(_replayed_states(*_solo_outputs(c)) for c in cells)
+        assert got >= len(cells)    # every lane here records a sample
+
+    def test_unproven_sums_fall_back_and_are_counted(self, corner_cells):
+        """Each node sum the vectorised sum cannot round with proof goes
+        to ``math.fsum`` and is counted in ``rebuild_fallbacks``, in a
+        bucket of any width; the rows still equal the serial one."""
+        cell = corner_cells[FALLBACK_LANE]
+        cells = [dataclasses.replace(cell, seed=cell.seed
+                                     + k * len(CORNER_LANES))
+                 for k in range(8)]
+        rows = run_cells_lanes(cells)
+        assert lane_calls(1)[0]["counts"]["rebuild_fallbacks"] >= len(cells)
+        want = run_cells(cells[:1], workers=1)[0]
+        for row in rows:
+            for field in _RESULT_FIELDS:
+                assert row[field] == want[field], field
+        assert want["avg_ram_ratio"] != (1.0 + TINY / 3584.0) / 3
+
+    @pytest.mark.parametrize("lanes_per_block", [1, 3])
+    def test_blocks_of_lanes_rebuild_the_same_rows(self, corner_cells,
+                                                   monkeypatch,
+                                                   lanes_per_block):
+        """A bucket rebuilt in blocks of lanes, one lane or a width that
+        does not divide the bucket, gives the rows and counts of one
+        block."""
+        whole = run_cells_lanes(corner_cells)
+        counts = lane_calls(1)[0]["counts"]
+        monkeypatch.setattr(ev, "_REBUILD_BLOCK", 4 * lanes_per_block)
+        blocked = run_cells_lanes(corner_cells)
+        got = lane_calls(1)[0]["counts"]
+        for a, b in zip(whole, blocked):
+            assert {k: v for k, v in a.items() if k != "wall_s"} == {
+                k: v for k, v in b.items() if k != "wall_s"}
+        assert (got["sample_states"], got["rebuild_fallbacks"]) == (
+            counts["sample_states"], counts["rebuild_fallbacks"])
+
+
+def _sum_cases(name, rng, n=2048):
+    """(terms, columns) arrays of one family of float64 sums."""
+    if name == "random":            # both signs, exponents far apart
+        return rng.standard_normal((19, n)) * 2.0 ** rng.integers(
+            -80, 80, (19, n))
+    if name == "ratios":            # node usage over allocatable
+        used = rng.integers(0, 40, (19, n)) * rng.choice(
+            [128.0, 307.2, 512.0, 1433.6], (19, n))
+        return used / rng.choice([3584.0, 940.0, 1.0], n)
+    a = rng.random(n) + 1.0
+    half = np.spacing(a) / 2
+    if name == "ties":              # exactly on a midpoint: ties to even
+        x = np.stack([a, half / 2, half / 2, np.zeros(n)])
+    else:                           # within 2**-k of a midpoint, k to 200
+        tiny = half * 2.0 ** -rng.integers(1, 200, n) * rng.choice(
+            [1.0, -1.0], n)
+        x = np.stack([a, half, tiny, np.zeros(n)])
+    return rng.permuted(x, axis=0)
+
+
+class TestExactSums:
+    @pytest.mark.parametrize("family", ["random", "ratios", "ties",
+                                        "near-midpoint"])
+    def test_matches_fsum_bits(self, family):
+        """Each column's sum is ``math.fsum`` of the column bit for bit;
+        only columns it cannot prove fall back."""
+        x = _sum_cases(family, np.random.default_rng(11))
+        got, fallbacks = _exact_sums(x)
+        want = np.array([math.fsum(c) for c in x.T.tolist()])
+        assert np.array_equal(got.view(np.int64), want.view(np.int64))
+        if family == "near-midpoint":
+            assert 0 < fallbacks < x.shape[1]
+        else:
+            assert fallbacks == 0
+
+    def test_narrow_inputs_sum_by_fsum(self):
+        """Narrow inputs (a bucket of a few lanes) take the same passes:
+        each column is ``math.fsum``'s, and the columns that fall back to
+        it are counted."""
+        x = _sum_cases("near-midpoint", np.random.default_rng(12), n=64)
+        got, fallbacks = _exact_sums(x)
+        want = np.array([math.fsum(c) for c in x.T.tolist()])
+        assert np.array_equal(got.view(np.int64), want.view(np.int64))
+        assert 0 < fallbacks < x.shape[1]
+        one, _ = _exact_sums(x[:, :1])
+        assert one.view(np.int64)[0] == want.view(np.int64)[0]
+        assert _exact_sums(np.zeros((3, 0)))[0].shape == (0,)
+
+    def test_blocks_cover_every_column(self):
+        """Sums run in blocks of columns: a width that is not a multiple
+        of the block is summed whole."""
+        x = _sum_cases("random", np.random.default_rng(4), n=40000)
+        got, _ = _exact_sums(x)
+        assert np.array_equal(got, [math.fsum(c) for c in x.T.tolist()])
+
+    def test_split_is_exact_under_sample_counts(self):
+        """``m * hi`` and ``m * lo`` are exact products, so their exact sum
+        is ``m * v`` for any sample count up to the horizon's."""
+        from fractions import Fraction
+        rng = np.random.default_rng(9)
+        v = rng.random(512) * 2.0 ** rng.integers(-30, 5, 512)
+        m = rng.integers(1, ml.MAX_CYCLES + 2, 512)
+        hi, lo = _split(v)
+        assert np.array_equal(hi + lo, v)
+        for vi, mi, h, l in zip(v, m, m * hi, m * lo):
+            assert Fraction(h) + Fraction(l) == Fraction(vi) * int(mi)
