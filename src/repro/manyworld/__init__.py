@@ -1,7 +1,8 @@
 """Many-world lanes: batched JAX evaluation of independent simulations.
 
-An explicitly-flagged fast path that runs thousands of void/void
-static-cluster experiment *lanes* as one jit-compiled program — see
+An explicitly-flagged fast path that runs thousands of experiment
+*lanes* (static fleets, or fleets grown by the binding autoscaler and
+shrunk by Alg. 6) as one jit-compiled program — see
 `repro.manyworld.lanes` for the engine, its relaxed-semantics contract
 and its integer IEEE-754 float discipline, and
 `repro.manyworld.evaluator` for the ``run_cells(..., workers="lanes")``

@@ -6,11 +6,12 @@ cell list and returns the same row dicts in the same order, but evaluates
 every *lane-eligible* cell inside batched JAX programs
 (`repro.manyworld.lanes`) instead of one serial simulation per cell.
 
-**Eligibility** is the lane engine's relaxed-semantics envelope — the
-void/void static-cluster regime (:func:`lane_eligible`).  Anything
-outside it (autoscalers, reschedulers, chaos, the object engine) falls
-back to the serial ``run_cell`` transparently, so a mixed cell list still
-returns one complete row list.
+**Eligibility** is the lane engine's relaxed-semantics envelope — no
+rescheduler, on a static fleet or under the binding autoscaler
+(:func:`lane_eligible`).  Anything outside it (the other autoscalers,
+reschedulers, chaos, the object engine) falls back to the serial
+``run_cell`` transparently, so a mixed cell list still returns one
+complete row list.
 
 **Exactness.**  For eligible cells the rows are bit-identical to
 ``run_cell`` (except ``wall_s``, which is wall time and is reported as
@@ -36,12 +37,19 @@ lane-pods; lanes with no pod take the same path, without the program):
   ``statistics.fmean``) is rounded once here: :func:`_exact_sums`, a
   vectorised correctly rounded sum that falls back to ``math.fsum``
   wherever it cannot prove its rounding;
-* cost/node-seconds use the serial CostModel formulas for a static fleet
-  billed from t=0 (one ``ceil`` per node, left-to-right accumulation).
+* cost/node-seconds use the serial CostModel formulas: one ``ceil``
+  per node record, from launch (t=0 for a static node) to removal or the
+  run's end, accumulated left-to-right in retirement order, then the
+  nodes still up in launch order.
 
-Buckets: lanes group by ``(scheduler, pod-pad, node-pad)`` with
-power-of-two pads, so the jit cache stays small while mixed workloads
-share compilations.
+An autoscaled lane's replay adds its evictions (each closes an
+incarnation: its bind and its pending interval are recorded too) and
+its nodes' joins and removals, which change the set of nodes a sample
+averages over.
+
+Buckets: lanes group by ``(scheduler, pod-pad, node-pad, autoscaled)``
+with power-of-two pads, so the jit cache stays small while mixed
+workloads share compilations.
 
 **Spans and counts.**  Each call is one ``lanes.call`` span of
 ``lanes.PROFILER`` (and of a JAX profiler trace, when one runs), with
@@ -115,14 +123,22 @@ def lane_calls(n: int) -> List[Dict]:
 
 def lane_eligible(cell) -> bool:
     """True when ``cell`` is inside the lane engine's relaxed envelope:
-    a void/void static cluster (no autoscaler, no rescheduler, no chaos)
-    on the array engine with a lane scheduler (``lanes.SCHEDULERS``:
-    k8s-default and weighted run on the serial reference on every
-    backend).  A weighted spec stays serial, so an invalid one raises the
-    serial error."""
-    if cell.autoscaler != "void" or cell.rescheduler != "void":
+    no rescheduler, no chaos, the array engine and a lane scheduler
+    (``lanes.SCHEDULERS``: k8s-default and weighted run on the serial
+    reference on every backend), on a static fleet (void autoscaler) or
+    an autoscaled one (the binding autoscaler with Alg. 6 ungated, on a
+    template whose provisioning delay is a whole number of cycles longer
+    than a sample period).  The non-binding and predictive autoscalers
+    stay serial.  A weighted spec stays serial, so an invalid one raises
+    the serial error."""
+    if cell.rescheduler != "void" or cell.chaos:
         return False
-    if cell.chaos:
+    if cell.autoscaler == "binding":
+        if cell.scale_in_util_ceiling is not None:
+            return False
+        if _boot_cycles(cell) is None:
+            return False
+    elif cell.autoscaler != "void":
         return False
     if cell.engine not in (None, "array"):
         return False
@@ -137,6 +153,22 @@ def _template_of(cell):
     from repro.cloud.adapter import M2_SMALL, NODE_TEMPLATES
     return (NODE_TEMPLATES[cell.template_name]
             if cell.template_name is not None else M2_SMALL)
+
+
+def _boot_cycles(cell) -> Optional[int]:
+    """The template's provisioning delay in cycles, or None where the lane
+    program cannot hold it: an unknown template, a delay that is not a
+    whole number of cycles, or one no longer than a sample period (a
+    NODE_READY must fire before the SAMPLE at its instant)."""
+    from repro.cloud.adapter import NODE_TEMPLATES
+    if (cell.template_name is not None
+            and cell.template_name not in NODE_TEMPLATES):
+        return None
+    delay = _template_of(cell).provisioning_delay_s
+    cycles = delay / CYCLE_PERIOD_S
+    if cycles != int(cycles) or delay <= SAMPLE_PERIOD_S:
+        return None
+    return int(cycles)
 
 
 _CELL_FIELDS: tuple = ()
@@ -234,6 +266,15 @@ def _split(v: np.ndarray):
     return hi, v - hi
 
 
+def _cycle_k(tc):
+    """First sample grid index that sees an effect of the cycle at ``tc``
+    (a bind, an eviction, a node removal): the next grid point after
+    ``tc`` (SAMPLE(t) runs before CYCLE(t) for t > 0), except cycle 0,
+    whose effects sample at t = 0 (run() pushes CYCLE(0) before
+    SAMPLE(0))."""
+    return np.where(tc == 0.0, 0.0, np.floor(tc / SAMPLE_PERIOD_S) + 1.0)
+
+
 def _bucket_metrics(entries: list, batch, out: dict):
     """One bucket's ExperimentResult fields, rebuilt from its lane outputs
     with array work over the whole bucket.
@@ -242,18 +283,21 @@ def _bucket_metrics(entries: list, batch, out: dict):
     (pod columns and cluster scalars), ``out`` the program's per-pod
     ``bound`` / ``bind_node`` / ``bind_seq`` / ``bind_cycle`` / ``done_t``
     / ``done_committed`` and per-lane ``completed`` / ``done_time`` /
-    ``done_is_cycle`` / ``scale_outs``.  Returns one field dict per lane,
-    the sample states replayed and the sums that fell back to
-    ``math.fsum``.  Every formula is the serial one, applied in the serial
-    order, and every float sum that the serial run rounds once (``fsum``,
-    ``fmean``) is rounded once here (:func:`_exact_sums`).
+    ``done_is_cycle`` / ``scale_outs``; an autoscaled bucket's also hold
+    its node records, evictions and ``pend`` (``lanes.run_lane_batch``).
+    Returns one field dict per lane, the sample states replayed and the
+    sums that fell back to ``math.fsum``.  Every formula is the serial
+    one, applied in the serial order, and every float sum that the serial
+    run rounds once (``fsum``, ``fmean``) is rounded once here
+    (:func:`_exact_sums`).
     """
     SP = SAMPLE_PERIOD_S
     L, P = batch.valid.shape
     lanes = np.arange(L)
     valid = batch.valid
+    fleet = batch.autoscale
     n_nodes = batch.n_nodes.astype(np.int64)
-    N = int(n_nodes.max())
+    N = batch.n_pad if fleet else int(n_nodes.max())
     acpu = np.maximum(batch.alloc_cpu.astype(np.float64), 1.0)
     price = np.array([float(e[3].price_per_s) for e in entries])
 
@@ -262,6 +306,14 @@ def _bucket_metrics(entries: list, batch, out: dict):
     bind_t = out["bind_cycle"].astype(np.float64) * CYCLE_PERIOD_S
     done_t, seq = out["done_t"], out["bind_seq"]
     completed, te = out["completed"], out["done_time"]
+    if fleet:
+        # The closed incarnations of evicted pods: bound at ev_bind_t,
+        # pending since ev_pend, evicted at ev_t.
+        X = out["ev_pod"].shape[1]
+        logged = np.arange(X) < out["ev_n"][:, None]
+        ev_bind_t = out["ev_bind_cycle"].astype(np.float64) * CYCLE_PERIOD_S
+        ev_t = out["ev_cycle"].astype(np.float64) * CYCLE_PERIOD_S
+        seq_of_index = _lanes.node_layout(N)[0]
 
     # -- end of run (simulation.run: last_batch_done wins when truthy) --
     lbd_raw = np.where(done, done_t, -np.inf).max(axis=1)
@@ -270,12 +322,17 @@ def _bucket_metrics(entries: list, batch, out: dict):
     a0 = batch.arrival_t[:, 0]
     start = np.where(valid[:, 0] & (a0 <= HORIZON_S), a0, 0.0)
 
-    # -- pending intervals (store.pending_intervals_all: bound rows only,
-    # row order; void/void never rebinds so one interval per pod) --------
-    pend = bind_t - batch.arrival_t
-    n_pend = bound.sum(axis=1)
-    pend_sum, fallbacks = _exact_sums(np.where(bound, pend, 0.0).T.copy())
-    srt = np.sort(np.where(bound, pend, np.inf), axis=1)
+    # -- pending intervals (store.pending_intervals_all: one per bind, of
+    # every incarnation) ------------------------------------------------
+    if fleet:
+        pend = np.concatenate([bind_t - out["pend"],
+                               ev_bind_t - out["ev_pend"]], axis=1)
+        pend_ok = np.concatenate([bound, logged], axis=1)
+    else:
+        pend, pend_ok = bind_t - batch.arrival_t, bound
+    n_pend = pend_ok.sum(axis=1)
+    pend_sum, fallbacks = _exact_sums(np.where(pend_ok, pend, 0.0).T.copy())
+    srt = np.sort(np.where(pend_ok, pend, np.inf), axis=1)
     half = n_pend // 2
     median = np.where(n_pend % 2 == 1, srt[lanes, half],
                       (srt[lanes, np.maximum(half - 1, 0)]
@@ -283,42 +340,92 @@ def _bucket_metrics(entries: list, batch, out: dict):
     top = srt[lanes, np.maximum(n_pend - 1, 0)]
 
     # -- utilisation sample replay --------------------------------------
-    # Each lane's events (its completions, then its binds: 2P columns) in
-    # serial processing order, (time, kind, bind_seq): POD_DONE (0)
-    # before the cycle's binds (1) at equal times; equal-time completions
-    # fire in scheduling-push order == ascending bind_seq.  Padding and
-    # pods without the event sort last.  Each event carries the first
-    # sample (grid index k, time 20 k) that can see it:
-    # * a bind at cycle tc is visible from the next grid point after tc
-    #   (SAMPLE(t) runs before CYCLE(t) for t>0) — except cycle 0, whose
-    #   binds sample at t=0 (run() pushes CYCLE(0) before SAMPLE(0));
+    # Each lane's events in serial processing order, (time, kind, seq):
+    # NODE_READY (-1, pushed a provisioning delay before, so before every
+    # other event of its instant that a sample can tell apart), POD_DONE
+    # (0) before the cycle's binds (1), its evictions (2) and node
+    # removals (3) at equal times; equal-time completions fire in
+    # scheduling-push order == ascending bind_seq, and a node's evictions
+    # in bind order.  Padding and missing events sort last.  Each event
+    # carries the first sample (grid index k, time 20 k) that can see it:
+    # * a cycle's effect at tc: :func:`_cycle_k`;
     # * a completion at td is visible from td itself when td is on-grid
     #   and its POD_DONE was pushed (at its bind cycle tc) before
     #   SAMPLE(td) was (at td-20) — i.e. tc < td-20, or the cycle-0
-    #   corner tc==0, td==20 — else from the next grid point after td.
+    #   corner tc==0, td==20 — else from the next grid point after td;
+    # * a node's NODE_READY at tr, pushed at its launch, more than a
+    #   sample period before: from the first grid point at or after tr.
+    # Events carry (node, signed memory, signed cpu): node events none.
     td = np.where(done, done_t, 0.0)
     early = ((np.fmod(td, SP) == 0.0)
              & ((bind_t < td - SP) | ((bind_t == 0.0) & (td == SP))))
     kd = np.where(early, td / SP, np.floor(td / SP) + 1.0)
-    kb = np.where(bind_t == 0.0, 0.0, np.floor(bind_t / SP) + 1.0)
-    order = np.lexsort((np.concatenate([seq, seq], axis=1),
-                        np.broadcast_to(np.arange(2 * P) >= P, (L, 2 * P)),
-                        np.concatenate([np.where(done, done_t, np.inf),
-                                        np.where(bound, bind_t, np.inf)],
-                                       axis=1)), axis=1)
-    is_done = order < P
-    ev_pod = order % P
+    node = out["bind_node"]
+    mem_p = batch.mem_mb
+    cpu_p = batch.cpu_m.astype(np.float64)
+    groups = [  # time, kind, seq, live, k, node, sign, mem, cpu
+        (done_t, 0, seq, done, kd, node, -1, mem_p, cpu_p),
+        (bind_t, 1, seq, bound, _cycle_k(bind_t), node, 1, mem_p, cpu_p)]
+    if fleet:
+        ev_pod = out["ev_pod"]
+        ev_mem = np.take_along_axis(mem_p, ev_pod, axis=1)
+        ev_cpu = np.take_along_axis(cpu_p, ev_pod, axis=1)
+        ev_seq, ev_node = out["ev_bind_seq"], out["ev_node"]
+        nstate = out["nstate"]
+        autoscaled = seq_of_index[None, :] >= n_nodes[:, None]
+        launched = autoscaled & (nstate != _lanes.NODE_NONE)
+        ready_t = (out["launch_k"] + batch.boot_cycles[:, None]).astype(
+            np.float64) * CYCLE_PERIOD_S
+        gone = out["gone_k"] >= 0
+        gone_t = out["gone_k"].astype(np.float64) * CYCLE_PERIOD_S
+        zero_n = np.zeros((L, N), np.int64)
+        zero_f = np.zeros((L, N))
+        groups += [
+            (ev_bind_t, 1, ev_seq, logged, _cycle_k(ev_bind_t), ev_node, 1,
+             ev_mem, ev_cpu),
+            (ev_t, 2, ev_seq, logged, _cycle_k(ev_t), ev_node, -1, ev_mem,
+             ev_cpu),
+            (ready_t, -1, zero_n, launched, np.ceil(ready_t / SP), zero_n,
+             0, zero_f, zero_f),
+            (gone_t, 3, zero_n, gone, _cycle_k(gone_t), zero_n, 0, zero_f,
+             zero_f)]
+    widths = [g[3].shape[1] for g in groups]
+    E = sum(widths)
+    kinds = np.concatenate([np.full(w, g[1], np.int8)
+                            for g, w in zip(groups, widths)])
+    order = np.lexsort((
+        np.concatenate([g[2] for g in groups], axis=1),
+        np.broadcast_to(kinds, (L, E)),
+        np.concatenate([np.where(g[3], g[0], np.inf) for g in groups],
+                       axis=1)), axis=1)
+    # Each sorted column's group and index in it, to gather every field
+    # from the group's own (lane, event) array.
+    equal = len(set(widths)) == 1
+    if equal:
+        gid, local = np.divmod(order, widths[0])
+    else:
+        starts = np.cumsum([0] + widths[:-1])
+        gid = np.searchsorted(starts, order, side="right") - 1
+        local = order - starts[gid]
 
-    def at_events(x):
-        return np.take_along_axis(x, ev_pod, axis=1)
+    def at_events(field):
+        if equal and all(g[field] is groups[0][field] for g in groups):
+            return np.take_along_axis(groups[0][field], local, axis=1)
+        val = None
+        for i, (g, w) in enumerate(zip(groups, widths)):
+            x = g[field]
+            if np.ndim(x):
+                x = np.take_along_axis(
+                    x, local if equal else np.minimum(local, w - 1), axis=1)
+            val = x if val is None else np.where(gid == i, x, val)
+        return val
 
-    live = np.where(is_done, at_events(done), at_events(bound))
+    live = at_events(3)
     # A sample applies the events in order up to the first it cannot see
     # yet, so an event is applied at the first sample at or after every
     # visibility time up to it: a running maximum along the lane.
-    ev_k = np.maximum.accumulate(
-        np.where(live, np.where(is_done, at_events(kd), at_events(kb)), 0.0),
-        axis=1).astype(np.int64)
+    ev_k = np.maximum.accumulate(np.where(live, at_events(4), 0.0),
+                                 axis=1).astype(np.int64)
 
     # The last sample recorded.  Non-completed lanes sample the whole
     # horizon.  A completed lane breaks on its trigger event at te: every
@@ -364,16 +471,15 @@ def _bucket_metrics(entries: list, batch, out: dict):
     # states (grid point 0 before any event), whose usage stays zero.
     closes = applied.copy()
     closes[:, :-1] &= ~(applied[:, 1:] & same_k[:, 1:])
-    sign = np.where(is_done, -1, 1)
-    mem = at_events(batch.mem_mb)
-    cpu = at_events(batch.cpu_m).astype(np.float64)
+    sign = at_events(6)
     walk = [x.T.copy() for x in (
-        np.where(live, at_events(out["bind_node"]), 0) * L + lanes[:, None],
-        np.where(live, sign * mem, 0.0), np.where(live, sign * cpu, 0.0))]
+        np.where(live, at_events(5), 0) * L + lanes[:, None],
+        np.where(live, sign * at_events(7), 0.0),
+        np.where(live, sign * at_events(8), 0.0))]
     col, lane_at = np.nonzero(closes.T)
     zero = first[has_seg & ~(applied & (ev_k == 0)).any(axis=1)]
     table = np.concatenate([seg[lane_at, col], zero])
-    cuts = np.cumsum(np.bincount(col, minlength=2 * P))
+    cuts = np.cumsum(np.bincount(col, minlength=E))
     used_mem = np.zeros((N, L))
     used_cpu = np.zeros((N, L))
     state_mem = np.zeros((N, n_seg))
@@ -389,16 +495,40 @@ def _bucket_metrics(entries: list, batch, out: dict):
     pods = np.zeros(n_seg, np.int64)
     pods[seg[closes]] = np.cumsum(applied * sign, axis=1)[closes]
 
-    # Serial sampler: exact fsum of per-node IEEE ratios, / n; each
-    # lane's average is fmean over its samples, m copies of each state:
-    # m * v splits into two exact products (:func:`_split`).
-    nn = n_nodes[seg_lane]
+    # Serial sampler: exact fsum of per-node IEEE ratios over the READY
+    # and TAINTED nodes, / their count; each lane's average is fmean over
+    # its samples, m copies of each state: m * v splits into two exact
+    # products (:func:`_split`).
+    if fleet:
+        # A node is sampled from the grid point its NODE_READY reaches
+        # (grid point 0 for a static node) to the one its removal reaches.
+        ev_k_at = np.empty_like(ev_k)
+        np.put_along_axis(ev_k_at, order, ev_k, axis=1)
+        off = 2 * P + 2 * X
+        never = np.iinfo(np.int64).max
+        ready_k = np.where(autoscaled, np.where(
+            launched, ev_k_at[:, off:off + N], never), 0)
+        gone_k = np.where(gone, ev_k_at[:, off + N:off + 2 * N], never)
+        t_lane, t_k = seg_lane[table], seg_k[table]
+        unsampled = ~((ready_k[t_lane] <= t_k[:, None])
+                      & (t_k[:, None] < gone_k[t_lane])).T
+        nn = np.empty(n_seg, np.int64)
+        nn[table] = N - unsampled.sum(axis=0)
+    else:
+        nn = n_nodes[seg_lane]
+
+    def node_sums(state, alloc):
+        ratio = state / alloc[seg_lane[table]]
+        if fleet:
+            np.copyto(ratio, 0.0, where=unsampled)
+        return _exact_sums(ratio)
+
     ram, cpu_r = np.empty(n_seg), np.empty(n_seg)
-    ram[table], n = _exact_sums(state_mem
-                                / batch.alloc_mem[seg_lane[table]])
+    ram[table], n = node_sums(state_mem, batch.alloc_mem)
     fallbacks += n
-    cpu_r[table], n = _exact_sums(state_cpu / acpu[seg_lane[table]])
+    cpu_r[table], n = node_sums(state_cpu, acpu)
     fallbacks += n
+    # (Static nodes are never removed, so every sample sees one.)
     hi, lo = _split(np.stack([ram / nn, cpu_r / nn,
                               pods.astype(np.float64) / nn], axis=1))
     pos = np.arange(n_seg) - first[seg_lane]
@@ -412,13 +542,34 @@ def _bucket_metrics(entries: list, batch, out: dict):
                     sums.reshape(L, 3) / np.maximum(n_samples, 1)[:, None],
                     0.0)
 
-    # -- cost (CostModel: N static nodes billed 0 -> end, ceil'd, summed
-    # left-to-right in record order) ------------------------------------
-    secs = np.ceil(np.maximum(0.0, end))
-    term = secs * price
-    cost = np.zeros(L)
-    for k in range(N):
-        cost = np.where(k < n_nodes, cost + term, cost)
+    # -- cost (CostModel: per node record ceil(max(0, stop - start)) *
+    # price, summed left-to-right in record order: the records closed by
+    # Alg. 6 as they retired, then the open ones in provision order) ----
+    if fleet:
+        exists = nstate != _lanes.NODE_NONE
+        start_n = out["launch_k"].astype(np.float64) * CYCLE_PERIOD_S
+        stop_n = np.where(gone, gone_t, end[:, None])
+        secs_n = np.where(exists, np.ceil(np.maximum(0.0, stop_n - start_n)),
+                          0.0)
+        rec = np.lexsort((np.broadcast_to(seq_of_index, (L, N)),
+                          out["gone_step"], np.where(gone, out["gone_k"], 0),
+                          ~gone, ~exists), axis=1)
+        term = np.take_along_axis(secs_n, rec, axis=1) * price[:, None]
+        real = np.take_along_axis(exists, rec, axis=1)
+        cost = np.zeros(L)
+        for k in range(N):
+            cost = np.where(real[:, k], cost + term[:, k], cost)
+        node_secs = secs_n.sum(axis=1).astype(np.int64)
+        max_nodes = np.zeros(L, np.int64)
+        np.maximum.at(max_nodes, seg_lane, nn)
+    else:
+        secs = np.ceil(np.maximum(0.0, end))
+        term = secs * price
+        cost = np.zeros(L)
+        for k in range(N):
+            cost = np.where(k < n_nodes, cost + term, cost)
+        node_secs = (secs * n_nodes).astype(np.int64)
+        max_nodes = np.where(n_samples > 0, n_nodes, 0)
 
     has = n_pend > 0
     zeros = np.zeros(L, np.int64)
@@ -433,11 +584,11 @@ def _bucket_metrics(entries: list, batch, out: dict):
         "avg_ram_ratio": avgs[:, 0],
         "avg_cpu_ratio": avgs[:, 1],
         "avg_pods_per_node": avgs[:, 2],
-        "max_nodes": np.where(n_samples > 0, n_nodes, 0),
-        "node_seconds": (secs * n_nodes).astype(np.int64),
-        "evictions": zeros,
+        "max_nodes": max_nodes,
+        "node_seconds": node_secs,
+        "evictions": out["ev_n"].astype(np.int64) if fleet else zeros,
         "scale_outs": out["scale_outs"].astype(np.int64),
-        "scale_ins": zeros,
+        "scale_ins": out["scale_ins"].astype(np.int64) if fleet else zeros,
         "failures_injected": zeros,
         "preemption_notices": zeros,
         "lost_work_s": np.zeros(L),
@@ -473,26 +624,32 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
     _count_compiles()
     compiles0 = _compiles
     span = _lanes.PROFILER.span
-    counts = dict.fromkeys(_lanes.COUNTERS + (
-        "lane_steps", "sample_states", "rebuild_fallbacks"), 0)
+    counts = dict.fromkeys(_lanes.COUNTERS + _lanes.FLEET_COUNTERS + (
+        "lane_steps", "node_cycles", "sample_states", "rebuild_fallbacks",
+        "lane_fallbacks"), 0)
     n_lanes = 0
     with span("lanes.call") as root:
         with span("lanes.prepare"):
             buckets, idle = _prepare(cells, rows)
-        for (sched, p_pad, _n_pad), entries in buckets.items():
+        for (sched, p_pad, n_pad, fleet), entries in buckets.items():
             t0 = time.perf_counter()
             with span("lanes.stack"):
                 batch = _lanes.stack_lanes([e[4] for e in entries], sched,
-                                           p_pad=p_pad)
+                                           p_pad=p_pad,
+                                           node_pad=n_pad if fleet else None)
             out = _lanes.run_lane_batch(batch)
             share = (time.perf_counter() - t0) / len(entries)
             with span("lanes.rebuild"):
-                got = {key: int(out.pop(key)) for key in _lanes.COUNTERS}
+                got = {key: int(out.pop(key)) for key in
+                       _lanes.COUNTERS + _lanes.FLEET_COUNTERS if key in out}
                 for key, val in got.items():
                     counts[key] += val
                 counts["lane_steps"] += len(entries) * (
                     got["n_cycles"] + got["wave_steps"]
                     + got["completion_steps"])
+                if fleet:
+                    counts["node_cycles"] += (len(entries) * got["n_cycles"]
+                                              * n_pad)
                 n_lanes += len(entries)
                 _rebuild(entries, batch, out, share, rows, counts)
         if idle:
@@ -539,10 +696,16 @@ def _prepare(cells: list, rows: list):
             lane["alloc_mem"] = float(template.allocatable.mem_mb)
             entry = (idx, cell, trace, template, lane)
             if trace.n == 0:
+                # No pod: no launch either, so the static rebuild holds.
                 idle.append(entry)
                 continue
-            key = (cell.scheduler, next_pow2(trace.n),
-                   next_pow2(cell.initial_workers))
+            if cell.autoscaler == "binding":
+                lane["boot_cycles"] = _boot_cycles(cell)
+                key = (cell.scheduler, next_pow2(trace.n),
+                       _node_records(cell, trace), True)
+            else:
+                key = (cell.scheduler, next_pow2(trace.n),
+                       next_pow2(cell.initial_workers), False)
             buckets.setdefault(key, []).append(entry)
         except CellError:
             raise
@@ -551,18 +714,41 @@ def _prepare(cells: list, rows: list):
     return buckets, idle
 
 
+def _node_records(cell, trace) -> int:
+    """Node records of an autoscaled lane: its static nodes plus one
+    launch per pod, to a power of two.  Nothing bounds a lane's launches
+    (a pod may ask again each time its node boots without it), so a lane
+    that needs more records stops and runs serially (:func:`_rebuild`):
+    the bound sets the program's width, never a row."""
+    return next_pow2(cell.initial_workers + trace.n)
+
+
 def _rebuild(entries: list, batch, out: dict, share: float, rows: list,
              counts: dict) -> None:
     """One bucket's rows from its lane outputs (counters taken out), in
     blocks of ``_REBUILD_BLOCK`` lane-pods; adds the sample states and
-    ``math.fsum`` fallbacks to ``counts``."""
-    from repro.search.runner import CellError
+    ``math.fsum`` fallbacks to ``counts``.  A lane that ran past its node
+    or eviction records (``overflow``) runs through the serial
+    ``run_cell`` instead, counted in ``lane_fallbacks``."""
+    from repro.search.runner import CellError, run_cell
     step = max(1, _REBUILD_BLOCK // batch.p_pad)
     arrays = [f.name for f in dataclasses.fields(batch)
-              if f.name != "scheduler"]
-    for lo in range(0, len(entries), step):
-        lanes = slice(lo, lo + step)
-        block = entries[lanes]
+              if isinstance(getattr(batch, f.name), np.ndarray)]
+    over = out.pop("overflow", np.zeros(len(entries), bool))
+    for i in np.flatnonzero(over):
+        idx, cell = entries[i][:2]
+        try:
+            rows[idx] = run_cell(cell)
+        except Exception as exc:
+            raise CellError(f"cell {cell.label} failed: {exc!r}") from exc
+    counts["lane_fallbacks"] += int(over.sum())
+    keep = np.flatnonzero(~over) if over.any() else None
+    n_keep = len(entries) if keep is None else keep.size
+    for lo in range(0, n_keep, step):
+        lanes = (slice(lo, lo + step) if keep is None
+                 else keep[lo:lo + step])
+        block = (entries[lanes] if keep is None
+                 else [entries[i] for i in lanes])
         sub = dataclasses.replace(
             batch, **{name: getattr(batch, name)[lanes] for name in arrays})
         try:

@@ -1,7 +1,7 @@
 """Many-world lane engine: thousands of simulations as one JAX program.
 
-One *lane* is one full static-cluster experiment — trace, scheduler,
-fleet size — and a batch of lanes runs as a single jit-compiled program
+One *lane* is one full experiment — trace, scheduler, fleet — and a
+batch of lanes runs as a single jit-compiled program
 over stacked ``(lane, node)`` / ``(lane, pod)`` arrays.  The program is
 the cycle hot path of the serial engine lowered to fixed shapes:
 
@@ -18,23 +18,43 @@ the cycle hot path of the serial engine lowered to fixed shapes:
   ``used += req`` / ``free = alloc - used``.
 
 **Names and counts.**  The jitted program is named after its scheduler
-(``lane_program_best_fit``: the ``XLA Modules`` line of a profiler
-trace), and its loop bodies run under the named scopes ``completions``,
-``wave`` and ``cycle_end``.  Beside its outputs it returns the steps it
-took (:data:`COUNTERS`): the outer cycles, the iterations of each inner
-loop, and how many lanes had work in them.  The counts only describe the
-run; nothing reads them back into a decision.  :func:`run_lane_batch`
+(``lane_program_best_fit``, ``..._autoscaled`` for an autoscaled fleet:
+the ``XLA Modules`` line of a profiler trace), and its loop bodies run
+under the named scopes ``completions``, ``wave``, ``scale_in`` and
+``cycle_end``.  Beside its outputs it returns the steps it took
+(:data:`COUNTERS`, and :data:`FLEET_COUNTERS` when autoscaled): the outer
+cycles, the iterations of each inner loop, how many lanes had work in
+them, and the nodes launched, removed and live.  The counts only describe
+the run; nothing reads them back into a decision.  :func:`run_lane_batch`
 times its dispatch, wait and copy to the host as spans of
 :data:`PROFILER`, the lane path's process-wide span recorder.
 
-**Relaxed-semantics envelope.**  Lanes model the void/void static-cluster
-regime only: no autoscaler, no rescheduler, no chaos, homogeneous READY
-fleet billed from t=0, speed factor 1.  Everything else — event ordering,
-tie-breaks, stuck detection, blocked-pod scale-out counting — follows the
-serial engine exactly; ``repro.manyworld.evaluator`` reconstructs full
-``ExperimentResult`` rows host-side from the lane outputs.  See
-ARCHITECTURE.md "Many-world lanes" for the contract and the enumerated
-divergences.
+**Relaxed-semantics envelope.**  Lanes model no rescheduler, no chaos,
+a homogeneous fleet and speed factor 1, on a static fleet (void
+autoscaler: READY nodes billed from t=0) or an autoscaled one.
+Everything else — event ordering, tie-breaks, stuck detection,
+blocked-pod scale-out counting — follows the serial engine exactly;
+``repro.manyworld.evaluator`` reconstructs full ``ExperimentResult``
+rows host-side from the lane outputs.  See ARCHITECTURE.md "Many-world
+lanes" for the contract and the enumerated divergences.
+
+**Autoscaled lanes.**  The binding autoscaler (paper Alg. 7) and Alg. 6
+scale-in run inside the cycle.  Each lane holds ``n_pad`` node records,
+its static nodes and every node it launches, never reused, laid out in
+node_id order (:func:`node_layout`) so the first index of a masked
+extremum is the serial lowest-id tie-break; each record has a state
+(:data:`NODE_STATES`), its launch and removal cycles, and the planned
+free room of its booting tracker.  A cycle first lets the nodes whose
+provisioning delay has passed join (their trackers' pods lose their
+association), commits completions, then places: READY nodes first,
+TAINTED as the last resort, and a blocked pod asks the autoscaler, which
+associates it with a booting node or launches one.  After a cycle with
+no blocked pod, Alg. 6 removes empty autoscaled nodes and visits the
+others in launch order, evicting moveable pods whose shadow best-fit
+succeeds; evicted pods re-pend at the cycle's instant, so the wave picks
+by (pending since, row).  Each eviction records the incarnation it
+closes, for the host's rebuild.  A lane that runs past its node or
+eviction records stops (``overflow``) and is rerun serially.
 
 **Float discipline.**  The serial engine's float64 values — arrival and
 completion times, memory requests and the per-node ``used_mem`` running
@@ -75,6 +95,20 @@ SCHEDULERS = ("best-fit", "worst-fit", "first-fit")
 #: steps can pass 2**31 at policy-search sizes.
 COUNTERS = ("n_cycles", "wave_steps", "completion_steps", "busy_lane_steps",
             "active_lane_cycles")
+#: The autoscaled program's further counts: ``scale_out_nodes`` nodes
+#: launched and ``scale_in_nodes`` nodes removed by Alg. 6 (steps 1 and
+#: 2), over all lanes; ``active_node_cycles`` live nodes (booting, ready
+#: or tainted) of the active lanes, summed over the outer cycles;
+#: ``scale_in_steps`` iterations of Alg. 6's candidate, shadow-placement
+#: and eviction loops.
+FLEET_COUNTERS = ("scale_out_nodes", "scale_in_nodes", "active_node_cycles",
+                  "scale_in_steps")
+
+#: Node record states of an autoscaled lane: no node yet, booting
+#: (PROVISIONING, with the binding autoscaler's tracker), READY, TAINTED
+#: (Alg. 6 step 3) and removed (Alg. 6 steps 1 and 2).
+NODE_STATES = tuple(range(5))
+NODE_NONE, NODE_BOOTING, NODE_READY, NODE_TAINTED, NODE_GONE = NODE_STATES
 
 #: The lane path's spans (``repro.manyworld.evaluator`` opens the rest).
 PROFILER = PhaseProfiler(max_spans=1 << 14)
@@ -117,8 +151,14 @@ class LaneBatch:
     """Stacked fixed-shape inputs for one compiled many-world program.
 
     Pod axis is padded to ``p_pad`` (``valid`` masks real rows), node axis
-    to ``n_pad`` (``n_nodes`` masks real nodes); every lane in a batch
-    shares one scheduler.  Build via :func:`stack_lanes`.
+    to ``n_pad``; every lane in a batch shares one scheduler.  A static
+    batch (``node_pad`` None) holds ``n_nodes`` READY nodes per lane, at
+    node indices ``0 .. n_nodes - 1``.  An autoscaled batch (the binding
+    autoscaler, :data:`NODE_STATES`) gives each lane ``node_pad`` node
+    records: its ``n_nodes`` static nodes and every node it launches,
+    in node_id order (:func:`node_layout`); ``boot_cycles`` is the
+    provisioning delay in cycles and ``moveable`` marks the pods Alg. 6
+    may move.  Build via :func:`stack_lanes`.
     """
 
     scheduler: str
@@ -131,6 +171,9 @@ class LaneBatch:
     n_nodes: np.ndarray       # (L,)  i32
     alloc_cpu: np.ndarray     # (L,)  i64
     alloc_mem: np.ndarray     # (L,)  f64
+    moveable: np.ndarray      # (L, P) bool
+    boot_cycles: np.ndarray   # (L,)  i32
+    node_pad: Optional[int] = None
 
     @property
     def n_lanes(self) -> int:
@@ -141,16 +184,24 @@ class LaneBatch:
         return self.arrival_t.shape[1]
 
     @property
+    def autoscale(self) -> bool:
+        return self.node_pad is not None
+
+    @property
     def n_pad(self) -> int:
+        if self.node_pad is not None:
+            return self.node_pad
         return next_pow2(int(self.n_nodes.max()) if self.n_nodes.size else 1)
 
 
-def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None
-                ) -> LaneBatch:
+def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None,
+                node_pad: Optional[int] = None) -> LaneBatch:
     """Stack per-lane dicts (``TraceStore.to_lane_arrays`` output plus
     cluster scalars ``n_nodes`` / ``alloc_cpu`` / ``alloc_mem``) into one
     padded :class:`LaneBatch`.  CPU requests and allocatable must be whole
-    milli-cores, as ``Resources.cpu_m`` is."""
+    milli-cores, as ``Resources.cpu_m`` is.  ``node_pad`` makes the batch
+    autoscaled: each lane dict then also holds ``boot_cycles``, and
+    ``node_pad`` bounds the node records of every lane."""
     if scheduler not in SCHEDULERS:
         raise ValueError(f"unsupported lane scheduler {scheduler!r}")
     n_max = max((int(d["arrival_t"].size) for d in lanes), default=0)
@@ -164,9 +215,11 @@ def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None
     dur = np.zeros((L, P))
     isb = np.zeros((L, P), bool)
     val = np.zeros((L, P), bool)
+    mov = np.zeros((L, P), bool)
     n_nodes = np.zeros(L, np.int32)
     a_cpu = np.zeros(L, np.int64)
     a_mem = np.zeros(L)
+    boot = np.zeros(L, np.int32)
     for i, d in enumerate(lanes):
         n = int(d["arrival_t"].size)
         c = np.append(np.asarray(d["cpu_m"], np.float64), d["alloc_cpu"])
@@ -182,8 +235,32 @@ def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None
         n_nodes[i] = d["n_nodes"]
         a_cpu[i] = d["alloc_cpu"]
         a_mem[i] = d["alloc_mem"]
+        if node_pad is not None:
+            mov[i, :n] = d["moveable"]
+            boot[i] = d["boot_cycles"]
+    if node_pad is not None and L and int(n_nodes.max()) > node_pad:
+        raise ValueError(f"node_pad={node_pad} < largest static fleet "
+                         f"({int(n_nodes.max())} nodes)")
     return LaneBatch(scheduler, arr, cpu, mem, dur, isb, val,
-                     n_nodes, a_cpu, a_mem)
+                     n_nodes, a_cpu, a_mem, mov, boot, node_pad)
+
+
+def node_layout(n_pad: int):
+    """``(seq_of_index, index_of_seq)`` of an autoscaled batch's node axis.
+
+    A lane's nodes are ``node-<seq>``, numbered in launch order from 0 (its
+    static nodes first), and node ids order *lexicographically*: ties in
+    every placement go to the lowest id, and the binding autoscaler visits
+    its booting nodes in id order.  So node ``seq`` sits at index
+    ``index_of_seq[seq]``, the rank of its id among ``node-0 ..
+    node-<n_pad - 1>``, and the first index of a masked extremum is the
+    serial tie-break; ``seq_of_index`` is the inverse, the launch
+    (insertion) order that Alg. 6 walks."""
+    seq_of_index = np.array(sorted(range(n_pad), key=lambda s: f"node-{s}"),
+                            np.int32)
+    index_of_seq = np.empty(n_pad, np.int32)
+    index_of_seq[seq_of_index] = np.arange(n_pad, dtype=np.int32)
+    return seq_of_index, index_of_seq
 
 
 def f64_add(a, b):
@@ -268,43 +345,81 @@ def _wave_keys(sched: str, free_mem):
     return jnp.zeros_like(free_mem)
 
 
-def _program_factory(sched: str, n_pad: int):
+def _while(cond, body, state: dict, keys):
+    """``lax.while_loop`` over the entries ``keys`` of ``state``, carried
+    as a tuple in that order (the carry's order sets the compiled loop's
+    buffers); ``cond`` and ``body`` see, and ``body`` returns, dicts."""
+    from jax import lax
+
+    def as_dict(c):
+        return dict(zip(keys, c))
+
+    def step(c):
+        new = body(as_dict(c))
+        return tuple(new[k] for k in keys)
+
+    out = lax.while_loop(lambda c: cond(as_dict(c)), step,
+                         tuple(state[k] for k in keys))
+    return {**state, **as_dict(out)}
+
+
+def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
     """Build the jitted many-world program for one (scheduler, padded node
-    count); XLA retraces per (L, P) bucket.  ``arr_t`` / ``mem`` / ``dur``
-    / ``alloc_mem`` and the float outputs are float64 bit patterns
-    (module docstring, "Float discipline"); all times are non-negative, so
-    their patterns compare like their values."""
+    count, fleet kind); XLA retraces per (L, P) bucket.  ``arr_t`` /
+    ``mem`` / ``dur`` / ``alloc_mem`` and the float outputs are float64
+    bit patterns (module docstring, "Float discipline"); all times are
+    non-negative, so their patterns compare like their values.
+
+    ``autoscale`` adds the binding autoscaler and Alg. 6 (module
+    docstring, "Autoscaled lanes") and its outputs; without it the program
+    is the static-fleet one, which traces none of that code."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    def run(arr_t, cpu, mem, dur, isb, valid, n_nodes, alloc_cpu, alloc_mem):
+    seq_of_index, index_of_seq = node_layout(n_pad)
+
+    def run(arr_t, cpu, mem, dur, isb, valid, n_nodes, alloc_cpu, alloc_mem,
+            *fleet):
         L, P = arr_t.shape
         t_of = jnp.asarray(_T_BITS)
         li = jnp.arange(L)
-        node_active = (jnp.arange(n_pad, dtype=jnp.int32)[None, :]
-                       < n_nodes[:, None])                    # (L, N)
         ac = alloc_cpu[:, None]
         am = alloc_mem[:, None]
+        if autoscale:
+            moveable, boot = fleet
+            node_seq = jnp.asarray(seq_of_index)[None, :]         # (1, N)
+            index_of = jnp.asarray(index_of_seq)
+            autoscaled = node_seq >= n_nodes[:, None]             # (L, N)
+            X = P                      # eviction records per lane
+        else:
+            node_active = (jnp.arange(n_pad, dtype=jnp.int32)[None, :]
+                           < n_nodes[:, None])                    # (L, N)
 
-        def completions(t, st, steps, busy):
+        def completions(t, S):
             """Commit due batch completions one pod per lane per step, in
             (done_time, bind_seq) order — the serial POD_DONE event order
             (heap pops ascending time; push order == bind order within a
-            timestamp).  ``steps`` and ``busy`` count the iterations and
-            the lanes that committed in them."""
+            timestamp).  ``completion_steps`` and ``busy`` count the
+            iterations and the lanes that committed in them."""
+            keys = ["used_cpu", "used_mem", "pcount", "done_c", "done_t",
+                    "bound", "bind_node", "bind_seq", "bind_cycle", "active",
+                    "completed", "done_time", "done_is_cycle",
+                    "completion_steps", "busy"]
+            if autoscale:
+                keys.append("nbatch")
+
             def due_of(c):
-                done_c, done_t, bound, active = c[3], c[4], c[5], c[9]
-                return (valid & isb & bound & ~done_c
-                        & (done_t <= t) & active[:, None])
+                return (valid & isb & c["bound"] & ~c["done_c"]
+                        & (c["done_t"] <= t) & c["active"][:, None])
 
             def cond(c):
                 return due_of(c).any()
 
             def body(c):
-                (used_cpu, used_mem, pcount, done_c, done_t, bound,
-                 bind_node, bind_seq, bind_cycle, active, completed,
-                 done_time, done_is_cycle, steps, busy) = c
+                c = dict(c)
+                done_t, bound = c["done_t"], c["bound"]
+                bind_node, bind_seq = c["bind_node"], c["bind_seq"]
                 due = due_of(c)
                 has = due.any(axis=1)
                 # Two-stage extremum: earliest done_time, then lowest
@@ -315,13 +430,20 @@ def _program_factory(sched: str, n_pad: int):
                 p = jnp.argmin(s1, axis=1)
                 node = jnp.where(has, bind_node[li, p], 0)
                 # serial: node._used_* -= req, one pod at a time.
-                old_c, old_m = used_cpu[li, node], used_mem[li, node]
-                used_cpu = used_cpu.at[li, node].set(
+                old_c = c["used_cpu"][li, node]
+                old_m = c["used_mem"][li, node]
+                c["used_cpu"] = c["used_cpu"].at[li, node].set(
                     jnp.where(has, old_c - cpu[li, p], old_c))
-                used_mem = used_mem.at[li, node].set(
-                    jnp.where(has, f64_add(old_m, mem[li, p] ^ _SIGN), old_m))
-                pcount = pcount.at[li, node].add(-has.astype(jnp.int32))
-                done_c = done_c.at[li, p].set(done_c[li, p] | has)
+                c["used_mem"] = c["used_mem"].at[li, node].set(
+                    jnp.where(has, f64_add(old_m, mem[li, p] ^ _SIGN),
+                              old_m))
+                c["pcount"] = c["pcount"].at[li, node].add(
+                    -has.astype(jnp.int32))
+                if autoscale:
+                    c["nbatch"] = c["nbatch"].at[li, node].add(
+                        -has.astype(jnp.int32))
+                done_c = c["done_c"].at[li, p].set(c["done_c"][li, p] | has)
+                c["done_c"] = done_c
                 # _done() check after this POD_DONE event: all arrived at
                 # the *event's* time, every batch row committed, every
                 # service bound.
@@ -329,103 +451,175 @@ def _program_factory(sched: str, n_pad: int):
                 arrived_td = (~valid | (arr_t <= td[:, None])).all(axis=1)
                 batch_done = (~valid | ~isb | done_c).all(axis=1)
                 svc_bound = (~valid | isb | bound).all(axis=1)
-                now_done = has & active & arrived_td & batch_done & svc_bound
-                completed = completed | now_done
-                done_time = jnp.where(now_done, td, done_time)
-                active = active & ~now_done
-                return (used_cpu, used_mem, pcount, done_c, done_t, bound,
-                        bind_node, bind_seq, bind_cycle, active, completed,
-                        done_time, done_is_cycle, steps + 1,
-                        busy + has.sum(dtype=busy.dtype))
+                now_done = (has & c["active"] & arrived_td & batch_done
+                            & svc_bound)
+                c["completed"] = c["completed"] | now_done
+                c["done_time"] = jnp.where(now_done, td, c["done_time"])
+                c["active"] = c["active"] & ~now_done
+                c["completion_steps"] = c["completion_steps"] + 1
+                c["busy"] = c["busy"] + has.sum(dtype=c["busy"].dtype)
+                return c
 
             with jax.named_scope("completions"):
-                out = lax.while_loop(cond, body, st + (steps, busy))
-            return out[:13], out[13], out[14]
+                return _while(cond, body, S, keys)
 
-        def wave(t, k, st, steps, busy):
+        def scale_out(k, p, pc, pm, blk, c):
+            """The binding autoscaler (Alg. 7) for each lane's blocked pod:
+            a pod already associated with a booting node asks for nothing;
+            else the first booting node in id order whose planned free
+            room holds it absorbs it; else a new node is launched for it,
+            billed from now and READY ``boot`` cycles later.  Planned free
+            room is ``allocatable - req - req ...``, one subtraction per
+            absorbed pod, as the serial tracker sums it."""
+            need = blk & (c["assoc"][li, p] < 0)
+            pf_cpu, pf_mem = c["pf_cpu"], c["pf_mem"]
+            room = ((c["nstate"] == NODE_BOOTING) & (pf_cpu >= pc)
+                    & (_order_key(f64_add(pf_mem, _EPS_BITS))
+                       >= _order_key(pm)))
+            absorb = need & room.any(axis=1)
+            launch = need & ~absorb
+            seq = c["n_launched"]
+            fits_pad = seq < n_pad
+            go = launch & fits_pad
+            c["overflow"] = c["overflow"] | (launch & ~fits_pad)
+            tgt = jnp.where(absorb, jnp.argmax(room, axis=1),
+                            index_of[jnp.minimum(seq, n_pad - 1)]
+                            ).astype(jnp.int32)
+            upd = absorb | go
+            old_c, old_m = pf_cpu[li, tgt], pf_mem[li, tgt]
+            base_c = jnp.where(absorb, old_c, alloc_cpu)
+            base_m = jnp.where(absorb, old_m, alloc_mem)
+            c["pf_cpu"] = pf_cpu.at[li, tgt].set(
+                jnp.where(upd, base_c - pc[:, 0], old_c))
+            c["pf_mem"] = pf_mem.at[li, tgt].set(
+                jnp.where(upd, f64_add(base_m, pm[:, 0] ^ _SIGN), old_m))
+            c["nstate"] = c["nstate"].at[li, tgt].set(
+                jnp.where(go, NODE_BOOTING, c["nstate"][li, tgt]))
+            c["launch_k"] = c["launch_k"].at[li, tgt].set(
+                jnp.where(go, k, c["launch_k"][li, tgt]))
+            c["assoc"] = c["assoc"].at[li, p].set(
+                jnp.where(upd, tgt, c["assoc"][li, p]))
+            c["n_launched"] = seq + go.astype(jnp.int32)
+            c["scale_out_nodes"] = (c["scale_out_nodes"]
+                                    + go.sum(dtype=jnp.int64))
+            return c
+
+        def wave(t, k, S):
             """One scheduling cycle's wave: walk the pending snapshot in
-            row (FIFO) order, one pod per lane per step.  Blocked pods are
-            counted (the serial void/void fallback bumps one scale-out
-            request per blocked pod) and skipped — decision-identical to
-            the serial blocked_keys latch, which only memoizes the same
-            outcome (working frees never grow inside a cycle).  ``steps``
-            and ``busy`` count the iterations and the lanes that attempted
-            a pod in them."""
-            (used_cpu, used_mem, pcount, done_c, done_t, bound,
-             bind_node, bind_seq, bind_cycle, active, completed,
-             done_time, done_is_cycle, seq_ctr, scale_outs) = st
+            FIFO order, one pod per lane per step.  Blocked pods are
+            counted (one scale-out request per blocked pod, as the serial
+            engine counts them) and skipped — decision-identical to the
+            serial blocked_keys latch, which only memoizes the same outcome
+            (working frees never grow inside a cycle).  ``wave_steps`` and
+            ``busy`` count the iterations and the lanes that attempted a
+            pod in them."""
             arrived = valid & (arr_t <= t)
+            active = S["active"]
+            keys = ["used_cpu", "used_mem", "bound", "bind_node", "bind_seq",
+                    "bind_cycle", "done_t", "pcount", "attempted", "placed",
+                    "blocked", "seq_ctr", "wave_steps", "busy"]
+            if autoscale:
+                keys += ["nmove", "nbatch", "assoc", "nstate", "launch_k",
+                         "pf_cpu", "pf_mem", "n_launched", "overflow",
+                         "scale_out_nodes"]
+                pend = S["pend"]
 
             def cand_of(c):
-                bound, attempted = c[2], c[8]
-                return arrived & ~bound & ~attempted & active[:, None]
+                return (arrived & ~c["bound"] & ~c["attempted"]
+                        & active[:, None])
 
             def cond(c):
                 return cand_of(c).any()
 
             def body(c):
-                (used_cpu, used_mem, bound, bind_node, bind_seq,
-                 bind_cycle, done_t, pcount, attempted, placed, blocked,
-                 seq_ctr, steps, busy) = c
+                c = dict(c)
                 cand = cand_of(c)
                 has = cand.any(axis=1)
-                p = jnp.argmax(cand, axis=1)       # first pending row
+                if autoscale:
+                    # FIFO by (pending_since, row): evicted pods re-pend
+                    # at their eviction instant.
+                    p = jnp.argmin(jnp.where(cand, pend, _KEY_MAX), axis=1)
+                else:
+                    p = jnp.argmax(cand, axis=1)       # first pending row
                 pc = cpu[li, p][:, None]
                 pm = mem[li, p][:, None]
+                used_cpu, used_mem = c["used_cpu"], c["used_mem"]
                 # serial WavePlacer: free = alloc - used (elementwise);
                 # fits = (free_cpu >= cpu) & (free_mem + 1e-9 >= mem).
                 free_cpu = ac - used_cpu
                 free_mem = f64_add(am, used_mem ^ _SIGN)
                 mem_fits = (_order_key(f64_add(free_mem, _EPS_BITS))
                             >= _order_key(pm))
-                mask = (free_cpu >= pc) & mem_fits & node_active
-                r = masked_argmin(_wave_keys(sched, free_mem), mask)
-                feas = mask.any(axis=1)
+                fits = (free_cpu >= pc) & mem_fits
+                keys_n = _wave_keys(sched, free_mem)
+                if autoscale:
+                    # READY nodes first; TAINTED ones only when no READY
+                    # node fits (the serial last-resort fallback).
+                    m_r = fits & (c["nstate"] == NODE_READY)
+                    m_t = fits & (c["nstate"] == NODE_TAINTED)
+                    f_r = m_r.any(axis=1)
+                    r = jnp.where(f_r, masked_argmin(keys_n, m_r),
+                                  masked_argmin(keys_n, m_t))
+                    feas = f_r | m_t.any(axis=1)
+                else:
+                    mask = fits & node_active
+                    r = masked_argmin(keys_n, mask)
+                    feas = mask.any(axis=1)
                 do = has & feas
                 blk = has & ~feas
                 r_g = jnp.where(do, r, 0).astype(jnp.int32)
                 old_c, old_m = used_cpu[li, r_g], used_mem[li, r_g]
-                used_cpu = used_cpu.at[li, r_g].set(
+                c["used_cpu"] = used_cpu.at[li, r_g].set(
                     jnp.where(do, old_c + pc[:, 0], old_c))
-                used_mem = used_mem.at[li, r_g].set(
+                c["used_mem"] = used_mem.at[li, r_g].set(
                     jnp.where(do, f64_add(old_m, pm[:, 0]), old_m))
-                pcount = pcount.at[li, r_g].add(do.astype(jnp.int32))
-                bound = bound.at[li, p].set(bound[li, p] | do)
-                bind_node = bind_node.at[li, p].set(
-                    jnp.where(do, r_g, bind_node[li, p]))
-                bind_seq = bind_seq.at[li, p].set(
-                    jnp.where(do, seq_ctr, bind_seq[li, p]))
-                bind_cycle = bind_cycle.at[li, p].set(
-                    jnp.where(do, k, bind_cycle[li, p]))
+                c["pcount"] = c["pcount"].at[li, r_g].add(
+                    do.astype(jnp.int32))
+                if autoscale:
+                    c["nmove"] = c["nmove"].at[li, r_g].add(
+                        (do & moveable[li, p]).astype(jnp.int32))
+                    c["nbatch"] = c["nbatch"].at[li, r_g].add(
+                        (do & isb[li, p]).astype(jnp.int32))
+                c["bound"] = c["bound"].at[li, p].set(c["bound"][li, p] | do)
+                c["bind_node"] = c["bind_node"].at[li, p].set(
+                    jnp.where(do, r_g, c["bind_node"][li, p]))
+                c["bind_seq"] = c["bind_seq"].at[li, p].set(
+                    jnp.where(do, c["seq_ctr"], c["bind_seq"][li, p]))
+                c["bind_cycle"] = c["bind_cycle"].at[li, p].set(
+                    jnp.where(do, k, c["bind_cycle"][li, p]))
                 # Completion timestamp: now + duration (speed factor 1);
                 # services never complete (+inf).
                 td = jnp.where(do & isb[li, p], f64_add(t, dur[li, p]),
                                _INF_BITS)
-                done_t = done_t.at[li, p].set(
-                    jnp.where(do, td, done_t[li, p]))
-                seq_ctr = seq_ctr + do.astype(jnp.int32)
-                placed = placed + do.astype(jnp.int32)
-                blocked = blocked + blk.astype(jnp.int32)
-                attempted = attempted.at[li, p].set(attempted[li, p] | has)
-                return (used_cpu, used_mem, bound, bind_node, bind_seq,
-                        bind_cycle, done_t, pcount, attempted, placed,
-                        blocked, seq_ctr, steps + 1,
-                        busy + has.sum(dtype=busy.dtype))
+                c["done_t"] = c["done_t"].at[li, p].set(
+                    jnp.where(do, td, c["done_t"][li, p]))
+                if autoscale:
+                    c = scale_out(k, p, pc, pm, blk, c)
+                c["seq_ctr"] = c["seq_ctr"] + do.astype(jnp.int32)
+                c["placed"] = c["placed"] + do.astype(jnp.int32)
+                c["blocked"] = c["blocked"] + blk.astype(jnp.int32)
+                c["attempted"] = c["attempted"].at[li, p].set(
+                    c["attempted"][li, p] | has)
+                c["wave_steps"] = c["wave_steps"] + 1
+                c["busy"] = c["busy"] + has.sum(dtype=c["busy"].dtype)
+                return c
 
             zeros_i = jnp.zeros(L, jnp.int32)
             with jax.named_scope("wave"):
-                (used_cpu, used_mem, bound, bind_node, bind_seq, bind_cycle,
-                 done_t, pcount, _att, placed, blocked, seq_ctr, steps, busy
-                 ) = lax.while_loop(
-                    cond, body,
-                    (used_cpu, used_mem, bound, bind_node, bind_seq,
-                     bind_cycle, done_t, pcount, jnp.zeros_like(bound),
-                     zeros_i, zeros_i, seq_ctr, steps, busy))
-            scale_outs = scale_outs + blocked
+                S = _while(cond, body, {**S, "attempted": jnp.zeros_like(
+                    S["bound"]), "placed": zeros_i, "blocked": zeros_i}, keys)
+            placed, blocked = S.pop("placed"), S.pop("blocked")
+            S.pop("attempted")
+            S["scale_outs"] = S["scale_outs"] + blocked
+            if autoscale:
+                with jax.named_scope("scale_in"):
+                    S = scale_in(t, k, S, active & (blocked == 0))
 
-            # -- post-cycle bookkeeping (serial order: wave stats, the
-            # _done() check after the CYCLE event, then stuck detection).
+            # -- post-cycle bookkeeping (serial order: wave stats, scale-in,
+            # the _done() check after the CYCLE event, then stuck
+            # detection).
             with jax.named_scope("cycle_end"):
+                bound, done_c = S["bound"], S["done_c"]
                 all_arrived = (~valid | (arr_t <= t)).all(axis=1)
                 pending_after = (arrived & ~bound).any(axis=1)
                 running_batch = (valid & isb & bound & ~done_c).any(axis=1)
@@ -434,94 +628,309 @@ def _program_factory(sched: str, n_pad: int):
                 has_pods = valid.any(axis=1)
                 done_b = (active & has_pods & all_arrived & batch_done
                           & svc_bound)
-                completed = completed | done_b
-                done_time = jnp.where(done_b, t, done_time)
-                done_is_cycle = done_is_cycle | done_b
+                S["completed"] = S["completed"] | done_b
+                S["done_time"] = jnp.where(done_b, t, S["done_time"])
+                S["done_is_cycle"] = S["done_is_cycle"] | done_b
                 active = active & ~done_b
-                # _permanently_stuck: static cluster, everything arrived,
-                # nothing placed, something blocked, nothing running.
+                # _permanently_stuck: everything arrived, nothing placed,
+                # something blocked, nothing running, nothing booting.
                 stuck_now = (active & all_arrived & (placed == 0)
-                             & (blocked > 0) & ~running_batch & pending_after)
+                             & (blocked > 0) & ~running_batch
+                             & pending_after)
+                if autoscale:
+                    stuck_now = stuck_now & ~(
+                        S["nstate"] == NODE_BOOTING).any(axis=1)
                 active = active & ~stuck_now
                 # Quiescent: all arrived, nothing pending, nothing running,
                 # not done (zero-pod lanes) — state can never change again;
                 # the lane just samples to the horizon (host-side).
-                quies = active & all_arrived & ~pending_after & ~running_batch
+                quies = (active & all_arrived & ~pending_after
+                         & ~running_batch)
                 active = active & ~quies
-            return (used_cpu, used_mem, pcount, done_c, done_t, bound,
-                    bind_node, bind_seq, bind_cycle, active, completed,
-                    done_time, done_is_cycle, seq_ctr, scale_outs,
-                    steps, busy)
+                if autoscale:
+                    # A lane past its node or eviction records stops here;
+                    # the host runs it serially.
+                    active = active & ~S["overflow"]
+                S["active"] = active
+            return S
 
-        def cycle_body(st):
-            k, state = st[0], st[1:16]
-            wave_steps, completion_steps, busy, active_cycles = st[16:]
-            active_cycles += state[9].sum(dtype=active_cycles.dtype)
+        def scale_in(t, k, S, go):
+            """Alg. 6 after a fully successful cycle (``go``): remove the
+            empty autoscaled READY/TAINTED nodes, then visit the non-empty
+            autoscaled READY nodes in launch order.  A node whose pods are
+            all moveable, or whose moveable pods share it with batch pods,
+            is consolidated when every moveable pod fits elsewhere by
+            best-fit on a shadow of the other READY nodes (pods by memory,
+            then row, descending): its moveable pods are evicted in bind
+            order and re-pend now; the first kind of node is removed, the
+            second tainted.  Later candidates see the earlier changes."""
+            nstate = S["nstate"]
+            step1 = (go[:, None] & autoscaled & (S["pcount"] == 0)
+                     & ((nstate == NODE_READY) | (nstate == NODE_TAINTED)))
+            S["nstate"] = jnp.where(step1, NODE_GONE, nstate)
+            S["gone_k"] = jnp.where(step1, k, S["gone_k"])
+            S["gone_step"] = jnp.where(step1, 1, S["gone_step"])
+            n1 = step1.sum(axis=1, dtype=jnp.int32)
+            S["scale_ins"] = S["scale_ins"] + n1
+            S["scale_in_nodes"] = S["scale_in_nodes"] + n1.sum(
+                dtype=jnp.int64)
+            # Only nodes holding moveable pods can change, and a node's
+            # own pods change only when it is visited: the others are
+            # skipped without a visit.
+            n_mv, n_b, n_pods = S["nmove"], S["nbatch"], S["pcount"]
+            cands = (go[:, None] & autoscaled & (n_pods > 0)
+                     & (S["nstate"] == NODE_READY) & (n_mv > 0)
+                     & ((n_mv == n_pods) | ((n_b > 0)
+                                            & (n_mv + n_b == n_pods))))
+            keys = ["used_cpu", "used_mem", "pcount", "nmove", "nstate",
+                    "gone_k", "gone_step", "bound", "pend", "ev_pod",
+                    "ev_node", "ev_bind_cycle", "ev_bind_seq", "ev_pend",
+                    "ev_cycle", "ev_n", "overflow", "scale_ins",
+                    "scale_in_nodes", "scale_in_steps"]
+            bind_node, bind_seq = S["bind_node"], S["bind_seq"]
+            bind_cycle, nbatch = S["bind_cycle"], S["nbatch"]
+            mem_key = _order_key(mem)
+
+            def placeable(c, node, movers, ok):
+                """Shadow best-fit of ``movers`` (one node's moveable pods)
+                onto the other READY nodes, largest first; ``ok`` stays
+                True where every mover found room."""
+                others = ((c["nstate"] == NODE_READY)
+                          & (jnp.arange(n_pad)[None, :] != node[:, None]))
+
+                def cond(s):
+                    return (s[0].any(axis=1) & s[3]).any()
+
+                def body(s):
+                    rem, sh_cpu, sh_mem, ok, steps = s
+                    act = rem.any(axis=1) & ok
+                    mk = jnp.where(rem, mem_key, -1)
+                    top = rem & (mk == mk.max(axis=1, keepdims=True))
+                    q = P - 1 - jnp.argmax(top[:, ::-1], axis=1)
+                    qc, qm = cpu[li, q], mem[li, q]
+                    f = (others & (sh_cpu >= qc[:, None])
+                         & (_order_key(f64_add(sh_mem, _EPS_BITS))
+                            >= _order_key(qm)[:, None]))
+                    b = masked_argmin(_order_key(sh_mem), f)
+                    put = act & f.any(axis=1)
+                    sc, sm = sh_cpu[li, b], sh_mem[li, b]
+                    sh_cpu = sh_cpu.at[li, b].set(
+                        jnp.where(put, sc - qc, sc))
+                    sh_mem = sh_mem.at[li, b].set(
+                        jnp.where(put, f64_add(sm, qm ^ _SIGN), sm))
+                    ok = ok & ~(act & ~put)
+                    rem = rem.at[li, q].set(rem[li, q] & ~act)
+                    return rem, sh_cpu, sh_mem, ok, steps + 1
+
+                s = (movers, ac - c["used_cpu"],
+                     f64_add(am, c["used_mem"] ^ _SIGN), ok,
+                     c["scale_in_steps"])
+                s = lax.while_loop(cond, body, s)
+                c["scale_in_steps"] = s[4]
+                return s[3]
+
+            def evict(c, node, out):
+                """Evict ``out`` (pods on ``node``) in bind order: the
+                node's usage drops one pod at a time, and each pod re-pends
+                now, its closed incarnation recorded."""
+                def cond(s):
+                    return s[0].any()
+
+                def body(s):
+                    out, c = s
+                    c = dict(c)
+                    act = out.any(axis=1)
+                    q = jnp.argmin(jnp.where(out, bind_seq, _SEQ_INF),
+                                   axis=1)
+                    old_c = c["used_cpu"][li, node]
+                    old_m = c["used_mem"][li, node]
+                    c["used_cpu"] = c["used_cpu"].at[li, node].set(
+                        jnp.where(act, old_c - cpu[li, q], old_c))
+                    c["used_mem"] = c["used_mem"].at[li, node].set(
+                        jnp.where(act, f64_add(old_m, mem[li, q] ^ _SIGN),
+                                  old_m))
+                    dec = act.astype(jnp.int32)
+                    c["pcount"] = c["pcount"].at[li, node].add(-dec)
+                    c["nmove"] = c["nmove"].at[li, node].add(-dec)
+                    slot = c["ev_n"]
+                    rec = act & (slot < X)
+                    sl = jnp.minimum(slot, X - 1)
+                    for key, val in (("ev_pod", q), ("ev_node", node),
+                                     ("ev_bind_cycle", bind_cycle[li, q]),
+                                     ("ev_bind_seq", bind_seq[li, q]),
+                                     ("ev_pend", c["pend"][li, q]),
+                                     ("ev_cycle", jnp.full(L, k))):
+                        c[key] = c[key].at[li, sl].set(
+                            jnp.where(rec, val.astype(c[key].dtype),
+                                      c[key][li, sl]))
+                    c["overflow"] = c["overflow"] | (act & (slot >= X))
+                    c["ev_n"] = slot + dec
+                    c["bound"] = c["bound"].at[li, q].set(
+                        c["bound"][li, q] & ~act)
+                    c["pend"] = c["pend"].at[li, q].set(
+                        jnp.where(act, t, c["pend"][li, q]))
+                    c["scale_in_steps"] = c["scale_in_steps"] + 1
+                    return out.at[li, q].set(False), c
+
+                return lax.while_loop(cond, body, (out, c))[1]
+
+            def cond(s):
+                return s[0].any()
+
+            def body(s):
+                cands, c = s
+                c = dict(c)
+                has = cands.any(axis=1)
+                node = jnp.argmin(jnp.where(cands, node_seq, n_pad),
+                                  axis=1).astype(jnp.int32)
+                cands = cands.at[li, node].set(False)
+                only = has & (c["nmove"][li, node] == c["pcount"][li, node])
+                mixed = has & ~only
+                movers = ((only | mixed)[:, None] & c["bound"] & valid
+                          & moveable & (bind_node == node[:, None]))
+                ok = placeable(c, node, movers, only | mixed)
+                c = evict(c, node, movers & ok[:, None])
+                st = c["nstate"][li, node]
+                c["nstate"] = c["nstate"].at[li, node].set(
+                    jnp.where(ok & only, NODE_GONE,
+                              jnp.where(ok & mixed, NODE_TAINTED, st)))
+                gone = ok & only
+                c["gone_k"] = c["gone_k"].at[li, node].set(
+                    jnp.where(gone, k, c["gone_k"][li, node]))
+                c["gone_step"] = c["gone_step"].at[li, node].set(
+                    jnp.where(gone, 2, c["gone_step"][li, node]))
+                c["scale_ins"] = c["scale_ins"] + ok.astype(jnp.int32)
+                c["scale_in_nodes"] = c["scale_in_nodes"] + gone.sum(
+                    dtype=jnp.int64)
+                c["scale_in_steps"] = c["scale_in_steps"] + 1
+                return cands, c
+
+            c = lax.while_loop(cond, body,
+                               (cands, {key: S[key] for key in keys}))[1]
+            return {**S, **c}
+
+        def cycle_body(S):
+            S = dict(S)
+            k = S["k"]
             t = t_of[k]
+            active = S["active"]
+            S["active_lane_cycles"] = S["active_lane_cycles"] + active.sum(
+                dtype=jnp.int64)
+            if autoscale:
+                # NODE_READY events due by t fire before CYCLE(t): the node
+                # joins, and its tracker's pods lose their association.
+                nstate = S["nstate"]
+                ready = (active[:, None] & (nstate == NODE_BOOTING)
+                         & (S["launch_k"] + boot[:, None] <= k))
+                S["nstate"] = jnp.where(ready, NODE_READY, nstate)
+                assoc = S["assoc"]
+                freed = (assoc >= 0) & jnp.take_along_axis(
+                    ready, jnp.maximum(assoc, 0), axis=1)
+                S["assoc"] = jnp.where(freed, -1, assoc)
+                live = ((S["nstate"] != NODE_NONE)
+                        & (S["nstate"] != NODE_GONE))
+                S["active_node_cycles"] = S["active_node_cycles"] + (
+                    live & active[:, None]).sum(dtype=jnp.int64)
             # POD_DONE events at times <= t all fire before CYCLE(t).
-            mid, completion_steps, busy = completions(
-                t, state[:13], completion_steps, busy)
-            *state, wave_steps, busy = wave(t, k, mid + state[13:],
-                                            wave_steps, busy)
-            return (k + 1, *state, wave_steps, completion_steps, busy,
-                    active_cycles)
+            S = completions(t, S)
+            S = wave(t, k, S)
+            S["k"] = k + 1
+            return S
 
-        def cycle_cond(st):
-            k, active = st[0], st[10]
-            return active.any() & (k <= MAX_CYCLES)
+        def cycle_cond(S):
+            return S["active"].any() & (S["k"] <= MAX_CYCLES)
 
-        init = (
-            jnp.zeros((), jnp.int32),                      # k
-            jnp.zeros((L, n_pad), cpu.dtype),              # used_cpu
-            jnp.zeros((L, n_pad), mem.dtype),              # used_mem (+0.0)
-            jnp.zeros((L, n_pad), jnp.int32),              # pcount
-            jnp.zeros((L, P), bool),                       # done_c
-            jnp.full((L, P), _INF_BITS),                   # done_t
-            jnp.zeros((L, P), bool),                       # bound
-            jnp.full((L, P), -1, jnp.int32),               # bind_node
-            jnp.full((L, P), -1, jnp.int32),               # bind_seq
-            jnp.full((L, P), -1, jnp.int32),               # bind_cycle
-            valid.any(axis=1),                             # active
-            jnp.zeros(L, bool),                            # completed
-            jnp.full(L, _HORIZON_BITS),                    # done_time
-            jnp.zeros(L, bool),                            # done_is_cycle
-            jnp.zeros(L, jnp.int32),                       # seq_ctr
-            jnp.zeros(L, jnp.int32),                       # scale_outs
-            jnp.zeros((), jnp.int32),                      # wave_steps
-            jnp.zeros((), jnp.int32),                      # completion_steps
-            jnp.zeros((), jnp.int64),                      # busy_lane_steps
-            jnp.zeros((), jnp.int64),                      # active_lane_cycles
-        )
-        (k, used_cpu, used_mem, pcount, done_c, done_t, bound,
-         bind_node, bind_seq, bind_cycle, active, completed, done_time,
-         done_is_cycle, seq_ctr, scale_outs, wave_steps, completion_steps,
-         busy, active_cycles) = lax.while_loop(cycle_cond, cycle_body, init)
-        return {
-            "bound": bound, "done_committed": done_c,
-            "bind_node": bind_node, "bind_seq": bind_seq,
-            "bind_cycle": bind_cycle, "done_t": done_t,
-            "completed": completed, "done_time": done_time,
-            "done_is_cycle": done_is_cycle, "scale_outs": scale_outs,
-            "n_cycles": k, "wave_steps": wave_steps,
-            "completion_steps": completion_steps, "busy_lane_steps": busy,
-            "active_lane_cycles": active_cycles,
-            "used_cpu": used_cpu, "used_mem": used_mem, "pcount": pcount,
+        S = {
+            "k": jnp.zeros((), jnp.int32),
+            "used_cpu": jnp.zeros((L, n_pad), cpu.dtype),
+            "used_mem": jnp.zeros((L, n_pad), mem.dtype),      # +0.0
+            "pcount": jnp.zeros((L, n_pad), jnp.int32),
+            "done_c": jnp.zeros((L, P), bool),
+            "done_t": jnp.full((L, P), _INF_BITS),
+            "bound": jnp.zeros((L, P), bool),
+            "bind_node": jnp.full((L, P), -1, jnp.int32),
+            "bind_seq": jnp.full((L, P), -1, jnp.int32),
+            "bind_cycle": jnp.full((L, P), -1, jnp.int32),
+            "active": valid.any(axis=1),
+            "completed": jnp.zeros(L, bool),
+            "done_time": jnp.full(L, _HORIZON_BITS),
+            "done_is_cycle": jnp.zeros(L, bool),
+            "seq_ctr": jnp.zeros(L, jnp.int32),
+            "scale_outs": jnp.zeros(L, jnp.int32),
+            "wave_steps": jnp.zeros((), jnp.int32),
+            "completion_steps": jnp.zeros((), jnp.int32),
+            "busy": jnp.zeros((), jnp.int64),
+            "active_lane_cycles": jnp.zeros((), jnp.int64),
         }
+        if autoscale:
+            zl = jnp.zeros(L, jnp.int32)
+            S.update(
+                nstate=jnp.where(autoscaled, NODE_NONE,
+                                 NODE_READY).astype(jnp.int32),
+                launch_k=jnp.zeros((L, n_pad), jnp.int32),
+                gone_k=jnp.full((L, n_pad), -1, jnp.int32),
+                gone_step=jnp.zeros((L, n_pad), jnp.int32),
+                nmove=jnp.zeros((L, n_pad), jnp.int32),
+                nbatch=jnp.zeros((L, n_pad), jnp.int32),
+                pf_cpu=jnp.zeros((L, n_pad), cpu.dtype),
+                pf_mem=jnp.zeros((L, n_pad), mem.dtype),
+                n_launched=n_nodes.astype(jnp.int32),
+                assoc=jnp.full((L, P), -1, jnp.int32),
+                pend=arr_t,
+                ev_pod=jnp.zeros((L, X), jnp.int32),
+                ev_node=jnp.zeros((L, X), jnp.int32),
+                ev_bind_cycle=jnp.zeros((L, X), jnp.int32),
+                ev_bind_seq=jnp.zeros((L, X), jnp.int32),
+                ev_pend=jnp.zeros((L, X), arr_t.dtype),
+                ev_cycle=jnp.zeros((L, X), jnp.int32),
+                ev_n=zl, overflow=jnp.zeros(L, bool), scale_ins=zl,
+                scale_out_nodes=jnp.zeros((), jnp.int64),
+                scale_in_nodes=jnp.zeros((), jnp.int64),
+                active_node_cycles=jnp.zeros((), jnp.int64),
+                scale_in_steps=jnp.zeros((), jnp.int64))
+        S = _while(cycle_cond, cycle_body, S, list(S))
+        out = {
+            "bound": S["bound"], "done_committed": S["done_c"],
+            "bind_node": S["bind_node"], "bind_seq": S["bind_seq"],
+            "bind_cycle": S["bind_cycle"], "done_t": S["done_t"],
+            "completed": S["completed"], "done_time": S["done_time"],
+            "done_is_cycle": S["done_is_cycle"],
+            "scale_outs": S["scale_outs"],
+            "n_cycles": S["k"], "wave_steps": S["wave_steps"],
+            "completion_steps": S["completion_steps"],
+            "busy_lane_steps": S["busy"],
+            "active_lane_cycles": S["active_lane_cycles"],
+            "used_cpu": S["used_cpu"], "used_mem": S["used_mem"],
+            "pcount": S["pcount"],
+        }
+        if autoscale:
+            for key in ("nstate", "launch_k", "gone_k", "gone_step", "pend",
+                        "ev_pod", "ev_node", "ev_bind_cycle", "ev_bind_seq",
+                        "ev_pend", "ev_cycle", "ev_n", "overflow",
+                        "scale_ins") + FLEET_COUNTERS:
+                out[key] = S[key]
+        return out
 
-    run.__name__ = run.__qualname__ = "lane_program_" + sched.replace("-", "_")
+    name = "lane_program_" + sched.replace("-", "_")
+    if autoscale:
+        name += "_autoscaled"
+    run.__name__ = run.__qualname__ = name
     return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_cache(sched: str, n_pad: int):
-    return _program_factory(sched, n_pad)
+def _jit_cache(sched: str, n_pad: int, autoscale: bool = False):
+    return _program_factory(sched, n_pad, autoscale)
 
 
 def program_args(batch: LaneBatch) -> tuple:
     """The program's host arguments for ``batch``, floats as bit patterns."""
-    return (f64_bits(batch.arrival_t), batch.cpu_m, f64_bits(batch.mem_mb),
+    args = (f64_bits(batch.arrival_t), batch.cpu_m, f64_bits(batch.mem_mb),
             f64_bits(batch.duration_s), batch.is_batch, batch.valid,
             batch.n_nodes, batch.alloc_cpu, f64_bits(batch.alloc_mem))
+    if batch.autoscale:
+        args += (batch.moveable, batch.boot_cycles)
+    return args
 
 
 def run_lane_batch(batch: LaneBatch) -> dict:
@@ -532,7 +941,16 @@ def run_lane_batch(batch: LaneBatch) -> dict:
     serial parity maps ``node_slot`` through ``ClusterArrays.id_rank``),
     ``bind_seq`` (per-lane bind order), ``bind_cycle`` (bind time is
     exactly ``bind_cycle * 10.0``), ``done_t`` and ``done_committed``.
-    Per batch: the scalar step counts of :data:`COUNTERS`.
+    Per batch: the scalar step counts of :data:`COUNTERS`.  An autoscaled
+    batch adds, per node record (index as :func:`node_layout`):
+    ``nstate`` (:data:`NODE_STATES`), ``launch_k`` (launch cycle, 0 for
+    a static node), ``gone_k`` / ``gone_step`` (the cycle and Alg. 6 step
+    that removed it, or -1 / 0); per pod ``pend`` (pending since); per
+    eviction, in order, ``ev_pod`` / ``ev_node`` / ``ev_bind_cycle`` /
+    ``ev_bind_seq`` / ``ev_pend`` (the closed incarnation) and
+    ``ev_cycle``, ``ev_n`` of them; per lane ``scale_ins`` and
+    ``overflow`` (past its node or eviction records: its outputs are not
+    a run); and the counts of :data:`FLEET_COUNTERS`.
 
     Spans of :data:`PROFILER`: ``lanes.dispatch`` (arguments and the jit
     call), ``lanes.wait`` (until the device is done), ``lanes.fetch`` (the
@@ -542,12 +960,14 @@ def run_lane_batch(batch: LaneBatch) -> dict:
     span = PROFILER.span
     with jax.enable_x64(True):
         with span("lanes.dispatch"):
-            run = _jit_cache(batch.scheduler, batch.n_pad)
+            run = _jit_cache(batch.scheduler, batch.n_pad, batch.autoscale)
             dev = run(*program_args(batch))
         with span("lanes.wait"):
             jax.block_until_ready(dev)
         with span("lanes.fetch"):
             out = {key: np.asarray(v) for key, v in dev.items()}
-            for key in ("done_t", "done_time", "used_mem"):
-                out[key] = out[key].view(np.float64)
+            for key in ("done_t", "done_time", "used_mem", "pend",
+                        "ev_pend"):
+                if key in out:
+                    out[key] = out[key].view(np.float64)
     return out

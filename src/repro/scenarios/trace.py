@@ -209,7 +209,8 @@ class TraceStore:
     def to_lane_arrays(self) -> Dict:
         """Per-lane workload columns for the many-world engine
         (`repro.manyworld.lanes.stack_lanes`): float64 request/duration
-        columns plus the batch-kind mask, in trace row order.  The caller
+        columns plus the batch-kind and moveable masks, in trace row
+        order.  The caller
         adds the cluster scalars (``n_nodes`` / ``alloc_*`` / weights);
         ``stack_lanes`` pads the pod axis across lanes.  Integer CPU
         milli-units are exact in float64 (far below 2^53), so the lane
@@ -221,6 +222,7 @@ class TraceStore:
             "mem_mb": self.mem_mb.astype(np.float64),
             "duration_s": self.duration_s.astype(np.float64),
             "is_batch": self.kind == KIND_BATCH,
+            "moveable": self.moveable.copy(),
         }
 
     # -- slicing / composition -------------------------------------------------

@@ -75,6 +75,27 @@ def test_lane_program_compiles_for_v5e(one_chip, sched):
     assert "f64[" not in compiled.as_text()      # no float64 operand
 
 
+def test_autoscaled_lane_program_compiles_for_v5e(one_chip):
+    """The autoscaled lane program (binding autoscaler, Alg. 6) at the
+    policy-search population of its benchmark cell: 4096 lanes x 64 pods
+    x 64 node records, int64 bit patterns only."""
+    lane = {"arrival_t": np.zeros(1), "cpu_m": np.zeros(1),
+            "mem_mb": np.zeros(1), "duration_s": np.zeros(1),
+            "is_batch": np.ones(1, bool), "moveable": np.zeros(1, bool),
+            "n_nodes": 1, "alloc_cpu": 940, "alloc_mem": 3584.0,
+            "boot_cycles": 5}
+    tiny = ml.stack_lanes([lane], "best-fit", node_pad=64)
+    with jax.enable_x64(True):
+        args = [jax.ShapeDtypeStruct((4096, 64) if a.ndim == 2
+                                     else (4096,), a.dtype,
+                                     sharding=one_chip)
+                for a in ml.program_args(tiny)]
+        compiled = ml._program_factory("best-fit", 64, True).lower(
+            *args).compile()
+    _fits_one_chip(compiled)
+    assert "f64[" not in compiled.as_text()      # no float64 operand
+
+
 def _forecaster_shapes(one_chip):
     from repro.forecast import model as fmodel
     from repro.models.params import init_params

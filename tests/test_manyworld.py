@@ -144,9 +144,13 @@ class TestEvaluatorRows:
                      autoscaler="void", rescheduler="void", seed=1,
                      n_jobs=40, engine="array", initial_workers=5,
                      scheduler_weights=(0.2, 0.5, 0.3)),
-            # ineligible: binding autoscaler -> serial fallback
+            # the binding autoscaler: an autoscaled lane
             CellSpec(scenario="heavy-tail", scheduler="best-fit",
                      autoscaler="binding", seed=0, n_jobs=16,
+                     engine="array"),
+            # ineligible: the non-binding autoscaler -> serial fallback
+            CellSpec(scenario="heavy-tail", scheduler="best-fit",
+                     autoscaler="non-binding", seed=0, n_jobs=16,
                      engine="array"),
             # infeasible short-circuit: heavy-tail pods exceed m2.tiny
             CellSpec(scenario="heavy-tail", scheduler="best-fit",
@@ -171,7 +175,21 @@ class TestEvaluatorRows:
         assert not lane_eligible(CellSpec(**{**base, "scheduler": "k8s-default"}))
         assert not lane_eligible(CellSpec(**{**base, "scheduler": "weighted"}))
         assert lane_eligible(CellSpec(**{**base, "engine": None}))
-        assert not lane_eligible(CellSpec(**{**base, "autoscaler": "binding"}))
+        # The binding autoscaler runs as lanes; the other autoscalers, an
+        # Alg. 6 gate and a delay the program cannot hold stay serial.
+        assert lane_eligible(CellSpec(**{**base, "autoscaler": "binding"}))
+        assert lane_eligible(CellSpec(**{**base, "autoscaler": "binding",
+                                         "template_name": "tpu-v5e-host"}))
+        assert not lane_eligible(CellSpec(**{**base,
+                                             "autoscaler": "non-binding"}))
+        assert not lane_eligible(CellSpec(**{**base,
+                                             "autoscaler": "predictive"}))
+        assert not lane_eligible(CellSpec(**{**base, "autoscaler": "binding",
+                                             "scale_in_util_ceiling": 0.5}))
+        assert not lane_eligible(CellSpec(**{**base, "autoscaler": "binding",
+                                             "template_name": "no-such"}))
+        assert not lane_eligible(CellSpec(**{**base, "autoscaler": "binding",
+                                             "rescheduler": "binding"}))
         assert not lane_eligible(CellSpec(**{**base, "rescheduler": "non-binding"}))
         assert not lane_eligible(CellSpec(**{**base, "engine": "object"}))
         assert not lane_eligible(
@@ -790,3 +808,162 @@ class TestExactSums:
         assert np.array_equal(hi + lo, v)
         for vi, mi, h, l in zip(v, m, m * hi, m * lo):
             assert Fraction(h) + Fraction(l) == Fraction(vi) * int(mi)
+
+
+# -- autoscaled lanes: the binding autoscaler and Alg. 6 ---------------------
+
+#: Hand-built autoscaled lanes, (static nodes, pods as in CORNER_LANES).
+FLEET_LANES = [
+    # out, in, out again: B waits for node-1 (launched at 10, READY at
+    # 60), which Alg. 6 removes once B is done (160); D launches node-2 at
+    # 210, but C's end at 250 frees node-0 first: D binds there, and the
+    # empty node-2 goes at 260; D completes the lane at 300
+    (1, [(0.0, 900, 100.0, 100.0), (1.0, 900, 100.0, 100.0),
+         (200.0, 900, 100.0, 50.0), (201.0, 900, 100.0, 50.0)]),
+    # never past one node: everything fits the static worker
+    (1, [(0.0, 100, 300.0, 60.0), (4.0, 200, 600.0, None),
+         (9.0, 100, 300.0, 30.0)]),
+    # step 2: the service S waits for node-1; once A leaves node-0 (100),
+    # Alg. 6 moves S there and removes node-1; S binds again at 110, and
+    # C completes the lane at 510
+    (1, [(0.0, 800, 100.0, 100.0), (1.0, 300, 1000.0, None),
+         (3.0, 100, 100.0, 500.0)]),
+    # step 3: S and the batch pod B share node-1 (B absorbed by its
+    # tracker); at 100 S moves to node-0 and node-1 is tainted until B
+    # completes the lane at 360
+    (1, [(0.0, 800, 100.0, 100.0), (1.0, 300, 1000.0, None),
+         (2.0, 300, 100.0, 300.0)]),
+]
+
+
+def _fleet_trace(seed, _n_jobs):
+    _nodes, pods = FLEET_LANES[seed % len(FLEET_LANES)]
+    specs = [PodSpec(f"p{i}", PodKind.SERVICE if dur is None
+                     else PodKind.BATCH, Resources(cpu, mem),
+                     duration_s=dur or 0.0, moveable=dur is None)
+             for i, (_t, cpu, mem, dur) in enumerate(pods)]
+    return TraceStore(specs, np.arange(len(pods)), [p[0] for p in pods],
+                      duration_s=[p[3] or 0.0 for p in pods],
+                      name="fleet-corners")
+
+
+def _fleet_cells(sched="best-fit", **kw):
+    register("fleet-corners", _fleet_trace, overwrite=True)
+    return [CellSpec(scenario="fleet-corners", scheduler=sched,
+                     autoscaler="binding", rescheduler="void", seed=i,
+                     engine="array", initial_workers=nodes, **kw)
+            for i, (nodes, _pods) in enumerate(FLEET_LANES)]
+
+
+def _assert_rows_equal(serial, rows):
+    assert [r["label"] for r in rows] == [r["label"] for r in serial]
+    for s, l in zip(serial, rows):
+        for field in _RESULT_FIELDS:
+            assert s[field] == l[field], (s["label"], field)
+            assert type(s[field]) is type(l[field]), (s["label"], field)
+        assert (s["infeasible"], s["n_jobs"]) == (l["infeasible"],
+                                                  l["n_jobs"])
+
+
+def _no_serial_runs(monkeypatch):
+    """Fail any cell that would run on the serial engine."""
+    import repro.search.runner as runner
+
+    def refuse(cell):
+        raise AssertionError(f"{cell.label} ran serially")
+    monkeypatch.setattr(runner, "run_cell", refuse)
+
+
+class TestAutoscaledLanes:
+    @pytest.mark.parametrize("scen,sched,n", [
+        ("paper-bursty", "best-fit", 16), ("paper-slow", "best-fit", 8),
+        ("paper-bursty", "worst-fit", 4), ("paper-mixed", "first-fit", 4)])
+    def test_rows_bitwise_equal_serial(self, scen, sched, n, monkeypatch):
+        """Seeded paper traces on the paper's chain from one worker: every
+        lane row equals the serial ``run_cell`` row, field by field, and
+        no cell ran serially."""
+        cells = [CellSpec(scenario=scen, scheduler=sched,
+                          autoscaler="binding", rescheduler="void",
+                          seed=s, initial_workers=1) for s in range(n)]
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        rec = lane_calls(1)[0]
+        assert (rec["lanes"], rec["counts"]["lane_fallbacks"]) == (n, 0)
+        _assert_rows_equal(serial, rows)
+        assert sum(r["evictions"] for r in serial) > 0
+        assert sum(r["scale_ins"] for r in serial) > 0
+        assert max(r["max_nodes"] for r in serial) > 4
+
+    @pytest.mark.parametrize("sched", ["best-fit", "first-fit"])
+    def test_hand_built_fleets_equal_serial(self, sched, monkeypatch):
+        """Out, in and out again; a fleet that never grows; services
+        drained and a node tainted by Alg. 6: rows equal serial."""
+        cells = _fleet_cells(sched)
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        out_in_out, one_node, drained, tainted = serial
+        assert (out_in_out["scale_ins"], out_in_out["max_nodes"]) == (2, 2)
+        assert out_in_out["node_seconds"] == 300 + 150 + 50
+        assert (one_node["max_nodes"], one_node["scale_outs"]) == (1, 0)
+        assert (drained["evictions"], drained["scale_ins"],
+                drained["duration_s"]) == (1, 1, 510.0)
+        assert (tainted["evictions"], tainted["scale_ins"],
+                tainted["duration_s"]) == (1, 1, 360.0)
+
+    def test_pod_no_node_holds_is_infeasible(self):
+        """A pod larger than the template: the serial short-circuit row,
+        beside an autoscaled lane that runs."""
+        cells = [CellSpec(scenario="paper-bursty", scheduler="best-fit",
+                          autoscaler="binding", rescheduler="void", seed=s,
+                          initial_workers=1, template_name=tpl)
+                 for s, tpl in ((0, "m2.tiny"), (1, None))]
+        serial = run_cells(cells, workers=1)
+        rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        assert rows[0]["infeasible"] and not rows[1]["infeasible"]
+        assert lane_calls(1)[0]["lanes"] == 1
+
+    def test_lane_past_its_records_runs_serially(self, monkeypatch):
+        """Node records too few for a lane's launches: the lane stops on
+        the device and its row comes from the serial engine, counted."""
+        cells = [CellSpec(scenario="paper-bursty", scheduler="best-fit",
+                          autoscaler="binding", rescheduler="void", seed=s,
+                          initial_workers=1) for s in range(3)]
+        serial = run_cells(cells, workers=1)
+        monkeypatch.setattr(ev, "_node_records", lambda cell, trace: 4)
+        rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        assert lane_calls(1)[0]["counts"]["lane_fallbacks"] == 3
+
+    def test_fleet_counters_on_one_lane(self):
+        """The out-in-out lane by hand: 31 cycles (t = 0 .. 300), node-0
+        live in each, node-1 in cycles 2-16 (launched in cycle 1, removed
+        by Alg. 6 in cycle 16), node-2 in cycles 22-26: 51 live node
+        cycles; two launches, two removals."""
+        trace = _fleet_trace(0, None)
+        lane = {**_lane_of(trace, 1), "boot_cycles": 5}
+        out = ml.run_lane_batch(ml.stack_lanes([lane], "best-fit",
+                                               node_pad=8))
+        assert int(out["n_cycles"]) == 31
+        assert (int(out["scale_out_nodes"]), int(out["scale_in_nodes"]),
+                int(out["active_node_cycles"])) == (2, 2, 51)
+        assert int(out["scale_ins"][0]) == 2 and not out["overflow"][0]
+        seq_of, index_of = ml.node_layout(8)
+        assert list(out["launch_k"][0, index_of[:3]]) == [0, 1, 21]
+        assert list(out["gone_k"][0, index_of[:3]]) == [-1, 16, 26]
+        assert list(out["gone_step"][0, index_of[:3]]) == [0, 1, 1]
+        assert list(out["nstate"][0, index_of[:3]]) == [
+            ml.NODE_READY, ml.NODE_GONE, ml.NODE_GONE]
+        assert sorted(seq_of) == list(range(8))
+
+    def test_node_layout_orders_ids_as_strings(self):
+        seq_of, index_of = ml.node_layout(16)
+        names = [f"node-{s}" for s in seq_of]
+        assert names == sorted(names)
+        assert list(index_of[seq_of]) == list(range(16))
+        assert seq_of[:3].tolist() == [0, 1, 10]
