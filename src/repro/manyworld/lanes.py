@@ -332,6 +332,47 @@ def masked_argmin(keys, mask):
     return jnp.argmin(buf, axis=1).astype(jnp.int32)
 
 
+# Per-lane row access inside the step loops.  A TPU scatter or gather
+# with one index per lane runs serially over the lanes (about 0.1 ms a
+# call at 4,096 lanes on a TPU v5e), while a select over a whole (lane,
+# row) array of a few dozen columns is one vector pass; so every read and
+# write of one element per lane is a one-hot select over the row axis.
+# A select moves bits, so the values are those of the indexed forms.
+
+def _row_hit(width: int, i):
+    """``(L, width)`` bool, True at column ``i[l]`` of lane ``l``."""
+    import jax.numpy as jnp
+    return jnp.arange(width, dtype=jnp.int32)[None, :] == i[:, None]
+
+
+def _row_put(x, i, v, m):
+    """``x`` with ``x[l, i[l]] = v[l]`` (or the scalar ``v``) in the lanes
+    where ``m[l]``: ``x.at[li, i].set(jnp.where(m, v, x[li, i]))``."""
+    import jax.numpy as jnp
+    v = jnp.asarray(v, x.dtype)
+    return jnp.where(_row_hit(x.shape[1], i) & m[:, None],
+                     v[:, None] if v.ndim else v, x)
+
+
+def _row_add(x, i, d, m):
+    """``x`` with the scalar ``d`` added at ``x[l, i[l]]`` in the lanes
+    where ``m[l]``: ``x.at[li, i].add(jnp.where(m, d, 0))``."""
+    import jax.numpy as jnp
+    return x + jnp.where(_row_hit(x.shape[1], i) & m[:, None],
+                         jnp.asarray(d, x.dtype), 0)
+
+
+def _row_pick(x, i):
+    """``x[l, i[l]]`` per lane (``x`` may be one row, ``(1, N)``): the one
+    selected element survives a max (an ``any`` for bool) whose fill is
+    the dtype's least value, so the result is exact."""
+    import jax.numpy as jnp
+    hit = _row_hit(x.shape[1], i)
+    if x.dtype == jnp.bool_:
+        return (hit & x).any(axis=1)
+    return jnp.where(hit, x, jnp.iinfo(x.dtype).min).max(axis=1)
+
+
 def _wave_keys(sched: str, free_mem):
     """Per-node score keys for one pod per lane, **negated for max-mode**
     so one masked-argmin select serves every policy: the serial
@@ -383,7 +424,6 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
             *fleet):
         L, P = arr_t.shape
         t_of = jnp.asarray(_T_BITS)
-        li = jnp.arange(L)
         ac = alloc_cpu[:, None]
         am = alloc_mem[:, None]
         if autoscale:
@@ -428,26 +468,24 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 tmin = t1.min(axis=1, keepdims=True)
                 s1 = jnp.where(due & (t1 == tmin), bind_seq, _SEQ_INF)
                 p = jnp.argmin(s1, axis=1)
-                node = jnp.where(has, bind_node[li, p], 0)
+                node = jnp.where(has, _row_pick(bind_node, p), 0)
                 # serial: node._used_* -= req, one pod at a time.
-                old_c = c["used_cpu"][li, node]
-                old_m = c["used_mem"][li, node]
-                c["used_cpu"] = c["used_cpu"].at[li, node].set(
-                    jnp.where(has, old_c - cpu[li, p], old_c))
-                c["used_mem"] = c["used_mem"].at[li, node].set(
-                    jnp.where(has, f64_add(old_m, mem[li, p] ^ _SIGN),
-                              old_m))
-                c["pcount"] = c["pcount"].at[li, node].add(
-                    -has.astype(jnp.int32))
+                old_c = _row_pick(c["used_cpu"], node)
+                old_m = _row_pick(c["used_mem"], node)
+                c["used_cpu"] = _row_put(c["used_cpu"], node,
+                                         old_c - _row_pick(cpu, p), has)
+                c["used_mem"] = _row_put(
+                    c["used_mem"], node,
+                    f64_add(old_m, _row_pick(mem, p) ^ _SIGN), has)
+                c["pcount"] = _row_add(c["pcount"], node, -1, has)
                 if autoscale:
-                    c["nbatch"] = c["nbatch"].at[li, node].add(
-                        -has.astype(jnp.int32))
-                done_c = c["done_c"].at[li, p].set(c["done_c"][li, p] | has)
+                    c["nbatch"] = _row_add(c["nbatch"], node, -1, has)
+                done_c = _row_put(c["done_c"], p, True, has)
                 c["done_c"] = done_c
                 # _done() check after this POD_DONE event: all arrived at
                 # the *event's* time, every batch row committed, every
-                # service bound.
-                td = jnp.where(has, done_t[li, p], _INF_BITS)
+                # service bound.  done_t[p] is tmin, bit for bit.
+                td = jnp.where(has, tmin[:, 0], _INF_BITS)
                 arrived_td = (~valid | (arr_t <= td[:, None])).all(axis=1)
                 batch_done = (~valid | ~isb | done_c).all(axis=1)
                 svc_bound = (~valid | isb | bound).all(axis=1)
@@ -471,7 +509,7 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
             billed from now and READY ``boot`` cycles later.  Planned free
             room is ``allocatable - req - req ...``, one subtraction per
             absorbed pod, as the serial tracker sums it."""
-            need = blk & (c["assoc"][li, p] < 0)
+            need = blk & (_row_pick(c["assoc"], p) < 0)
             pf_cpu, pf_mem = c["pf_cpu"], c["pf_mem"]
             room = ((c["nstate"] == NODE_BOOTING) & (pf_cpu >= pc)
                     & (_order_key(f64_add(pf_mem, _EPS_BITS))
@@ -483,22 +521,18 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
             go = launch & fits_pad
             c["overflow"] = c["overflow"] | (launch & ~fits_pad)
             tgt = jnp.where(absorb, jnp.argmax(room, axis=1),
-                            index_of[jnp.minimum(seq, n_pad - 1)]
+                            _row_pick(index_of[None, :],
+                                      jnp.minimum(seq, n_pad - 1))
                             ).astype(jnp.int32)
             upd = absorb | go
-            old_c, old_m = pf_cpu[li, tgt], pf_mem[li, tgt]
-            base_c = jnp.where(absorb, old_c, alloc_cpu)
-            base_m = jnp.where(absorb, old_m, alloc_mem)
-            c["pf_cpu"] = pf_cpu.at[li, tgt].set(
-                jnp.where(upd, base_c - pc[:, 0], old_c))
-            c["pf_mem"] = pf_mem.at[li, tgt].set(
-                jnp.where(upd, f64_add(base_m, pm[:, 0] ^ _SIGN), old_m))
-            c["nstate"] = c["nstate"].at[li, tgt].set(
-                jnp.where(go, NODE_BOOTING, c["nstate"][li, tgt]))
-            c["launch_k"] = c["launch_k"].at[li, tgt].set(
-                jnp.where(go, k, c["launch_k"][li, tgt]))
-            c["assoc"] = c["assoc"].at[li, p].set(
-                jnp.where(upd, tgt, c["assoc"][li, p]))
+            base_c = jnp.where(absorb, _row_pick(pf_cpu, tgt), alloc_cpu)
+            base_m = jnp.where(absorb, _row_pick(pf_mem, tgt), alloc_mem)
+            c["pf_cpu"] = _row_put(pf_cpu, tgt, base_c - pc[:, 0], upd)
+            c["pf_mem"] = _row_put(pf_mem, tgt,
+                                   f64_add(base_m, pm[:, 0] ^ _SIGN), upd)
+            c["nstate"] = _row_put(c["nstate"], tgt, NODE_BOOTING, go)
+            c["launch_k"] = _row_put(c["launch_k"], tgt, k, go)
+            c["assoc"] = _row_put(c["assoc"], p, tgt, upd)
             c["n_launched"] = seq + go.astype(jnp.int32)
             c["scale_out_nodes"] = (c["scale_out_nodes"]
                                     + go.sum(dtype=jnp.int64))
@@ -541,8 +575,8 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                     p = jnp.argmin(jnp.where(cand, pend, _KEY_MAX), axis=1)
                 else:
                     p = jnp.argmax(cand, axis=1)       # first pending row
-                pc = cpu[li, p][:, None]
-                pm = mem[li, p][:, None]
+                pc = _row_pick(cpu, p)[:, None]
+                pm = _row_pick(mem, p)[:, None]
                 used_cpu, used_mem = c["used_cpu"], c["used_mem"]
                 # serial WavePlacer: free = alloc - used (elementwise);
                 # fits = (free_cpu >= cpu) & (free_mem + 1e-9 >= mem).
@@ -568,38 +602,32 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 do = has & feas
                 blk = has & ~feas
                 r_g = jnp.where(do, r, 0).astype(jnp.int32)
-                old_c, old_m = used_cpu[li, r_g], used_mem[li, r_g]
-                c["used_cpu"] = used_cpu.at[li, r_g].set(
-                    jnp.where(do, old_c + pc[:, 0], old_c))
-                c["used_mem"] = used_mem.at[li, r_g].set(
-                    jnp.where(do, f64_add(old_m, pm[:, 0]), old_m))
-                c["pcount"] = c["pcount"].at[li, r_g].add(
-                    do.astype(jnp.int32))
+                old_c = _row_pick(used_cpu, r_g)
+                old_m = _row_pick(used_mem, r_g)
+                c["used_cpu"] = _row_put(used_cpu, r_g, old_c + pc[:, 0], do)
+                c["used_mem"] = _row_put(used_mem, r_g,
+                                         f64_add(old_m, pm[:, 0]), do)
+                c["pcount"] = _row_add(c["pcount"], r_g, 1, do)
+                is_b = _row_pick(isb, p)
                 if autoscale:
-                    c["nmove"] = c["nmove"].at[li, r_g].add(
-                        (do & moveable[li, p]).astype(jnp.int32))
-                    c["nbatch"] = c["nbatch"].at[li, r_g].add(
-                        (do & isb[li, p]).astype(jnp.int32))
-                c["bound"] = c["bound"].at[li, p].set(c["bound"][li, p] | do)
-                c["bind_node"] = c["bind_node"].at[li, p].set(
-                    jnp.where(do, r_g, c["bind_node"][li, p]))
-                c["bind_seq"] = c["bind_seq"].at[li, p].set(
-                    jnp.where(do, c["seq_ctr"], c["bind_seq"][li, p]))
-                c["bind_cycle"] = c["bind_cycle"].at[li, p].set(
-                    jnp.where(do, k, c["bind_cycle"][li, p]))
+                    c["nmove"] = _row_add(c["nmove"], r_g, 1,
+                                          do & _row_pick(moveable, p))
+                    c["nbatch"] = _row_add(c["nbatch"], r_g, 1, do & is_b)
+                c["bound"] = _row_put(c["bound"], p, True, do)
+                c["bind_node"] = _row_put(c["bind_node"], p, r_g, do)
+                c["bind_seq"] = _row_put(c["bind_seq"], p, c["seq_ctr"], do)
+                c["bind_cycle"] = _row_put(c["bind_cycle"], p, k, do)
                 # Completion timestamp: now + duration (speed factor 1);
                 # services never complete (+inf).
-                td = jnp.where(do & isb[li, p], f64_add(t, dur[li, p]),
+                td = jnp.where(do & is_b, f64_add(t, _row_pick(dur, p)),
                                _INF_BITS)
-                c["done_t"] = c["done_t"].at[li, p].set(
-                    jnp.where(do, td, c["done_t"][li, p]))
+                c["done_t"] = _row_put(c["done_t"], p, td, do)
                 if autoscale:
                     c = scale_out(k, p, pc, pm, blk, c)
                 c["seq_ctr"] = c["seq_ctr"] + do.astype(jnp.int32)
                 c["placed"] = c["placed"] + do.astype(jnp.int32)
                 c["blocked"] = c["blocked"] + blk.astype(jnp.int32)
-                c["attempted"] = c["attempted"].at[li, p].set(
-                    c["attempted"][li, p] | has)
+                c["attempted"] = _row_put(c["attempted"], p, True, has)
                 c["wave_steps"] = c["wave_steps"] + 1
                 c["busy"] = c["busy"] + has.sum(dtype=c["busy"].dtype)
                 return c
@@ -707,19 +735,18 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                     mk = jnp.where(rem, mem_key, -1)
                     top = rem & (mk == mk.max(axis=1, keepdims=True))
                     q = P - 1 - jnp.argmax(top[:, ::-1], axis=1)
-                    qc, qm = cpu[li, q], mem[li, q]
+                    qc, qm = _row_pick(cpu, q), _row_pick(mem, q)
                     f = (others & (sh_cpu >= qc[:, None])
                          & (_order_key(f64_add(sh_mem, _EPS_BITS))
                             >= _order_key(qm)[:, None]))
                     b = masked_argmin(_order_key(sh_mem), f)
                     put = act & f.any(axis=1)
-                    sc, sm = sh_cpu[li, b], sh_mem[li, b]
-                    sh_cpu = sh_cpu.at[li, b].set(
-                        jnp.where(put, sc - qc, sc))
-                    sh_mem = sh_mem.at[li, b].set(
-                        jnp.where(put, f64_add(sm, qm ^ _SIGN), sm))
+                    sc, sm = _row_pick(sh_cpu, b), _row_pick(sh_mem, b)
+                    sh_cpu = _row_put(sh_cpu, b, sc - qc, put)
+                    sh_mem = _row_put(sh_mem, b, f64_add(sm, qm ^ _SIGN),
+                                      put)
                     ok = ok & ~(act & ~put)
-                    rem = rem.at[li, q].set(rem[li, q] & ~act)
+                    rem = _row_put(rem, q, False, act)
                     return rem, sh_cpu, sh_mem, ok, steps + 1
 
                 s = (movers, ac - c["used_cpu"],
@@ -742,35 +769,32 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                     act = out.any(axis=1)
                     q = jnp.argmin(jnp.where(out, bind_seq, _SEQ_INF),
                                    axis=1)
-                    old_c = c["used_cpu"][li, node]
-                    old_m = c["used_mem"][li, node]
-                    c["used_cpu"] = c["used_cpu"].at[li, node].set(
-                        jnp.where(act, old_c - cpu[li, q], old_c))
-                    c["used_mem"] = c["used_mem"].at[li, node].set(
-                        jnp.where(act, f64_add(old_m, mem[li, q] ^ _SIGN),
-                                  old_m))
+                    old_c = _row_pick(c["used_cpu"], node)
+                    old_m = _row_pick(c["used_mem"], node)
+                    c["used_cpu"] = _row_put(c["used_cpu"], node,
+                                             old_c - _row_pick(cpu, q), act)
+                    c["used_mem"] = _row_put(
+                        c["used_mem"], node,
+                        f64_add(old_m, _row_pick(mem, q) ^ _SIGN), act)
                     dec = act.astype(jnp.int32)
-                    c["pcount"] = c["pcount"].at[li, node].add(-dec)
-                    c["nmove"] = c["nmove"].at[li, node].add(-dec)
+                    c["pcount"] = _row_add(c["pcount"], node, -1, act)
+                    c["nmove"] = _row_add(c["nmove"], node, -1, act)
                     slot = c["ev_n"]
                     rec = act & (slot < X)
                     sl = jnp.minimum(slot, X - 1)
                     for key, val in (("ev_pod", q), ("ev_node", node),
-                                     ("ev_bind_cycle", bind_cycle[li, q]),
-                                     ("ev_bind_seq", bind_seq[li, q]),
-                                     ("ev_pend", c["pend"][li, q]),
-                                     ("ev_cycle", jnp.full(L, k))):
-                        c[key] = c[key].at[li, sl].set(
-                            jnp.where(rec, val.astype(c[key].dtype),
-                                      c[key][li, sl]))
+                                     ("ev_bind_cycle",
+                                      _row_pick(bind_cycle, q)),
+                                     ("ev_bind_seq", _row_pick(bind_seq, q)),
+                                     ("ev_pend", _row_pick(c["pend"], q)),
+                                     ("ev_cycle", k)):
+                        c[key] = _row_put(c[key], sl, val, rec)
                     c["overflow"] = c["overflow"] | (act & (slot >= X))
                     c["ev_n"] = slot + dec
-                    c["bound"] = c["bound"].at[li, q].set(
-                        c["bound"][li, q] & ~act)
-                    c["pend"] = c["pend"].at[li, q].set(
-                        jnp.where(act, t, c["pend"][li, q]))
+                    c["bound"] = _row_put(c["bound"], q, False, act)
+                    c["pend"] = _row_put(c["pend"], q, t, act)
                     c["scale_in_steps"] = c["scale_in_steps"] + 1
-                    return out.at[li, q].set(False), c
+                    return _row_put(out, q, False, act), c
 
                 return lax.while_loop(cond, body, (out, c))[1]
 
@@ -783,22 +807,21 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 has = cands.any(axis=1)
                 node = jnp.argmin(jnp.where(cands, node_seq, n_pad),
                                   axis=1).astype(jnp.int32)
-                cands = cands.at[li, node].set(False)
-                only = has & (c["nmove"][li, node] == c["pcount"][li, node])
+                cands = _row_put(cands, node, False, has)
+                only = has & (_row_pick(c["nmove"], node)
+                              == _row_pick(c["pcount"], node))
                 mixed = has & ~only
                 movers = ((only | mixed)[:, None] & c["bound"] & valid
                           & moveable & (bind_node == node[:, None]))
                 ok = placeable(c, node, movers, only | mixed)
                 c = evict(c, node, movers & ok[:, None])
-                st = c["nstate"][li, node]
-                c["nstate"] = c["nstate"].at[li, node].set(
-                    jnp.where(ok & only, NODE_GONE,
-                              jnp.where(ok & mixed, NODE_TAINTED, st)))
+                # ``ok`` only stays True in lanes with a candidate.
+                c["nstate"] = _row_put(
+                    c["nstate"], node,
+                    jnp.where(only, NODE_GONE, NODE_TAINTED), ok)
                 gone = ok & only
-                c["gone_k"] = c["gone_k"].at[li, node].set(
-                    jnp.where(gone, k, c["gone_k"][li, node]))
-                c["gone_step"] = c["gone_step"].at[li, node].set(
-                    jnp.where(gone, 2, c["gone_step"][li, node]))
+                c["gone_k"] = _row_put(c["gone_k"], node, k, gone)
+                c["gone_step"] = _row_put(c["gone_step"], node, 2, gone)
                 c["scale_ins"] = c["scale_ins"] + ok.astype(jnp.int32)
                 c["scale_in_nodes"] = c["scale_in_nodes"] + gone.sum(
                     dtype=jnp.int64)
@@ -824,8 +847,11 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                          & (S["launch_k"] + boot[:, None] <= k))
                 S["nstate"] = jnp.where(ready, NODE_READY, nstate)
                 assoc = S["assoc"]
-                freed = (assoc >= 0) & jnp.take_along_axis(
-                    ready, jnp.maximum(assoc, 0), axis=1)
+                # Each pod's node, by a one-hot over the node axis (no
+                # association, -1, matches none).
+                node_ax = jnp.arange(n_pad)[None, None, :]
+                freed = ((assoc[:, :, None] == node_ax)
+                         & ready[:, None, :]).any(axis=2)
                 S["assoc"] = jnp.where(freed, -1, assoc)
                 live = ((S["nstate"] != NODE_NONE)
                         & (S["nstate"] != NODE_GONE))

@@ -291,6 +291,53 @@ class TestSelectKernels:
         assert got[3] == 4                                     # first tie
         assert 0 <= got[5] < 13
 
+    @pytest.mark.parametrize("dtype", ["int64", "int32", "bool"])
+    @pytest.mark.parametrize("n_lanes", [1, 4096])
+    def test_row_helpers_equal_indexed_forms(self, dtype, n_lanes):
+        """The step loops' one-hot row reads and writes equal the
+        ``.at[]`` and fancy-index forms bit for bit: float64 bit patterns
+        (negative, -0.0, +inf, the key fill) and counters, masked-off
+        lanes, the first and last column, one lane and 4,096."""
+        import jax.numpy as jnp
+        rng = np.random.default_rng(11)
+        L, N = n_lanes, 37
+        special = np.concatenate([
+            ml.f64_bits([np.inf, -0.0, -2415.616]),
+            np.array([ml._KEY_MAX, ml._SIGN, -1, 0], np.int64)])
+        if dtype == "bool":
+            x, v = rng.random((L, N)) < 0.5, rng.random(L) < 0.5
+        else:
+            info = np.iinfo(dtype)
+            x = rng.integers(info.min, info.max, (L, N), dtype=dtype,
+                             endpoint=True)
+            v = rng.integers(info.min, info.max, L, dtype=dtype,
+                             endpoint=True)
+            if dtype == "int64":
+                x.flat[rng.integers(0, x.size, x.size // 3)] = rng.choice(
+                    special, x.size // 3)
+                v[::2] = rng.choice(special, v[::2].size)
+        li = np.arange(L)
+        m0 = rng.random(L) < 0.5
+        with jax.enable_x64(True):
+            put, add, pick = (jax.jit(ml._row_put), jax.jit(ml._row_add),
+                              jax.jit(ml._row_pick))
+            for i in (np.zeros(L, np.int32), np.full(L, N - 1, np.int32),
+                      rng.integers(0, N, L).astype(np.int32)):
+                for m in (m0, ~m0):
+                    xj = jnp.asarray(x)
+                    ref = xj.at[li, i].set(jnp.where(m, v, xj[li, i]))
+                    assert np.array_equal(np.asarray(put(x, i, v, m)),
+                                          np.asarray(ref))
+                    ref = xj.at[li, i].set(jnp.where(m, v[0], xj[li, i]))
+                    assert np.array_equal(np.asarray(put(x, i, v[0], m)),
+                                          np.asarray(ref))
+                    for d in ((-1, 2) if dtype != "bool" else ()):
+                        ref = xj.at[li, i].add(jnp.where(m, d, 0))
+                        assert np.array_equal(np.asarray(add(x, i, d, m)),
+                                              np.asarray(ref))
+                assert np.array_equal(np.asarray(pick(x, i)), x[li, i])
+                assert np.array_equal(np.asarray(pick(x[:1], i)), x[0, i])
+
 
 def _f64_cases(name, rng, n=4096):
     """Operand pairs for one family of float64 additions."""
