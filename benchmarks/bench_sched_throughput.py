@@ -22,16 +22,13 @@ The array engine additionally runs each capped scale to completion for an
 **end-to-end full-run** figure (arrival batching + bucketed completions +
 incremental Table-5 sampling all live outside the capped cycle window, so
 the full run is where they show up); the large scale records the speedup
-against PR 2's committed wall time.  ``--kernels`` re-measures the
-argmin-vs-segment-tree wave-selection crossover that calibrates
-``engine.SEGTREE_AUTO_MIN_NODES``.
+against a committed reference wall time.
 
 Usage::
 
     python benchmarks/bench_sched_throughput.py                  # all scales
     python benchmarks/bench_sched_throughput.py --scale small    # CI smoke
     python benchmarks/bench_sched_throughput.py --engines array  # skip seed
-    python benchmarks/bench_sched_throughput.py --kernels        # + crossover
     python benchmarks/bench_sched_throughput.py --trace-replay   # + 100k trace
 
 ``--trace-replay`` (implied by ``--scale all``) replays a 100k-arrival
@@ -249,45 +246,6 @@ def bench_trace_replay(n_jobs=TRACE_REPLAY_JOBS,
     return out
 
 
-def bench_wave_kernels(ns=(2048, 8192, 32768, 65536), reps=2000) -> dict:
-    """Per-placement cost (extremum query + one point update) of the two
-    wave-selection kernels, across node counts — re-measures the crossover
-    behind ``engine.SEGTREE_AUTO_MIN_NODES`` (the kernels are
-    decision-identical, so this is purely a constant-factor question)."""
-    from repro.core.engine import SEGTREE_AUTO_MIN_NODES, SegExtTree
-
-    rng = np.random.default_rng(0)
-    out = {"auto_threshold_nodes": SEGTREE_AUTO_MIN_NODES, "per_n": {}}
-    for n in ns:
-        # Each kernel gets its own copy of the same start buffer and applies
-        # the identical (query, write-random-value) sequence, so both do the
-        # same real work — a constant write value would converge to a fixed
-        # minimum and turn the tree updates into early-exit no-ops.
-        base = rng.random(n)
-        vals = rng.random(reps)
-        flat = base.copy()
-        t0 = time.perf_counter()
-        for i in range(reps):
-            flat[int(flat.argmin())] = vals[i]
-        flat_us = 1e6 * (time.perf_counter() - t0) / reps
-        tree = SegExtTree(base.copy(), True)
-        t0 = time.perf_counter()
-        for i in range(reps):
-            tree.update(tree.argext(), vals[i])
-        tree_us = 1e6 * (time.perf_counter() - t0) / reps
-        t0 = time.perf_counter()
-        for _ in range(10):
-            SegExtTree(base, True)
-        build_us = 1e6 * (time.perf_counter() - t0) / 10
-        out["per_n"][str(n)] = {
-            "argmin_us": round(flat_us, 2),
-            "segtree_us": round(tree_us, 2),
-            "segtree_build_us": round(build_us, 1),
-        }
-        print(f"bench_sched.kernels.n{n},{flat_us:.2f},{tree_us:.2f}")
-    return out
-
-
 def bench_sweep_pool(workers: int = 4, n_jobs: int = 300) -> dict:
     """Process-pool speedup of the `repro.search` cell runner on a fixed
     sweep grid (six scenario families × two autoscalers, full rescheduler
@@ -327,8 +285,6 @@ def main(argv=None) -> dict:
                     choices=["all", "none"] + list(SCALES))
     ap.add_argument("--engines", default="array,object",
                     help="comma-separated subset of {array,object}")
-    ap.add_argument("--kernels", action="store_true",
-                    help="also run the wave-selection kernel crossover bench")
     ap.add_argument("--trace-replay", action="store_true",
                     help="also run the 100k-arrival columnar trace-replay "
                          "bench (always included with --scale all)")
@@ -360,8 +316,6 @@ def main(argv=None) -> dict:
         report["trace_replay"] = bench_trace_replay()
     if args.sweep_pool or args.scale == "all":
         report["sweep_pool"] = bench_sweep_pool(workers=args.pool_workers)
-    if args.kernels:
-        report["wave_select_kernels"] = bench_wave_kernels()
     # Preserve entries other benches merged into the same file (e.g. the
     # `manyworld` lane-evaluator entry from bench_manyworld.py) and, on a
     # partial --scale run, the scales this invocation didn't re-measure.

@@ -290,15 +290,13 @@ class Cluster:
     without rescanning every pod each cycle.
     """
 
-    def __init__(self, use_arrays: Optional[bool] = None,
-                 wave_select: Optional[str] = None):
+    def __init__(self, use_arrays: Optional[bool] = None):
         self.nodes: Dict[str, Node] = {}
         self.terminated: List[Node] = []    # kept for cost accounting
         if use_arrays is None:
             use_arrays = _engine.arrays_enabled_default()
         self.arrays: Optional[_engine.ClusterArrays] = (
-            _engine.ClusterArrays(wave_select=wave_select)
-            if use_arrays else None)
+            _engine.ClusterArrays() if use_arrays else None)
         # SoA pod columns (set by the orchestrator on the array engine); the
         # bind/unbind/complete commit points keep it in lockstep with any
         # materialized Pod shells.

@@ -13,8 +13,8 @@ of binding pods one at a time through the object layer, the orchestrator
 hands the scheduler a whole pending snapshot.  The scheduler places the wave
 against the placer's *working copies* of the usage columns — accumulating
 bind effects as array deltas — and the accumulated prefix is committed to
-the ``Cluster``/``Node``/``Pod`` objects once per wave
-(:meth:`repro.core.cluster.Cluster.bind_wave`) instead of once per pod.
+the cluster once per wave (:meth:`repro.core.cluster.Cluster.bind_wave_store`)
+instead of once per pod.
 
 Parity contract (enforced by ``tests/test_engine_parity.py``): every value in
 the mirror is *assigned* from the corresponding node's incremental
@@ -34,17 +34,12 @@ So pod *k* of a wave observes bit-identical frees to what it would have seen
 had pods ``1..k-1`` been committed individually — same pods land on the same
 nodes, with the same lowest-node_id tie-breaks.
 
-The mirror also anchors three further array-native subsystems:
+The mirror also anchors two further array-native subsystems:
 
 * **Table-5 sampling aggregates** — per-node utilization contribution
   columns with dirty tracking, so the 20 s metrics sampler costs O(dirty
   nodes) incremental maintenance plus one C-speed exact ``fsum`` instead of
   a per-node Python scan (see :meth:`ClusterArrays.sample_totals`);
-* **segment-tree selection** (:class:`SegExtTree`) — an O(log n)
-  first-extremum index over the wave path's cached score buffers, selectable
-  against the flat argmin kernel via ``REPRO_WAVE_SELECT`` /
-  ``ExperimentSpec(wave_select=...)`` (identical decisions, different
-  constants; "auto" switches on cluster size);
 * **pod state** (:class:`PodStore`) — uid-indexed SoA columns that are the
   source of truth for pod lifecycle on the array engine; ``Pod`` objects are
   lazily-materialized shells handed out only at API boundaries (callbacks,
@@ -69,7 +64,7 @@ import bisect
 import itertools
 import math
 import os
-from typing import List, Optional
+from typing import List
 
 import numpy as np
 
@@ -94,36 +89,10 @@ POD_F_SERVICE = 2
 POD_F_MOVEABLE = 4
 POD_F_CHECKPOINTABLE = 8
 
-# Below this many active nodes the flat C-speed argmin over the cached score
-# buffer beats the Python-level O(log n) tree descent; "auto" wave selection
-# switches to the segment tree only above it.  Measured on the CPU container
-# (query + one real point update per placement): argmin 0.5us/2k nodes ->
-# ~8us/64k nodes vs segtree ~4-6us roughly flat — crossover ~32k
-# (``benchmarks/bench_sched_throughput.py --kernels`` re-measures).
-SEGTREE_AUTO_MIN_NODES = 32768
-
-WAVE_SELECT_MODES = ("auto", "argmin", "segtree")
-
 
 def arrays_enabled_default() -> bool:
     """Engine selection: REPRO_SCHED_ENGINE=object forces the seed path."""
     return os.environ.get("REPRO_SCHED_ENGINE", "array").lower() != "object"
-
-
-def wave_select_default() -> str:
-    """Wave selection kernel: REPRO_WAVE_SELECT=argmin|segtree|auto (default
-    auto — segment tree above SEGTREE_AUTO_MIN_NODES active nodes)."""
-    return os.environ.get("REPRO_WAVE_SELECT", "auto").lower()
-
-
-def wave_runlen_enabled() -> bool:
-    """Run-length best-fit fast path: REPRO_WAVE_RUNLEN=0 disables it.
-
-    Decision-identical to querying the extremum per pod (see
-    ``Scheduler.select_wave_store``); the switch exists so parity tests can
-    compare the two paths and so a regression can be bisected in the field.
-    """
-    return os.environ.get("REPRO_WAVE_RUNLEN", "1") != "0"
 
 
 class ClusterArrays:
@@ -149,18 +118,12 @@ class ClusterArrays:
     object scan.
     """
 
-    def __init__(self, capacity: int = 64, wave_select: Optional[str] = None):
+    def __init__(self, capacity: int = 64):
         self.n_slots = 0                       # slots ever allocated (monotone)
         # Monotone mutation counter: bumped on every membership / state /
         # usage change.  WavePlacer uses it to detect that its working
         # arrays went stale (e.g. a rescheduler evicted pods mid-cycle).
         self.version = 0
-        if wave_select is None:
-            wave_select = wave_select_default()
-        if wave_select not in WAVE_SELECT_MODES:
-            raise ValueError(f"wave_select must be one of {WAVE_SELECT_MODES},"
-                             f" got {wave_select!r}")
-        self.wave_select = wave_select
         self._cap = capacity
         self.alloc_cpu = np.zeros(capacity, np.int64)
         self.alloc_mem = np.zeros(capacity, np.float64)
@@ -392,106 +355,21 @@ class ClusterArrays:
         assert not self._dirty_slots and not any(self._dirty)
 
 
-class SegExtTree:
-    """First-extremum segment tree over one cached wave score buffer.
-
-    Replaces the flat O(nodes) ``argmin``/``argmax`` of the cached-buffer
-    wave path with an O(log nodes) descent: :meth:`argext` returns the
-    *lowest rank attaining the extremum* (ties always prefer the left
-    child), which in node-id rank order is exactly the lowest-node_id
-    tie-break the flat reduction implements — so selections are
-    bit-identical to the argmin path (``tests/test_engine_parity.py``
-    asserts identical bind sequences under both kernels).
-
-    Point updates (:meth:`update`) recompute the leaf's ancestors in
-    O(log n), stopping early once an ancestor's value is unchanged.
-    Construction is one vectorized pairwise reduction per level; levels are
-    stored as plain Python lists because queries/updates run at scalar
-    granularity, where list access beats NumPy indexing.
-
-    Crossover: NumPy's flat argmin has far smaller constants, so the tree
-    only wins above roughly ``SEGTREE_AUTO_MIN_NODES`` active nodes —
-    ``wave_select="auto"`` picks per that threshold; ``"argmin"`` /
-    ``"segtree"`` force a kernel.
-    """
-
-    __slots__ = ("levels", "mode_min", "fill", "n")
-
-    def __init__(self, buf: np.ndarray, mode_min: bool):
-        self.mode_min = mode_min
-        self.fill = np.inf if mode_min else -np.inf
-        self.n = int(buf.shape[0])
-        red = np.minimum if mode_min else np.maximum
-        lv = buf.astype(np.float64)            # bool masks become 0.0 / 1.0
-        levels = []
-        while True:
-            if lv.shape[0] & 1 and lv.shape[0] > 1:
-                lv = np.append(lv, self.fill)  # keep sibling pairs complete
-            levels.append(lv.tolist())
-            if lv.shape[0] <= 1:
-                break
-            lv = red(lv[0::2], lv[1::2])
-        self.levels = levels
-
-    def argext(self) -> int:
-        """Lowest rank attaining the extremum, or -1 when the root is the
-        fill value (every rank masked infeasible)."""
-        levels = self.levels
-        top = levels[-1][0]
-        if top == self.fill:
-            return -1
-        i = 0
-        # The extremum value propagates unchanged down the chosen path, and
-        # preferring the left child on equality yields the first index.
-        for k in range(len(levels) - 2, -1, -1):
-            i <<= 1
-            if levels[k][i] != top:
-                i += 1
-        return i
-
-    def update(self, i: int, v: float) -> None:
-        levels = self.levels
-        levels[0][i] = v
-        if self.mode_min:
-            for k in range(1, len(levels)):
-                j = i & ~1
-                child = levels[k - 1]
-                a, b = child[j], child[j + 1]
-                nv = a if a < b else b
-                i >>= 1
-                parent = levels[k]
-                if parent[i] == nv:
-                    return
-                parent[i] = nv
-        else:
-            for k in range(1, len(levels)):
-                j = i & ~1
-                child = levels[k - 1]
-                a, b = child[j], child[j + 1]
-                nv = a if a >= b else b
-                i >>= 1
-                parent = levels[k]
-                if parent[i] == nv:
-                    return
-                parent[i] = nv
-
-
 class WavePlacer:
     """Working state for placing one wave of pods against the SoA mirror.
 
     A placer snapshots the usage columns (working *copies*) and the lifecycle
     masks (READY / TAINTED) of a :class:`ClusterArrays` mirror.
-    ``Scheduler.select_wave`` advances the working copies with :meth:`bind`
-    as it places pods, so later pods of the wave see earlier placements
-    **without any object-layer commit**; the orchestrator commits the
-    accumulated bindings once per wave via ``Cluster.bind_wave``.
+    ``Scheduler.select_wave_store`` advances the working copies with the ops
+    of :meth:`bind` as it places pods, so later pods of the wave see earlier
+    placements **without any commit**; the orchestrator commits the
+    accumulated bindings once per wave via ``Cluster.bind_wave_store``.
 
     Rank order: the working arrays cover the *active* slots permuted into
     **lexicographic node_id order** (``slot_of_rank[r]`` maps back to the
     mirror slot).  In rank space, ``argmin``/``argmax`` over a masked score
     buffer returns the *first* extremum — i.e. the lowest-node_id tie-break —
-    in a single vector pass, replacing the per-pod masked-reduction +
-    explicit tie-break chain of the iterated ``select_slot`` path.
+    in a single vector pass.
 
     Bit-parity with committing per pod:
 
@@ -502,14 +380,14 @@ class WavePlacer:
       ``ClusterArrays.free_views`` uses;
     * permuting into rank order copies float bits verbatim, and extremum /
       equality comparisons are order-independent, so selection decisions are
-      identical to the slot-ordered per-pod path;
+      identical to the per-pod object path;
     * lifecycle masks cannot change inside a wave (reschedulers/autoscalers
       only run between waves), so snapshotting them is exact.
 
     ``cache`` memoizes, per request size, the feasibility mask and the
-    policy's ready-masked score buffer; ``Scheduler.select_wave`` refreshes
-    only the just-bound rank after each placement, making the per-pod filter
-    cost O(1) amortized for repeated request sizes.
+    policy's ready-masked score buffer; ``Scheduler.select_wave_store``
+    refreshes only the just-bound rank after each placement, making the
+    per-pod filter cost O(1) amortized for repeated request sizes.
 
     Staleness: ``version`` captures ``ClusterArrays.version`` at snapshot
     time.  Any mirror mutation that did not flow through this placer (an
@@ -535,12 +413,7 @@ class WavePlacer:
         state = arr.state[rank]
         self.ready = state == STATE_READY
         self.tainted = state == STATE_TAINTED
-        # Selection kernel for this wave: flat argmin/argmax over the cached
-        # buffer, or the O(log n) segment tree (identical decisions).
-        mode = arr.wave_select
-        self.use_tree = (mode == "segtree"
-                         or (mode == "auto" and self.n >= SEGTREE_AUTO_MIN_NODES))
-        # (cpu_m, mem_mb) -> (fits, ready_mask, score_buf, requests, tree,
+        # (cpu_m, mem_mb) -> (fits, ready_mask, score_buf, requests,
         #                     cpu_m, mem_mb); cache_list mirrors the values
         # for the per-bind refresh loop (no dict-view overhead per pod).
         self.cache: dict = {}
@@ -561,8 +434,8 @@ class WavePlacer:
         """Record a placement at rank ``r`` in the working arrays (no object
         commit).  Same ``+=`` / ``alloc - used`` float ops as the object
         path, so the rest of the wave sees bit-identical frees.
-        (``Scheduler.select_wave`` inlines these four ops in its pod loop;
-        this method is the documented reference implementation.)"""
+        (``Scheduler.select_wave_store`` inlines these four ops in its pod
+        loop; this method is the documented reference implementation.)"""
         self.used_cpu[r] += req.cpu_m
         self.used_mem[r] += req.mem_mb
         self.free_cpu[r] = self.alloc_cpu[r] - self.used_cpu[r]

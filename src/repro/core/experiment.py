@@ -86,11 +86,6 @@ class ExperimentSpec:
     # "array" (vectorized SoA engine, default) or "object" (seed object-scan
     # engine); None defers to the REPRO_SCHED_ENGINE env var.
     engine: Optional[str] = None
-    # Wave selection kernel: "argmin" (flat reduction), "segtree" (O(log n)
-    # index), or "auto" (tree above engine.SEGTREE_AUTO_MIN_NODES active
-    # nodes — the kernels are decision-identical, so this is purely a
-    # performance choice); None defers to the REPRO_WAVE_SELECT env var.
-    wave_select: Optional[str] = None
     # Observability (repro.obs): an ObsConfig (or True for defaults)
     # attaches a flight recorder + cycle-phase profiler to the built
     # simulation.  None (default) compiles observability out — the hot
@@ -164,7 +159,7 @@ def build_simulation(spec: ExperimentSpec) -> Simulation:
     provider = SimCloudProvider(template, cost,
                                 straggler_injector=spec.straggler_injector)
     use_arrays = None if spec.engine is None else (spec.engine != "object")
-    cluster = Cluster(use_arrays=use_arrays, wave_select=spec.wave_select)
+    cluster = Cluster(use_arrays=use_arrays)
 
     n_static = (spec.static_workers if spec.static_workers is not None
                 else spec.initial_workers)

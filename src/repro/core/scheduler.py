@@ -8,25 +8,23 @@ Tainted nodes (Alg. 6 step 3) are used **only as a last resort**: the filter
 first considers READY nodes and falls back to TAINTED nodes only when no
 untainted node fits.
 
-Two execution engines share each policy:
+One placement path per engine:
 
-* the **object path** (seed engine) — list comprehensions over ``Node``
-  objects, kept for parity testing and as the fallback when the cluster has
-  no SoA mirror;
-* the **array path** — filter+select as masked NumPy reductions over the
-  cluster's :class:`repro.core.engine.ClusterArrays` mirror.  Identical
-  floats, identical IEEE ops, identical tie-breaks => identical bindings.
+* the **object engine** (seed engine, the parity reference) runs
+  :meth:`Scheduler.schedule` per pod: :meth:`Scheduler.suitable_nodes`
+  filters ``Node`` objects, :meth:`Scheduler.select` picks one, and
+  ``Cluster.bind`` commits it.  ``schedule`` works on either engine: on an
+  array cluster the ``Node`` API keeps the SoA mirror in sync;
+* the **array engine** schedules in **waves**
+  (:meth:`Scheduler.select_wave_store`): the whole pending snapshot — rows
+  of the SoA :class:`repro.core.engine.PodStore` — is placed against a
+  :class:`repro.core.engine.WavePlacer` in one call, and the chosen
+  bindings are committed once per wave (``Cluster.bind_wave_store``, or
+  the object-path ``Cluster.bind_wave`` when an external observer needs
+  ``Pod`` shells) instead of once per pod.
 
-On the array path the orchestrator schedules in **waves**
-(:meth:`Scheduler.select_wave_store`): the whole pending snapshot — rows of
-the SoA :class:`repro.core.engine.PodStore` — is placed against a
-:class:`repro.core.engine.WavePlacer` in one call, and the chosen bindings
-are committed once per wave (``Cluster.bind_wave_store``, or the
-object-path ``Cluster.bind_wave`` when an external observer needs ``Pod``
-shells) instead of once per pod.  :meth:`Scheduler.select_wave` is the
-``Pod``-based twin, kept as the documented reference implementation and for
-direct callers.  Each policy contributes its vectorized selection rule
-through two hooks:
+Each policy contributes its vectorized selection rule to the wave through
+two hooks:
 
 * :attr:`Scheduler.wave_mode` — ``'min'``/``'max'``: which extremum of the
   policy's score vector wins (``None`` = no score, first feasible node in
@@ -34,12 +32,6 @@ through two hooks:
 * :meth:`Scheduler.wave_scores` — the score vector itself, computed over the
   placer's working free columns (falls back to ``None`` for score-free
   policies).
-
-``select_slot`` (the iterated single-pod array kernel) remains as the
-non-wave array path used by :meth:`Scheduler.schedule`; a policy that
-defines ``select_slot`` but keeps the default wave hooks is still wave-
-compatible because the base ``select_wave`` loop and ``select_slot`` read
-the same masks and tie-breaks.
 
 Wave-placement parity contract (property-tested by
 ``tests/test_engine_parity.py``): a wave must produce the **bit-identical
@@ -60,7 +52,7 @@ import numpy as np
 
 from repro.core import engine as _engine
 from repro.core.cluster import Cluster, Node
-from repro.core.pods import Pod, PodPhase
+from repro.core.pods import Pod
 from repro.core.resources import Resources
 
 
@@ -72,10 +64,6 @@ class Scheduler(abc.ABC):
     """Base scheduler: filter feasible nodes, pick one, create the binding."""
 
     name = "scheduler"
-
-    # Concrete policies override with a vectorized (arrays, mask, free_cpu,
-    # free_mem, pod) -> slot implementation; None disables the array path.
-    select_slot = None
 
     # Wave placement: which extremum of `wave_scores` wins ('min' | 'max');
     # None = score-free policy (first feasible node in node_id order).
@@ -103,8 +91,6 @@ class Scheduler(abc.ABC):
 
     def schedule(self, cluster: Cluster, pod: Pod, now: float) -> bool:
         """Paper Alg. 2 skeleton. Returns True iff a binding was created."""
-        if cluster.arrays is not None and self.select_slot is not None:
-            return self._schedule_arrays(cluster, pod, now)
         nodes = self.suitable_nodes(cluster, pod)
         node = self.select(nodes, pod) if nodes else None
         if node is None:
@@ -112,34 +98,13 @@ class Scheduler(abc.ABC):
         cluster.bind(pod, node, now)
         return True
 
-    # -- array engine ---------------------------------------------------------
-    def _schedule_arrays(self, cluster: Cluster, pod: Pod, now: float) -> bool:
-        arr = cluster.arrays
-        if arr.n_slots == 0:
-            return False
-        req = pod.requests
-        free_cpu, free_mem = arr.free_views()
-        # Same feasibility ops as Resources.fits_in, elementwise.
-        fits = (free_cpu >= req.cpu_m) & ((free_mem + 1e-9) >= req.mem_mb)
-        state = arr.live("state")
-        mask = fits & arr.live("active") & (state == _engine.STATE_READY)
-        if not mask.any():
-            mask = fits & arr.live("active") & (state == _engine.STATE_TAINTED)
-            if not mask.any():
-                return False
-        slot = self.select_slot(arr, mask, free_cpu, free_mem, pod)
-        if slot < 0:
-            return False
-        cluster.bind(pod, cluster.node_by_slot(slot), now)
-        return True
-
     # -- wave placement (vectorized multi-pod array engine) --------------------
     def wave_scores(self, placer, req, sl=slice(None)) -> Optional[np.ndarray]:
         """Policy score vector over ``placer``'s working frees, or None.
 
-        ``sl`` restricts the computation to a slice of ranks: ``select_wave``
-        passes a single-rank slice to refresh a cached score buffer after a
-        placement (NumPy ops on a length-1 view are the same IEEE-754 ops as
+        ``sl`` restricts the computation to a slice of ranks:
+        :meth:`wave_score_at` passes a single-rank slice to refresh a cached
+        score buffer after a placement (NumPy ops on a length-1 view are the same IEEE-754 ops as
         the full-vector elementwise computation, so the refreshed entry is
         bit-identical to a recompute).  May return a *view* of a placer
         column (e.g. ``free_mem``).
@@ -157,130 +122,6 @@ class Scheduler(abc.ABC):
         bit-identical to a full recompute."""
         return self.wave_scores(placer, req, slice(r, r + 1))[0]
 
-    def select_wave(self, placer, pods: List[Pod],
-                    start: int = 0) -> Tuple[list, Optional[int]]:
-        """Place ``pods[start:]`` in order against the placer's working state.
-
-        The wave engine of ``Orchestrator.cycle``: pods are considered in
-        snapshot (FIFO) order; each placement is recorded in the placer's
-        working arrays so later pods of the wave observe it, but **no object
-        state is touched** — the caller commits the returned prefix with
-        ``Cluster.bind_wave``.
-
-        Returns ``(bindings, blocked)``: ``bindings`` is the placed prefix as
-        ``(pod, slot)`` pairs, and ``blocked`` is the index (into ``pods``)
-        of the first pod with no feasible node — or ``None`` when the whole
-        remainder was placed.  The orchestrator then runs the paper's
-        rescheduling/scale-out path for the blocked pod and resumes the wave
-        after it.
-
-        Selection per pod is one extremum query over a per-request-size
-        score buffer: the buffer holds the policy score where the node is
-        READY and feasible and ±inf elsewhere, lives in node-id rank order
-        (so the first extremum *is* the lowest-node_id tie-break), is
-        memoized in ``placer.cache``, and is refreshed only at the just-bound
-        rank after each placement — O(1) amortized filter+score work per pod
-        for repeated request sizes.  The extremum itself runs on one of two
-        kernels (``engine.wave_select_default`` / ``ExperimentSpec``):
-
-        * **flat** — one C-speed O(nodes) ``argmin``/``argmax`` per pod;
-        * **segment tree** — an :class:`repro.core.engine.SegExtTree` per
-          cached buffer answers the first-extremum query in O(log nodes)
-          and absorbs the per-bind refresh as an O(log nodes) point update.
-
-        Both kernels return the identical rank (same extremum, same
-        first-index tie-break), so decisions are bit-identical to each other
-        and to iterating ``select_slot`` pod by pod (see the module
-        docstring).
-        """
-        bindings: List[Tuple[Pod, int]] = []
-        cache = placer.cache
-        cache_list = placer.cache_list
-        mode = self.wave_mode
-        mode_min = mode == "min"
-        fill = np.inf if mode_min else -np.inf
-        slot_of_rank = placer.slot_of_rank_list
-        use_tree = placer.use_tree
-        ready = placer.ready
-        free_cpu, free_mem = placer.free_cpu, placer.free_mem
-        used_cpu, used_mem = placer.used_cpu, placer.used_mem
-        alloc_cpu, alloc_mem = placer.alloc_cpu, placer.alloc_mem
-        pending = PodPhase.PENDING
-        score_at = self.wave_score_at
-        for i in range(start, len(pods)):
-            pod = pods[i]
-            if pod.phase is not pending:
-                continue   # a binding rescheduler may have placed it already
-            if placer.n == 0:
-                return bindings, i
-            req = pod.requests
-            key = (req.cpu_m, req.mem_mb)
-            ent = cache.get(key)
-            if ent is None:
-                # Same feasibility ops as Resources.fits_in, elementwise.
-                fits = (free_cpu >= req.cpu_m) & (
-                    (free_mem + 1e-9) >= req.mem_mb)
-                mask = fits & ready
-                if mode is None:
-                    buf = mask          # argmax(bool) == first feasible rank
-                else:
-                    buf = np.where(mask, self.wave_scores(placer, req), fill)
-                if not use_tree:
-                    tree = None
-                elif mode is None:
-                    # Boolean mask as a 'max' tree with -inf infeasible
-                    # entries: first rank attaining 1.0 == first feasible,
-                    # all-(-inf) root == no feasible rank.
-                    tree = _engine.SegExtTree(
-                        np.where(mask, 1.0, -np.inf), False)
-                else:
-                    tree = _engine.SegExtTree(buf, mode_min)
-                ent = (fits, mask, buf, req, tree, key[0], key[1])
-                cache[key] = ent
-                cache_list.append(ent)
-            fits, mask, buf, _, tree, _, _ = ent
-            if tree is None:
-                r = int(buf.argmin() if mode_min else buf.argmax())
-                feasible = mask[r] if mode is None else buf[r] != fill
-            else:
-                r = tree.argext()
-                feasible = r >= 0
-            if not feasible:
-                # No READY node fits.  Last resort: tainted nodes (paper:
-                # "unless strictly necessary") — same fallback as per-pod.
-                r = self._select_wave_tainted(placer, fits, req)
-                if r < 0:
-                    return bindings, i
-            bindings.append((pod, slot_of_rank[r]))
-            # Inlined placer.bind(r, req): same `+=` / `alloc - used` float
-            # ops as the object accounting, so the rest of the wave sees
-            # bit-identical frees.
-            used_cpu[r] += req.cpu_m
-            used_mem[r] += req.mem_mb
-            free_cpu[r] = alloc_cpu[r] - used_cpu[r]
-            free_mem[r] = alloc_mem[r] - used_mem[r]
-            # Only the bound rank's feasibility/score changed: refresh that
-            # one entry in every cached buffer.  Scalar extraction is exact
-            # (int64/float64 round-trip verbatim), and Python int/float
-            # comparisons and the `+ 1e-9` are the identical IEEE doubles
-            # the elementwise vector ops compute.
-            fc = int(free_cpu[r])
-            fm_eps = float(free_mem[r]) + 1e-9
-            ready_r = bool(ready[r])
-            for f2, m2, b2, r2, t2, cpu_m, mem_mb in cache_list:
-                ok = fc >= cpu_m and fm_eps >= mem_mb
-                f2[r] = ok
-                ok = ok and ready_r
-                m2[r] = ok
-                if mode is not None:
-                    v = score_at(placer, r2, r) if ok else fill
-                    b2[r] = v
-                    if t2 is not None:
-                        t2.update(r, v)
-                elif t2 is not None:   # buf is the mask itself (1/-inf tree)
-                    t2.update(r, 1.0 if ok else -np.inf)
-        return bindings, None
-
     def _select_wave_tainted(self, placer, fits, req) -> int:
         """Tainted-node fallback of the wave filter: rank of the policy's
         pick among feasible TAINTED nodes, or -1.  Cold path — only reached
@@ -296,16 +137,30 @@ class Scheduler(abc.ABC):
 
     def select_wave_store(self, placer, store, rows,
                           start: int = 0) -> Tuple[list, Optional[int]]:
-        """Row-native :meth:`select_wave`: place ``rows[start:]`` of a
-        :class:`repro.core.engine.PodStore` against the placer.
+        """Place ``rows[start:]`` of a :class:`repro.core.engine.PodStore`
+        in order against the placer's working state.
 
-        The store-path wave engine of ``Orchestrator.cycle``.  Identical
-        decision procedure to :meth:`select_wave` — same cached ±inf-masked
-        score buffers, same per-bind float ops, same refresh, same
-        tie-breaks — except pod phase and request sizes are read from the
-        SoA columns instead of ``Pod`` attributes, and no object is ever
-        touched.  Returns ``(bindings, blocked)`` where ``bindings`` is the
-        placed prefix as ``(row, slot)`` pairs.
+        The wave engine of ``Orchestrator.cycle``: pods are considered in
+        snapshot (FIFO) order; each placement is recorded in the placer's
+        working arrays so later pods of the wave observe it, but **no object
+        or column state is touched** — the caller commits the returned
+        prefix with ``Cluster.bind_wave_store`` (or ``Cluster.bind_wave``).
+        Pod phase and request sizes are read from the store's columns.
+
+        Returns ``(bindings, blocked)``: ``bindings`` is the placed prefix as
+        ``(row, slot)`` pairs, and ``blocked`` is the index (into ``rows``)
+        of the first pod with no feasible node — or ``None`` when the whole
+        remainder was placed.  The orchestrator then runs the paper's
+        rescheduling/scale-out path for the blocked pod and resumes the wave
+        after it.
+
+        Selection per pod is one flat ``argmin``/``argmax`` over a
+        per-request-size score buffer: the buffer holds the policy score
+        where the node is READY and feasible and ±inf elsewhere, lives in
+        node-id rank order (so the first extremum *is* the lowest-node_id
+        tie-break), is memoized in ``placer.cache``, and is refreshed only at
+        the just-bound rank after each placement — O(1) amortized filter and
+        score work per pod for repeated request sizes.
 
         **Run-length fast path** (``wave_run_length`` policies, best-fit):
         one extremum query is amortized over a run of consecutive same-size
@@ -319,11 +174,10 @@ class Scheduler(abc.ABC):
         falls back to a full query.  Accounting floats still advance one pod
         at a time in bind order (``+=`` / ``alloc − used``), so the working
         frees — and therefore every subsequent decision — are bit-identical
-        to the per-pod query path; cache refreshes for the run's rank are
-        flushed before the next query reads any buffer (refreshes are pure
-        functions of the current working frees, so one flush equals the
-        per-bind refreshes it replaces).  ``REPRO_WAVE_RUNLEN=0`` forces the
-        per-pod query path for A/B parity testing.
+        to querying per pod; cache refreshes for the run's rank are flushed
+        before the next query reads any buffer (refreshes are pure functions
+        of the current working frees, so one flush equals the per-bind
+        refreshes it replaces).
         """
         bindings: List[Tuple[int, int]] = []
         cache = placer.cache
@@ -332,7 +186,6 @@ class Scheduler(abc.ABC):
         mode_min = mode == "min"
         fill = np.inf if mode_min else -np.inf
         slot_of_rank = placer.slot_of_rank_list
-        use_tree = placer.use_tree
         ready = placer.ready
         free_cpu, free_mem = placer.free_cpu, placer.free_mem
         used_cpu, used_mem = placer.used_cpu, placer.used_mem
@@ -342,8 +195,7 @@ class Scheduler(abc.ABC):
         mem_col = store.mem_mb
         pending = _engine.POD_PENDING
         score_at = self.wave_score_at
-        run_len = (self.wave_run_length and mode_min
-                   and _engine.wave_runlen_enabled())
+        run_len = self.wave_run_length and mode_min
 
         def refresh(r):
             # Only rank r's feasibility/score changed: refresh that one
@@ -354,18 +206,13 @@ class Scheduler(abc.ABC):
             fc = int(free_cpu[r])
             fm_eps = float(free_mem[r]) + 1e-9
             ready_r = bool(ready[r])
-            for f2, m2, b2, r2, t2, c2, m_mb2 in cache_list:
+            for f2, m2, b2, r2, c2, m_mb2 in cache_list:
                 ok = fc >= c2 and fm_eps >= m_mb2
                 f2[r] = ok
                 ok = ok and ready_r
                 m2[r] = ok
                 if mode is not None:
-                    v = score_at(placer, r2, r) if ok else fill
-                    b2[r] = v
-                    if t2 is not None:
-                        t2.update(r, v)
-                elif t2 is not None:   # buf is the mask itself (1/-inf tree)
-                    t2.update(r, 1.0 if ok else -np.inf)
+                    b2[r] = score_at(placer, r2, r) if ok else fill
 
         blocked_keys = placer.blocked_keys
         i = start
@@ -392,23 +239,12 @@ class Scheduler(abc.ABC):
                     buf = mask          # argmax(bool) == first feasible rank
                 else:
                     buf = np.where(mask, self.wave_scores(placer, req), fill)
-                if not use_tree:
-                    tree = None
-                elif mode is None:
-                    tree = _engine.SegExtTree(
-                        np.where(mask, 1.0, -np.inf), False)
-                else:
-                    tree = _engine.SegExtTree(buf, mode_min)
-                ent = (fits, mask, buf, req, tree, cpu_m, mem_mb)
+                ent = (fits, mask, buf, req, cpu_m, mem_mb)
                 cache[key] = ent
                 cache_list.append(ent)
-            fits, mask, buf, req, tree, _, _ = ent
-            if tree is None:
-                r = int(buf.argmin() if mode_min else buf.argmax())
-                feasible = mask[r] if mode is None else buf[r] != fill
-            else:
-                r = tree.argext()
-                feasible = r >= 0
+            fits, mask, buf, req, _, _ = ent
+            r = int(buf.argmin() if mode_min else buf.argmax())
+            feasible = mask[r] if mode is None else buf[r] != fill
             if not feasible:
                 # No READY node fits.  Last resort: tainted nodes (paper:
                 # "unless strictly necessary") — same fallback as per-pod.
@@ -417,8 +253,9 @@ class Scheduler(abc.ABC):
                     blocked_keys.add(key)
                     return bindings, i
             bindings.append((row, slot_of_rank[r]))
-            # Same `+=` / `alloc - used` float ops as the object accounting,
-            # so the rest of the wave sees bit-identical frees.
+            # Same `+=` / `alloc - used` float ops as the object accounting
+            # (``WavePlacer.bind`` is the reference), so the rest of the
+            # wave sees bit-identical frees.
             used_cpu[r] += cpu_m
             used_mem[r] += mem_mb
             free_cpu[r] = alloc_cpu[r] - used_cpu[r]
@@ -427,18 +264,13 @@ class Scheduler(abc.ABC):
             fc = int(free_cpu[r])
             fm_eps = float(free_mem[r]) + 1e-9
             rdy = bool(ready[r])
-            for f2, m2, b2, r2_, t2, c2, m_mb2 in cache_list:
+            for f2, m2, b2, r2_, c2, m_mb2 in cache_list:
                 ok = fc >= c2 and fm_eps >= m_mb2
                 f2[r] = ok
                 ok = ok and rdy
                 m2[r] = ok
                 if mode is not None:
-                    v = score_at(placer, r2_, r) if ok else fill
-                    b2[r] = v
-                    if t2 is not None:
-                        t2.update(r, v)
-                elif t2 is not None:
-                    t2.update(r, 1.0 if ok else -np.inf)
+                    b2[r] = score_at(placer, r2_, r) if ok else fill
             i += 1
             # Run-length continuation must pay for itself: the runner-up
             # query is one extra extremum pass, and a run of exactly two
@@ -451,20 +283,11 @@ class Scheduler(abc.ABC):
                     or mem_col[rows[i + 1]] != mem_mb):
                 continue   # (feasible False => tainted fallback bind: no run)
             # -- run-length continuation at rank r -----------------------------
-            if tree is None:
-                old = buf[r]
-                buf[r] = fill
-                r2 = int(buf.argmin())
-                v2 = buf[r2]
-                buf[r] = old
-            else:
-                old = buf[r]
-                tree.update(r, fill)
-                r2 = tree.argext()
-                tree.update(r, old)
-                v2 = buf[r2] if r2 >= 0 else fill
-                if r2 < 0:
-                    r2 = placer.n   # sentinel: no competitor, v2 == fill
+            old = buf[r]
+            buf[r] = fill
+            r2 = int(buf.argmin())
+            v2 = buf[r2]
+            buf[r] = old
             ready_r = bool(ready[r])
             dirty = False
             while i < n:
@@ -515,14 +338,11 @@ class BestFitBinPackingScheduler(Scheduler):
         # Deterministic tie-break on node_id.
         return min(nodes, key=lambda n: (n.free.mem_mb, n.node_id))
 
-    def select_slot(self, arr, mask, free_cpu, free_mem, pod) -> int:
-        best = free_mem[mask].min()
-        return arr.first_by_id(mask & (free_mem == best))
-
     def wave_scores(self, placer, req, sl=slice(None)):
         # A view into the working frees: the cached score buffer is still a
-        # masked *copy* (np.where) that select_wave must refresh per bind —
-        # the view only makes that single-element refresh read for free.
+        # masked *copy* (np.where) that select_wave_store must refresh per
+        # bind — the view only makes that single-element refresh read for
+        # free.
         return placer.free_mem[sl]
 
     def wave_score_at(self, placer, req, r: int):
@@ -567,12 +387,6 @@ class KubernetesDefaultScheduler(Scheduler):
         scored = [(score(n), n) for n in nodes]
         best = max(s for s, _ in scored)
         return _lowest_id([n for s, n in scored if s == best])
-
-    def select_slot(self, arr, mask, free_cpu, free_mem, pod) -> int:
-        scores = _k8s_scores(free_cpu, free_mem, arr.live("alloc_cpu"),
-                             arr.live("alloc_mem"), pod.requests)
-        best = scores[mask].max()
-        return arr.first_by_id(mask & (scores == best))
 
     def wave_scores(self, placer, req, sl=slice(None)):
         return _k8s_scores(placer.free_cpu[sl], placer.free_mem[sl],
@@ -655,12 +469,6 @@ class WeightedScoringScheduler(Scheduler):
         best = max(s for s, _ in scored)
         return _lowest_id([n for s, n in scored if s == best])
 
-    def select_slot(self, arr, mask, free_cpu, free_mem, pod) -> int:
-        scores = self._scores(free_cpu, free_mem, arr.live("alloc_cpu"),
-                              arr.live("alloc_mem"), pod.requests)
-        best = scores[mask].max()
-        return arr.first_by_id(mask & (scores == best))
-
     def wave_scores(self, placer, req, sl=slice(None)):
         return self._scores(placer.free_cpu[sl], placer.free_mem[sl],
                             placer.alloc_cpu[sl], placer.alloc_mem[sl], req)
@@ -680,9 +488,6 @@ class FirstFitScheduler(Scheduler):
     def select(self, nodes: List[Node], pod: Pod) -> Optional[Node]:
         return _lowest_id(nodes) if nodes else None
 
-    def select_slot(self, arr, mask, free_cpu, free_mem, pod) -> int:
-        return arr.first_by_id(mask)
-
 
 class WorstFitScheduler(Scheduler):
     """Ablation baseline: emptiest feasible node (Docker Swarm 'spread')."""
@@ -695,10 +500,6 @@ class WorstFitScheduler(Scheduler):
             return None
         best = max(n.free.mem_mb for n in nodes)
         return _lowest_id([n for n in nodes if n.free.mem_mb == best])
-
-    def select_slot(self, arr, mask, free_cpu, free_mem, pod) -> int:
-        best = free_mem[mask].max()
-        return arr.first_by_id(mask & (free_mem == best))
 
     def wave_scores(self, placer, req, sl=slice(None)):
         return placer.free_mem[sl]

@@ -20,9 +20,9 @@ Five layers:
 * **Metrics parity** — the incremental sampler (dirty-tracked aggregate
   columns + exact fsum rounding) produces every 20 s sample bit-identical
   to the seed per-node ``fmean`` scan, on curated and randomized runs.
-* **Selection-kernel parity** — the O(log n) segment-tree wave index and
-  the flat argmin kernel make identical decisions (same extremum, same
-  lowest-rank tie-break), unit-level and end-to-end.
+* **Run-length parity** — the best-fit run-length continuation of the wave
+  and the per-pod query every other policy takes bind the same pods, with
+  the same node used-floats, as the object engine.
 """
 import dataclasses
 import math
@@ -34,7 +34,6 @@ from repro.core import (Arrival, Cluster, ExperimentSpec, Node, Pod, PodKind,
                         PodSpec, Resources, build_simulation, gi,
                         reset_id_counters, run_all_combos, run_experiment,
                         run_k8s_baseline)
-from repro.core.engine import SegExtTree
 from repro.core.failures import FailureInjector, StragglerInjector
 
 COMBOS = [(r, a) for r in ("void", "binding", "non-binding")
@@ -134,7 +133,7 @@ class TestWaveBindSequenceParity:
     order, including rebinds of evicted incarnations — on randomized
     clusters, workloads and policy combos."""
 
-    @pytest.mark.parametrize("seed", range(8))
+    @pytest.mark.parametrize("seed", range(14))
     def test_bind_sequences_identical(self, seed):
         def run(engine):
             reset_id_counters()
@@ -159,14 +158,15 @@ class TestWaveBindSequenceParity:
                 inner(pod)
 
             sim.cluster.on_bind = spy
-            sim.run()
-            return spec.scheduler, log
+            result = sim.run()
+            return spec.scheduler, log, dataclasses.asdict(result)
 
-        combo_a, wave_log = run("array")
-        combo_o, perpod_log = run("object")
+        combo_a, wave_log, wave_result = run("array")
+        combo_o, perpod_log, perpod_result = run("object")
         assert combo_a == combo_o          # same randomized policy combo
         assert wave_log, "randomized workload produced no bindings"
         assert wave_log == perpod_log
+        assert wave_result == perpod_result
 
 
 def _mk_pod(rng):
@@ -350,88 +350,16 @@ class TestMetricsParity:
 
 
 class TestWaveSelectParity:
-    """Tentpole: the segment-tree selection kernel must make bit-identical
-    decisions to the flat argmin kernel — same extremum value, same
-    lowest-rank tie-break — unit-level and through whole experiments."""
-
-    @pytest.mark.parametrize("mode_min", [True, False])
-    def test_tree_matches_flat_reduction_under_updates(self, mode_min):
-        rng = np.random.default_rng(5)
-        fill = np.inf if mode_min else -np.inf
-        for n in (1, 2, 3, 7, 16, 33, 100):
-            # Small discrete value set => plenty of ties to break.
-            vals = rng.choice([1.0, 2.0, 3.0], size=n)
-            vals[rng.random(n) < 0.3] = fill
-            tree = SegExtTree(vals, mode_min)
-
-            def flat(v):
-                r = int(v.argmin() if mode_min else v.argmax())
-                return -1 if v[r] == fill else r
-
-            assert tree.argext() == flat(vals)
-            for _ in range(60):
-                i = int(rng.integers(0, n))
-                v = float(rng.choice([0.5, 1.0, 2.0, 3.0, fill]))
-                vals[i] = v
-                tree.update(i, v)
-                assert tree.argext() == flat(vals)
-
-    @pytest.mark.parametrize("seed", range(6))
-    def test_bind_sequences_identical_across_kernels(self, seed):
-        def run(wave_select):
-            reset_id_counters()
-            rng = np.random.default_rng(seed)
-            spec = ExperimentSpec(
-                workload="rand",
-                arrivals=_random_arrivals(rng, 80),
-                scheduler=str(rng.choice(["best-fit", "first-fit",
-                                          "worst-fit", "k8s-default"])),
-                rescheduler=str(rng.choice(["void", "binding",
-                                            "non-binding"])),
-                autoscaler=str(rng.choice(["non-binding", "binding"])),
-                initial_workers=int(rng.integers(1, 4)),
-                seed=0, engine="array", wave_select=wave_select)
-            sim = build_simulation(spec)
-            log = []
-            inner = sim.cluster.on_bind
-
-            def spy(pod):
-                log.append((pod.uid, pod.incarnation, pod.node_id,
-                            pod.bound_time))
-                inner(pod)
-
-            sim.cluster.on_bind = spy
-            result = sim.run()
-            return log, dataclasses.asdict(result)
-
-        tree_log, tree_result = run("segtree")
-        flat_log, flat_result = run("argmin")
-        assert tree_log, "randomized workload produced no bindings"
-        assert tree_log == flat_log
-        assert tree_result == flat_result
-
-    def test_fig3_combo_identical_under_segtree(self):
-        reset_id_counters()
-        seg = run_experiment(ExperimentSpec(
-            workload="mixed", rescheduler="non-binding",
-            autoscaler="binding", seed=0, engine="array",
-            wave_select="segtree"))
-        reset_id_counters()
-        obj = run_experiment(ExperimentSpec(
-            workload="mixed", rescheduler="non-binding",
-            autoscaler="binding", seed=0, engine="object"))
-        assert dataclasses.asdict(seg) == dataclasses.asdict(obj)
-
-    def test_unknown_wave_select_rejected(self):
-        with pytest.raises(ValueError):
-            Cluster(use_arrays=True, wave_select="quantum")
+    """``Scheduler.select_wave_store`` advances the placer's working arrays
+    with the same accounting ops as ``WavePlacer.bind``."""
 
     def test_waveplacer_bind_matches_inlined_wave_ops(self):
         """``WavePlacer.bind`` is the documented reference implementation of
-        the four accounting ops ``select_wave`` inlines in its pod loop;
-        replaying a wave's bindings through it must reproduce the placer's
-        working arrays bit-for-bit (guards the two copies against drift)."""
-        from repro.core.engine import WavePlacer
+        the four accounting ops ``select_wave_store`` inlines in its pod
+        loop; replaying a wave's bindings through it must reproduce the
+        placer's working arrays bit-for-bit (guards the two copies against
+        drift)."""
+        from repro.core.engine import PodStore, WavePlacer
         from repro.core.scheduler import BestFitBinPackingScheduler
 
         cluster = Cluster(use_arrays=True)
@@ -441,14 +369,18 @@ class TestWaveSelectParity:
                         node_id=f"wb-{i}")
             node.mark_ready(0.0)
             cluster.add_node(node)
-        pods = [_mk_pod(rng) for _ in range(30)]
         arr = cluster.arrays
+        store = PodStore(arr)
+        rows = [store.adopt(_mk_pod(rng)) for _ in range(30)]
         placer = WavePlacer(arr)
-        bindings, _ = BestFitBinPackingScheduler().select_wave(placer, pods)
+        bindings, _ = BestFitBinPackingScheduler().select_wave_store(
+            placer, store, rows)
         assert bindings, "wave placed nothing"
+        assert len({slot for _, slot in bindings}) > 1
         replay = WavePlacer(arr)   # same snapshot: nothing was committed
-        for pod, slot in bindings:
-            replay.bind(int(arr.id_rank[slot]), pod.requests)
+        for row, slot in bindings:
+            replay.bind(int(arr.id_rank[slot]),
+                        Resources(store.cpu_m[row], store.mem_mb[row]))
         for name in ("used_cpu", "used_mem", "free_cpu", "free_mem"):
             assert getattr(placer, name).tolist() == \
                 getattr(replay, name).tolist(), name
@@ -564,23 +496,22 @@ class TestFailureWaveParity:
 
 class TestRunLengthParity:
     """Satellite: the best-fit run-length fast path (one extremum query
-    amortized over runs of same-size pods) must produce bit-identical bind
-    sequences *and node used-floats* versus both the per-pod query path
-    (``REPRO_WAVE_RUNLEN=0``) and the seed object engine — float
+    amortized over runs of same-size pods) and the per-pod query that
+    every other policy takes must produce bit-identical bind sequences
+    *and node used-floats* versus the seed object engine — float
     accumulation order included, which is why the spy records the bound
     node's ``used`` bit patterns at every bind."""
 
-    def _bind_log(self, arrivals, engine, monkeypatch, runlen,
-                  wave_select=None, initial_workers=2):
+    SCHEDULERS = ["best-fit", "worst-fit", "first-fit", "k8s-default"]
+
+    def _bind_log(self, arrivals, engine, scheduler, initial_workers=2):
         import struct
 
-        monkeypatch.setenv("REPRO_WAVE_RUNLEN", "1" if runlen else "0")
         reset_id_counters()
         spec = ExperimentSpec(
             workload="runlen", arrivals=list(arrivals),
-            scheduler="best-fit", rescheduler="void", autoscaler="binding",
-            initial_workers=initial_workers, seed=0, engine=engine,
-            wave_select=wave_select)
+            scheduler=scheduler, rescheduler="void", autoscaler="binding",
+            initial_workers=initial_workers, seed=0, engine=engine)
         sim = build_simulation(spec)
         log = []
         inner = sim.cluster.on_bind
@@ -596,27 +527,26 @@ class TestRunLengthParity:
         result = sim.run()
         return log, dataclasses.asdict(result)
 
-    def _assert_all_identical(self, arrivals, monkeypatch, **kw):
-        fast_log, fast_res = self._bind_log(arrivals, "array", monkeypatch,
-                                            runlen=True, **kw)
-        slow_log, slow_res = self._bind_log(arrivals, "array", monkeypatch,
-                                            runlen=False, **kw)
-        obj_log, obj_res = self._bind_log(arrivals, "object", monkeypatch,
-                                          runlen=True, **kw)
-        assert fast_log, "workload produced no bindings"
-        assert fast_log == slow_log, "run-length path diverged from per-pod"
-        assert fast_log == obj_log, "run-length path diverged from seed"
-        assert fast_res == slow_res == obj_res
+    def _assert_all_identical(self, arrivals, scheduler, **kw):
+        wave_log, wave_res = self._bind_log(arrivals, "array", scheduler,
+                                            **kw)
+        obj_log, obj_res = self._bind_log(arrivals, "object", scheduler,
+                                          **kw)
+        assert wave_log, "workload produced no bindings"
+        assert wave_log == obj_log, "wave path diverged from seed"
+        assert wave_res == obj_res
 
-    def test_same_size_runs(self, monkeypatch):
+    @pytest.mark.parametrize("scheduler", SCHEDULERS)
+    def test_same_size_runs(self, scheduler):
         """A pure same-size stream: maximal run lengths, nodes fill one by
         one — the scenario the fast path was built for."""
         spec = PodSpec("rl-same", PodKind.BATCH, Resources(200, gi(0.6)),
                        duration_s=600.0)
         arrivals = [Arrival(float(i), spec) for i in range(40)]
-        self._assert_all_identical(arrivals, monkeypatch)
+        self._assert_all_identical(arrivals, scheduler)
 
-    def test_mixed_size_runs(self, monkeypatch):
+    @pytest.mark.parametrize("scheduler", SCHEDULERS)
+    def test_mixed_size_runs(self, scheduler):
         """Random run lengths of mixed sizes (including services) stress the
         run-break conditions: key changes, ties against the runner-up and
         nodes going infeasible mid-run."""
@@ -638,33 +568,14 @@ class TestRunLengthParity:
             for _ in range(int(rng.integers(1, 8))):
                 t += float(rng.exponential(3.0))
                 arrivals.append(Arrival(t, spec))
-        self._assert_all_identical(arrivals, monkeypatch)
+        self._assert_all_identical(arrivals, scheduler)
 
-    def test_runs_interrupted_by_scale_out(self, monkeypatch):
+    @pytest.mark.parametrize("scheduler", SCHEDULERS)
+    def test_runs_interrupted_by_scale_out(self, scheduler):
         """A one-node cluster forces mid-run blocking: the wave flushes, the
         binding autoscaler provisions, and the run resumes later — bind
         sequences must survive the interruption bit-for-bit."""
         spec = PodSpec("rl-burst", PodKind.BATCH, Resources(300, gi(1.2)),
                        duration_s=900.0)
         arrivals = [Arrival(float(i) * 0.5, spec) for i in range(30)]
-        self._assert_all_identical(arrivals, monkeypatch,
-                                   initial_workers=1)
-
-    def test_runs_under_segtree_kernel(self, monkeypatch):
-        """The run-length path drives the segment tree through its
-        mask/restore runner-up queries; decisions must match the flat
-        argmin kernel and the seed engine."""
-        spec = PodSpec("rl-tree", PodKind.BATCH, Resources(200, gi(0.6)),
-                       duration_s=600.0)
-        arrivals = [Arrival(float(i), spec) for i in range(36)]
-        fast_tree, res_tree = self._bind_log(arrivals, "array", monkeypatch,
-                                             runlen=True,
-                                             wave_select="segtree")
-        fast_flat, res_flat = self._bind_log(arrivals, "array", monkeypatch,
-                                             runlen=True,
-                                             wave_select="argmin")
-        obj_log, res_obj = self._bind_log(arrivals, "object", monkeypatch,
-                                          runlen=True)
-        assert fast_tree, "workload produced no bindings"
-        assert fast_tree == fast_flat == obj_log
-        assert res_tree == res_flat == res_obj
+        self._assert_all_identical(arrivals, scheduler, initial_workers=1)
