@@ -31,12 +31,14 @@ lane-pods; lanes with no pod take the same path, without the program):
   time) decide exactly which events each sample sees and which sample is
   the last one recorded before a completed run breaks.  Node usage is a
   sequential float64 accumulation in event order, as the serial engine
-  keeps it, and each distinct sample state is tabled once with the
+  keeps it, and each distinct sample state is kept once with the
   number of samples that record it;
-* every sum the serial run rounds once (the sampler's ``math.fsum``,
-  ``statistics.fmean``) is rounded once here: :func:`_exact_sums`, a
-  vectorised correctly rounded sum that falls back to ``math.fsum``
-  wherever it cannot prove its rounding;
+* every sum the serial run rounds once is rounded once here: a sample's
+  ``math.fsum`` of node ratios from exact integer limbs carried along
+  the event walk (:func:`_limbs`, :func:`_round_limbs`), and
+  ``statistics.fmean`` by :func:`_exact_sums`, a vectorised correctly
+  rounded sum; each falls back to ``math.fsum`` wherever it cannot hold
+  or prove its sum;
 * cost/node-seconds use the serial CostModel formulas: one ``ceil``
   per node record, from launch (t=0 for a static node) to removal or the
   run's end, accumulated left-to-right in retirement order, then the
@@ -113,8 +115,10 @@ def lane_calls(n: int) -> List[Dict]:
     ``lane_steps``, each bucket's lanes times its steps (outer cycles
     plus inner iterations), the most lanes that could have had work,
     ``sample_states``, the utilisation states the rebuild replayed over
-    all lanes, and ``rebuild_fallbacks``, its sums whose rounding the
-    vectorised sum could not prove (summed by ``math.fsum``)."""
+    all lanes, ``rebuild_fallbacks``, its pending and average sums whose
+    rounding the vectorised sum could not prove, and
+    ``node_sum_fallbacks``, its states whose node sums the limbs could not
+    hold (each summed by ``math.fsum``)."""
     if n <= 0:
         return []
     return [dict(rec, self_s=dict(rec["self_s"]), counts=dict(rec["counts"]))
@@ -257,6 +261,84 @@ def _exact_sums(x: np.ndarray) -> Tuple[np.ndarray, int]:
     return res, fallbacks
 
 
+#: The most nodes a state's limb sums may count: each limb is below
+#: 2**53, so the sums of 1,023 stay inside int64 (:func:`_limbs`).
+_LIMB_NODES = 1023
+
+
+def _limbs(r: np.ndarray, out: Optional[np.ndarray] = None):
+    """``r`` as three int64 limbs, ``r == a * 2**-32 + b * 2**-85 + c *
+    2**-138`` exactly, each with the sign of ``r`` and below 2**53 in
+    magnitude, in ``out`` (shape ``(3, r.size)``, made if not given); and
+    ``held``, where they hold ``r``.
+
+    ``x = r * 2**32`` is exact, and so is each step after it: ``a`` is
+    its whole part, the fraction ``x - a`` is a multiple of ``x``'s ulp
+    below one, and scaling it by 2**53 gives ``b`` as its whole part and
+    the next fraction, of which ``c`` is the whole part.  ``c`` takes
+    the rest exactly where ``r`` is a multiple of 2**-138, which every
+    magnitude from 2**-86 (an ulp of 2**-138) is.  ``held`` also asks
+    ``|r| < 2**11``, so that the sums of :data:`_LIMB_NODES` ratios keep
+    ``a`` below 2**53 (:func:`_round_limbs`).  Where ``held`` is false
+    the limbs are some integers."""
+    x = r * 2.0 ** 32
+    held = np.abs(x) < 2.0 ** 43
+    np.copyto(x, 0.0, where=~held)
+    whole = np.trunc(x)
+    if out is None:
+        out = np.empty((3, r.size), np.int64)
+    out[0] = whole
+    np.subtract(x, whole, out=x)
+    np.multiply(x, 2.0 ** 53, out=x)
+    np.trunc(x, out=whole)
+    out[1] = whole
+    np.subtract(x, whole, out=x)
+    np.multiply(x, 2.0 ** 53, out=x)
+    out[2] = x
+    held &= out[2] == x
+    return out, held
+
+
+def _carry(a, b, c):
+    """Limb sums with the carries moved up: ``b`` and ``c`` in
+    [0, 2**53), the sum unchanged."""
+    q = c >> 53
+    b = b + q
+    c = c - (q << 53)
+    q = b >> 53
+    return a + q, b - (q << 53), c
+
+
+def _round_limbs(a: np.ndarray, b: np.ndarray, c: np.ndarray):
+    """The exact ``a * 2**-32 + b * 2**-85 + c * 2**-138`` of int64 limb
+    sums, rounded once to float64, ties to even: ``math.fsum``'s bits for
+    the values the limbs were summed from, where the carried ``a`` stays
+    below 2**53 (sums of at most :data:`_LIMB_NODES` held limbs).
+
+    After the carries (:func:`_carry`) a negative sum is rounded as its
+    negation, so ``a >= 0``.  Without ``c`` the sum is two exact doubles,
+    and one IEEE addition rounds it.  With ``c`` and ``a`` zero it is the
+    same of ``b`` and ``c`` parts.  With both, the sum is at least 2**-32,
+    so its neighbouring doubles lie at least 2**-84 apart and every
+    rounding midpoint is a multiple of 2**-85, as the ``a`` and ``b``
+    parts are: the ``c`` part, below 2**-85, moves the rounding of their
+    sum only where that sum is itself a midpoint rounded down, which it
+    then rounds up."""
+    a, b, c = _carry(a, b, c)
+    neg = np.flatnonzero(a < 0)
+    a[neg], b[neg], c[neg] = _carry(-a[neg], -b[neg], -c[neg])
+    res = a * 2.0 ** -32 + b * 2.0 ** -85
+    low = np.flatnonzero(c)
+    hi, mid = a[low] * 2.0 ** -32, b[low] * 2.0 ** -85
+    s = res[low]
+    e = mid - (s - hi)                        # Fast2Sum: hi >= mid, or 0
+    up = np.nextafter(s, np.inf)
+    res[low] = np.where(a[low] == 0, mid + c[low] * 2.0 ** -138,
+                        np.where((e > 0) & (2.0 * e == up - s), up, s))
+    res[neg] = -res[neg]
+    return res
+
+
 def _split(v: np.ndarray):
     """Veltkamp's split: ``v == hi + lo`` exactly, each half with at most
     26 significant bits, so ``m * hi`` and ``m * lo`` are exact for any
@@ -285,11 +367,11 @@ def _bucket_metrics(entries: list, batch, out: dict):
     / ``done_committed`` and per-lane ``completed`` / ``done_time`` /
     ``done_is_cycle`` / ``scale_outs``; an autoscaled bucket's also hold
     its node records, evictions and ``pend`` (``lanes.run_lane_batch``).
-    Returns one field dict per lane, the sample states replayed and the
-    sums that fell back to ``math.fsum``.  Every formula is the serial
-    one, applied in the serial order, and every float sum that the serial
-    run rounds once (``fsum``, ``fmean``) is rounded once here
-    (:func:`_exact_sums`).
+    Returns one field dict per lane, the sample states replayed, the
+    sums :func:`_exact_sums` summed by ``math.fsum`` and the states whose
+    node sums were.  Every formula is the serial one, applied in the
+    serial order, and every float sum that the serial run rounds once
+    (``fsum``, ``fmean``) is rounded once here.
     """
     SP = SAMPLE_PERIOD_S
     L, P = batch.valid.shape
@@ -355,7 +437,8 @@ def _bucket_metrics(entries: list, batch, out: dict):
     #   corner tc==0, td==20 — else from the next grid point after td;
     # * a node's NODE_READY at tr, pushed at its launch, more than a
     #   sample period before: from the first grid point at or after tr.
-    # Events carry (node, signed memory, signed cpu): node events none.
+    # Events carry (node, signed memory, signed cpu): node events their
+    # node and no usage.
     td = np.where(done, done_t, 0.0)
     early = ((np.fmod(td, SP) == 0.0)
              & ((bind_t < td - SP) | ((bind_t == 0.0) & (td == SP))))
@@ -380,14 +463,15 @@ def _bucket_metrics(entries: list, batch, out: dict):
         gone_t = out["gone_k"].astype(np.float64) * CYCLE_PERIOD_S
         zero_n = np.zeros((L, N), np.int64)
         zero_f = np.zeros((L, N))
+        node_n = np.broadcast_to(np.arange(N), (L, N))
         groups += [
             (ev_bind_t, 1, ev_seq, logged, _cycle_k(ev_bind_t), ev_node, 1,
              ev_mem, ev_cpu),
             (ev_t, 2, ev_seq, logged, _cycle_k(ev_t), ev_node, -1, ev_mem,
              ev_cpu),
-            (ready_t, -1, zero_n, launched, np.ceil(ready_t / SP), zero_n,
+            (ready_t, -1, zero_n, launched, np.ceil(ready_t / SP), node_n,
              0, zero_f, zero_f),
-            (gone_t, 3, zero_n, gone, _cycle_k(gone_t), zero_n, 0, zero_f,
+            (gone_t, 3, zero_n, gone, _cycle_k(gone_t), node_n, 0, zero_f,
              zero_f)]
     widths = [g[3].shape[1] for g in groups]
     E = sum(widths)
@@ -398,27 +482,13 @@ def _bucket_metrics(entries: list, batch, out: dict):
         np.broadcast_to(kinds, (L, E)),
         np.concatenate([np.where(g[3], g[0], np.inf) for g in groups],
                        axis=1)), axis=1)
-    # Each sorted column's group and index in it, to gather every field
-    # from the group's own (lane, event) array.
-    equal = len(set(widths)) == 1
-    if equal:
-        gid, local = np.divmod(order, widths[0])
-    else:
-        starts = np.cumsum([0] + widths[:-1])
-        gid = np.searchsorted(starts, order, side="right") - 1
-        local = order - starts[gid]
 
-    def at_events(field):
-        if equal and all(g[field] is groups[0][field] for g in groups):
-            return np.take_along_axis(groups[0][field], local, axis=1)
-        val = None
-        for i, (g, w) in enumerate(zip(groups, widths)):
-            x = g[field]
-            if np.ndim(x):
-                x = np.take_along_axis(
-                    x, local if equal else np.minimum(local, w - 1), axis=1)
-            val = x if val is None else np.where(gid == i, x, val)
-        return val
+    def at_events(field, cols=E):
+        """One field of each lane's first ``cols`` events, in row order."""
+        return np.take_along_axis(np.concatenate(
+            [np.broadcast_to(g[field], (L, w))
+             for g, w in zip(groups, widths)], axis=1), order[:, :cols],
+            axis=1)
 
     live = at_events(3)
     # A sample applies the events in order up to the first it cannot see
@@ -464,70 +534,94 @@ def _bucket_metrics(entries: list, batch, out: dict):
     ends = (first + per_lane - 1)[has_seg]
     m[ends] = last_k[has_seg] - seg_k[ends] + 1
 
-    # Each state's node usage: ``used[node] += delta`` in event order, a
-    # sequential float64 accumulation, one event column at a time for
-    # every lane at once.  A state is the usage after its last event; the
-    # table holds them in the order the walk closes them, then the zero
-    # states (grid point 0 before any event), whose usage stays zero.
+    # The walk: one event column at a time for every lane at once.  Each
+    # event applies its node's usage, ``used[node] += delta``, a
+    # sequential float64 accumulation in event order (the serial engine's
+    # own), and its node's membership: a node counts in its lane's
+    # samples from the start (a static node) or from its NODE_READY, to
+    # its removal.  Where the node counts, the event adds to its lane's
+    # sums the limbs of the node's ratio after it and takes away those
+    # before it (:func:`_limbs`), so a state's node sums are the lane's
+    # running limb sums at its closing event, exact, and are rounded once
+    # (:func:`_round_limbs`).  A state with a ratio the limbs cannot hold,
+    # or more nodes than their sums can, is summed there by ``math.fsum``
+    # over its lane's counted nodes, and counted.
     closes = applied.copy()
     closes[:, :-1] &= ~(applied[:, 1:] & same_k[:, 1:])
-    sign = at_events(6)
-    walk = [x.T.copy() for x in (
-        np.where(live, at_events(5), 0) * L + lanes[:, None],
-        np.where(live, sign * at_events(7), 0.0),
-        np.where(live, sign * at_events(8), 0.0))]
-    col, lane_at = np.nonzero(closes.T)
-    zero = first[has_seg & ~(applied & (ev_k == 0)).any(axis=1)]
-    table = np.concatenate([seg[lane_at, col], zero])
-    cuts = np.cumsum(np.bincount(col, minlength=E))
-    used_mem = np.zeros((N, L))
-    used_cpu = np.zeros((N, L))
-    state_mem = np.zeros((N, n_seg))
-    state_cpu = np.zeros((N, n_seg))
-    lo = 0
-    n_cols = applied.any(axis=0).sum()
-    for flat, d_mem, d_cpu, hi in zip(*walk, cuts[:n_cols]):
-        used_mem.ravel()[flat] += d_mem
-        used_cpu.ravel()[flat] += d_cpu
-        state_mem[:, lo:hi] = used_mem[:, lane_at[lo:hi]]
-        state_cpu[:, lo:hi] = used_cpu[:, lane_at[lo:hi]]
-        lo = hi
+    n_cols = int(applied.any(axis=0).sum())
+    act = applied[:, :n_cols]
+    sign = at_events(6, n_cols)
+    flat = (np.where(act, at_events(5, n_cols), 0) * L
+            + lanes[:, None]).T.copy()
+    deltas = [np.where(act, sign * at_events(f, n_cols), 0.0).T.copy()
+              for f in (7, 8)]
+    kind = at_events(1, n_cols)
+    marks = np.where(act, (kind == -1) + 2 * (kind == 3), 0).T.astype(
+        np.int8)                   # 1: NODE_READY, 2: removal
+    # Membership bits, (node, lane): 1 once READY (a static node from the
+    # start), 2 once removed; a node counts while it reads 1.
+    seq = seq_of_index if fleet else np.arange(N)
+    member = (seq[:, None] < n_nodes[None, :]).astype(np.int8).ravel()
+    count = member.reshape(N, L).sum(axis=0)
+    used = [np.zeros(N * L) for _ in deltas]
+    allocs = (batch.alloc_mem, acpu)
+    ratio = np.empty((2, 2, L))             # (before, after) x (mem, cpu)
+    limbs = np.empty((2, 3, 2 * L), np.int64)
+    limb_sums = np.zeros((3, 2 * L), np.int64)
+    unheld = np.zeros(2 * L, np.int64)
+    col, lane_at = np.nonzero(closes[:, :n_cols].T)
+    cuts = np.cumsum(np.bincount(col, minlength=n_cols))
+    state = seg[lane_at, col]
+    state_sums = np.zeros((3, 2, state.size), np.int64)
+    nn = count[seg_lane]
+    by_fsum = []
+    c0 = 0
+    for j in range(n_cols):
+        f = flat[j]
+        m_b = member.take(f)
+        m_a = m_b | marks[j]
+        member[f] = m_a
+        for r, (u, d, alloc) in enumerate(zip(used, deltas, allocs)):
+            b = u.take(f)
+            np.divide(b, alloc, out=ratio[0, r])
+            b += d[j]
+            u[f] = b
+            np.divide(b, alloc, out=ratio[1, r])
+        c_b, c_a = m_b == 1, m_a == 1
+        ratio[0] *= c_b
+        ratio[1] *= c_a
+        for phase, add in ((1, np.add), (0, np.subtract)):
+            _, held = _limbs(ratio[phase].ravel(), out=limbs[phase])
+            add(limb_sums, limbs[phase], out=limb_sums)
+            if not held.all():
+                add(unheld, ~held, out=unheld)
+        count += c_a
+        count -= c_b
+        c1 = cuts[j]
+        cl = lane_at[c0:c1]
+        state_sums[:, 0, c0:c1] = limb_sums[:, cl]
+        state_sums[:, 1, c0:c1] = limb_sums[:, L + cl]
+        nn[state[c0:c1]] = count[cl]
+        if unheld.any() or count.max() > _LIMB_NODES:
+            for i in c0 + np.flatnonzero((unheld[cl] + unheld[L + cl] > 0)
+                                         | (count[cl] > _LIMB_NODES)):
+                ln = lane_at[i]
+                on = member[ln::L] == 1
+                by_fsum.append((state[i], *(
+                    math.fsum(u[ln::L][on] / alloc[ln])
+                    for u, alloc in zip(used, allocs))))
+        c0 = c1
+    ram, cpu_r = np.zeros(n_seg), np.zeros(n_seg)
+    ram[state], cpu_r[state] = (_round_limbs(*state_sums[:, r])
+                                for r in (0, 1))
+    for i, mem_sum, cpu_sum in by_fsum:
+        ram[i], cpu_r[i] = mem_sum, cpu_sum
     pods = np.zeros(n_seg, np.int64)
-    pods[seg[closes]] = np.cumsum(applied * sign, axis=1)[closes]
+    pods[seg[closes]] = np.cumsum(act * sign, axis=1)[closes[:, :n_cols]]
+    node_fallbacks = len(by_fsum)
 
-    # Serial sampler: exact fsum of per-node IEEE ratios over the READY
-    # and TAINTED nodes, / their count; each lane's average is fmean over
-    # its samples, m copies of each state: m * v splits into two exact
-    # products (:func:`_split`).
-    if fleet:
-        # A node is sampled from the grid point its NODE_READY reaches
-        # (grid point 0 for a static node) to the one its removal reaches.
-        ev_k_at = np.empty_like(ev_k)
-        np.put_along_axis(ev_k_at, order, ev_k, axis=1)
-        off = 2 * P + 2 * X
-        never = np.iinfo(np.int64).max
-        ready_k = np.where(autoscaled, np.where(
-            launched, ev_k_at[:, off:off + N], never), 0)
-        gone_k = np.where(gone, ev_k_at[:, off + N:off + 2 * N], never)
-        t_lane, t_k = seg_lane[table], seg_k[table]
-        unsampled = ~((ready_k[t_lane] <= t_k[:, None])
-                      & (t_k[:, None] < gone_k[t_lane])).T
-        nn = np.empty(n_seg, np.int64)
-        nn[table] = N - unsampled.sum(axis=0)
-    else:
-        nn = n_nodes[seg_lane]
-
-    def node_sums(state, alloc):
-        ratio = state / alloc[seg_lane[table]]
-        if fleet:
-            np.copyto(ratio, 0.0, where=unsampled)
-        return _exact_sums(ratio)
-
-    ram, cpu_r = np.empty(n_seg), np.empty(n_seg)
-    ram[table], n = node_sums(state_mem, batch.alloc_mem)
-    fallbacks += n
-    cpu_r[table], n = node_sums(state_cpu, acpu)
-    fallbacks += n
+    # Each lane's average is fmean over its samples, m copies of each
+    # state: m * v splits into two exact products (:func:`_split`).
     # (Static nodes are never removed, so every sample sees one.)
     hi, lo = _split(np.stack([ram / nn, cpu_r / nn,
                               pods.astype(np.float64) / nn], axis=1))
@@ -560,8 +654,6 @@ def _bucket_metrics(entries: list, batch, out: dict):
         for k in range(N):
             cost = np.where(real[:, k], cost + term[:, k], cost)
         node_secs = secs_n.sum(axis=1).astype(np.int64)
-        max_nodes = np.zeros(L, np.int64)
-        np.maximum.at(max_nodes, seg_lane, nn)
     else:
         secs = np.ceil(np.maximum(0.0, end))
         term = secs * price
@@ -569,7 +661,8 @@ def _bucket_metrics(entries: list, batch, out: dict):
         for k in range(N):
             cost = np.where(k < n_nodes, cost + term, cost)
         node_secs = (secs * n_nodes).astype(np.int64)
-        max_nodes = np.where(n_samples > 0, n_nodes, 0)
+    max_nodes = np.zeros(L, np.int64)
+    np.maximum.at(max_nodes, seg_lane, nn)
 
     has = n_pend > 0
     zeros = np.zeros(L, np.int64)
@@ -596,7 +689,7 @@ def _bucket_metrics(entries: list, batch, out: dict):
     names = list(cols)
     metrics = [dict(zip(names, vals))
                for vals in zip(*(cols[k].tolist() for k in names))]
-    return metrics, n_seg, fallbacks
+    return metrics, n_seg, fallbacks, node_fallbacks
 
 
 def _no_steps(batch) -> dict:
@@ -626,7 +719,7 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
     span = _lanes.PROFILER.span
     counts = dict.fromkeys(_lanes.COUNTERS + _lanes.FLEET_COUNTERS + (
         "lane_steps", "node_cycles", "sample_states", "rebuild_fallbacks",
-        "lane_fallbacks"), 0)
+        "node_sum_fallbacks", "lane_fallbacks"), 0)
     n_lanes = 0
     with span("lanes.call") as root:
         with span("lanes.prepare"):
@@ -752,7 +845,7 @@ def _rebuild(entries: list, batch, out: dict, share: float, rows: list,
         sub = dataclasses.replace(
             batch, **{name: getattr(batch, name)[lanes] for name in arrays})
         try:
-            metrics, states, fallbacks = _bucket_metrics(
+            metrics, states, fallbacks, node_fallbacks = _bucket_metrics(
                 block, sub, {key: val[lanes] for key, val in out.items()})
         except Exception as exc:
             raise CellError(f"{len(block)} lane cells from "
@@ -765,3 +858,4 @@ def _rebuild(entries: list, batch, out: dict, share: float, rows: list,
             rows[idx] = row
         counts["sample_states"] += states
         counts["rebuild_fallbacks"] += fallbacks
+        counts["node_sum_fallbacks"] += node_fallbacks

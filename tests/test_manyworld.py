@@ -26,8 +26,9 @@ from repro.core.pods import PodKind, PodSpec
 from repro.core.resources import Resources
 from repro.manyworld import evaluator as ev
 from repro.manyworld import lanes as ml
-from repro.manyworld.evaluator import (_exact_sums, _split, lane_calls,
-                                      lane_eligible, run_cells_lanes)
+from repro.manyworld.evaluator import (_exact_sums, _limbs, _round_limbs,
+                                      _split, lane_calls, lane_eligible,
+                                      run_cells_lanes)
 from repro.scenarios import register
 from repro.scenarios.trace import KIND_BATCH, TraceStore
 from repro.search.runner import _RESULT_FIELDS, CellSpec, _get_trace, run_cells
@@ -503,6 +504,30 @@ class TestLaneCounters:
         for scope in ("completions", "wave", "cycle_end"):
             assert f"/{scope}/" in text, scope
 
+    @pytest.mark.parametrize("case", ["corners", "planted", "narrow"])
+    def test_node_sum_fallbacks_count_fsum_states(self, corner_cells,
+                                                  monkeypatch, case):
+        """``node_sum_fallbacks`` counts the states whose node sums went to
+        ``math.fsum``: none on the corner lanes, at least one a lane on
+        the planted ratio below the limbs' reach, and every state that
+        counts more nodes than the limb sums may (their bound shrunk to
+        one node here).  The rows equal the serial ones in each case."""
+        planted = case == "planted"
+        cells = [c for i, c in enumerate(corner_cells)
+                 if (i % len(CORNER_LANES) == FALLBACK_LANE) == planted]
+        if case == "narrow":
+            monkeypatch.setattr(ev, "_LIMB_NODES", 1)
+        rows = run_cells_lanes(cells)
+        counts = lane_calls(1)[0]["counts"]
+        got = counts["node_sum_fallbacks"]
+        if case == "corners":
+            assert got == 0
+        elif planted:
+            assert got >= len(cells) == 2
+        else:
+            assert 0 < got <= counts["sample_states"]
+        _assert_rows_equal(run_cells(cells, workers=1), rows)
+
 
 def _span_cells():
     """Three lane cells in two buckets (two schedulers) and one serial
@@ -634,8 +659,12 @@ CORNER_LANES = [
     # it still precedes SAMPLE(20)
     (3, [(0.0, 300, 512.0, 20.0), (0.0, 200, 256.0, 10.0),
          (3.0, 100, 100.0, 7.0)]),
-    # a node sum just above a rounding midpoint, 1 + 2**-53 + 2**-200: the
-    # vectorised sum cannot prove its rounding and falls back
+    # float residues: 0.1 and 0.2 MB leave their node a memory of 2**-55
+    # MB (a ratio of 2**-66.8), which the samples see until D completes
+    (3, [(0.0, 100, 0.1, 30.0), (0.0, 100, 0.2, 50.0),
+         (1.0, 300, 500.0, 200.0)]),
+    # a node sum just above a rounding midpoint, 1 + 2**-53 + 2**-200: a
+    # ratio below the limbs' reach, so the state falls back to math.fsum
     (3, [(0.0, 600, 3584.0, 40.0), (0.0, 600, TINY, 40.0),
          (0.0, 600, TINY * 2.0 ** -147, 40.0)]),
 ]
@@ -716,12 +745,30 @@ def _replayed_states(o, n):
     return states
 
 
+def _spy_ratios(monkeypatch):
+    """The node ratios the rebuild splits into limbs, recorded."""
+    seen = []
+    limbs = ev._limbs
+
+    def spy(r, out=None):
+        seen.append(r.copy())
+        return limbs(r, out=out)
+    monkeypatch.setattr(ev, "_limbs", spy)
+    return seen
+
+
+def _smallest_ratio(seen):
+    r = np.abs(np.concatenate(seen))
+    return r[r > 0].min()
+
+
 class TestBucketRebuild:
-    def test_corner_rows_equal_serial(self, corner_cells):
+    def test_corner_rows_equal_serial(self, corner_cells, monkeypatch):
         """One call over every corner lane under two schedulers, two fleet
         sizes in one bucket and 3- and 4-pod lanes in one pod pad: rows
         equal the serial ``run_cell`` rows field by field, and the lanes
-        reach the corners they were built for."""
+        reach the corners they were built for, float residues among them."""
+        seen = _spy_ratios(monkeypatch)
         rows = run_cells(corner_cells, workers="lanes")
         rec = lane_calls(1)[0]
         serial = run_cells(corner_cells, workers=1)
@@ -729,7 +776,8 @@ class TestBucketRebuild:
             for field in _RESULT_FIELDS:
                 assert s[field] == l[field], (s["label"], field)
                 assert type(s[field]) is type(l[field]), (s["label"], field)
-        assert (rec["lanes"], rec["buckets"]) == (14, 2)
+        assert (rec["lanes"], rec["buckets"]) == (16, 2)
+        assert 0 < _smallest_ratio(seen) < 2.0 ** -60
         got = [_solo_outputs(c)[0] for c in corner_cells[:len(CORNER_LANES)]]
         assert list(got[0]["bind_cycle"][:3]) == [0, 0, 3]
         assert list(got[0]["done_t"][:3]) == [20.0, 40.0, 40.0]
@@ -738,7 +786,7 @@ class TestBucketRebuild:
         assert ends == [(True, False, 40.0), (True, False, 60.0),
                         (True, True, 100.0), (True, True, 50.0),
                         (False, False, ml.HORIZON_S), (True, False, 20.0),
-                        (True, False, 40.0)]
+                        (True, False, 210.0), (True, False, 40.0)]
         assert rows[4]["avg_ram_ratio"] > 0 and rows[5]["max_nodes"] == 4
 
     def test_sample_states_count_the_replay(self, corner_cells):
@@ -755,15 +803,16 @@ class TestBucketRebuild:
         assert got >= len(cells)    # every lane here records a sample
 
     def test_unproven_sums_fall_back_and_are_counted(self, corner_cells):
-        """Each node sum the vectorised sum cannot round with proof goes
-        to ``math.fsum`` and is counted in ``rebuild_fallbacks``, in a
-        bucket of any width; the rows still equal the serial one."""
+        """Each state whose node sums the limbs cannot hold goes to
+        ``math.fsum`` over its lane's nodes and is counted in
+        ``node_sum_fallbacks``, in a bucket of any width; the rows still
+        equal the serial one."""
         cell = corner_cells[FALLBACK_LANE]
         cells = [dataclasses.replace(cell, seed=cell.seed
                                      + k * len(CORNER_LANES))
                  for k in range(8)]
         rows = run_cells_lanes(cells)
-        assert lane_calls(1)[0]["counts"]["rebuild_fallbacks"] >= len(cells)
+        assert lane_calls(1)[0]["counts"]["node_sum_fallbacks"] >= len(cells)
         want = run_cells(cells[:1], workers=1)[0]
         for row in rows:
             for field in _RESULT_FIELDS:
@@ -785,8 +834,8 @@ class TestBucketRebuild:
         for a, b in zip(whole, blocked):
             assert {k: v for k, v in a.items() if k != "wall_s"} == {
                 k: v for k, v in b.items() if k != "wall_s"}
-        assert (got["sample_states"], got["rebuild_fallbacks"]) == (
-            counts["sample_states"], counts["rebuild_fallbacks"])
+        keys = ("sample_states", "rebuild_fallbacks", "node_sum_fallbacks")
+        assert [got[k] for k in keys] == [counts[k] for k in keys]
 
 
 def _sum_cases(name, rng, n=2048):
@@ -857,6 +906,81 @@ class TestExactSums:
             assert Fraction(h) + Fraction(l) == Fraction(vi) * int(mi)
 
 
+def _node_ratio_cases(name, rng, n=512):
+    """(nodes, states) arrays of node ratios, as a sample sums them: 1 to
+    64 nodes a state (the rest zero)."""
+    nodes = np.arange(64)[:, None] < rng.integers(1, 65, n)
+    if name == "random":            # pod requests summed over allocatable
+        used = rng.integers(0, 35840, (64, n)) * 0.1
+        return np.where(nodes, used / 3584.0, 0.0)
+    if name == "ones":              # full nodes
+        return np.where(nodes, 1.0, 0.0)
+    if name == "zeros":             # an empty fleet
+        return np.zeros((64, n))
+    if name == "residues":          # memory left over 3,584 MB after pods go
+        res = 2.0 ** -rng.integers(40, 61, (64, n)) * rng.random((64, n))
+        used = np.where(rng.random((64, n)) < 0.5,
+                        rng.integers(0, 35840, (64, n)) * 0.1,
+                        res * rng.choice([1.0, -1.0], (64, n)))
+        return np.where(nodes, used / 3584.0, 0.0)
+    # a rounding midpoint, exact or one ulp either side of it, spread over
+    # the nodes: a + half-ulp(a), less d on one node and plus d on another
+    a = rng.random(n) / 2 + 0.5
+    half = np.spacing(a) / 2
+    x = np.zeros((64, n))
+    x[0] = a
+    x[1] = {"ties": half, "below": np.nextafter(half, 0.0),
+            "above": np.nextafter(half, 1.0)}[name]
+    d = rng.integers(1, 2 ** 20, n) * 2.0 ** -40
+    x[0] -= d
+    x[2] = d
+    return rng.permuted(x, axis=0)
+
+
+class TestLimbSums:
+    @pytest.mark.parametrize("family", ["random", "ones", "zeros",
+                                        "residues", "ties", "below",
+                                        "above"])
+    def test_matches_fsum_bits(self, family):
+        """Each state's limb sums, rounded once, are ``math.fsum`` of its
+        node ratios bit for bit; every ratio here is held."""
+        x = _node_ratio_cases(family, np.random.default_rng(21))
+        limbs, held = _limbs(x.ravel())
+        assert held.all()
+        got = _round_limbs(*limbs.reshape(3, *x.shape).sum(axis=1))
+        want = np.array([math.fsum(c) for c in x.T.tolist()])
+        assert np.array_equal(got.view(np.int64), want.view(np.int64))
+
+    def test_limbs_are_the_ratio(self):
+        """Held limbs are the ratio exactly, with its sign, at every
+        magnitude from 2**-86 to below 2**11; a ratio past 2**11, or one
+        with bits below 2**-138, is not held."""
+        from fractions import Fraction
+        rng = np.random.default_rng(22)
+        r = (rng.random(2048) + 1.0) * 2.0 ** rng.integers(-86, 11, 2048)
+        r *= rng.choice([1.0, -1.0], r.size)
+        r = np.concatenate([r, [0.0, 1.0, -1.0, 2.0 ** -86,
+                                np.nextafter(2.0 ** 11, 0.0)]])
+        limbs, held = _limbs(r)
+        assert held.all()
+        for v, (a, b, c) in zip(r, limbs.T.tolist()):
+            assert Fraction(v) == (Fraction(a, 2 ** 32) + Fraction(b, 2 ** 85)
+                                   + Fraction(c, 2 ** 138))
+        out = np.array([(1.0 + 2.0 ** -52) * 2.0 ** -87, 2.0 ** -90 / 3,
+                        2.0 ** -200, 2.0 ** 11, -1e30])
+        assert not _limbs(out)[1].any()
+
+    def test_planted_ratio_below_the_limbs_is_not_held(self):
+        """A state holding a ratio below 2**-86 is flagged, so the rebuild
+        sums it by ``math.fsum`` (and counts it); its other states are
+        held."""
+        x = _node_ratio_cases("residues", np.random.default_rng(23), n=8)
+        x[5, 3] = 2.0 ** -200
+        _, held = _limbs(x.ravel())
+        assert (~held.reshape(x.shape)).any(axis=0).tolist() == [
+            i == 3 for i in range(8)]
+
+
 # -- autoscaled lanes: the binding autoscaler and Alg. 6 ---------------------
 
 #: Hand-built autoscaled lanes, (static nodes, pods as in CORNER_LANES).
@@ -880,6 +1004,15 @@ FLEET_LANES = [
     # completes the lane at 360
     (1, [(0.0, 800, 100.0, 100.0), (1.0, 300, 1000.0, None),
          (2.0, 300, 100.0, 300.0)]),
+    # B and C wait for node-1 and bind when it joins (60); they leave it
+    # at 160 and 180 with a float residue in its memory, which counts in
+    # the samples until Alg. 6 removes the empty node
+    (1, [(0.0, 900, 100.0, 300.0), (1.0, 300, 100.1, 100.0),
+         (2.0, 300, 200.2, 120.0)]),
+    # step 3 with fractional memory: B leaves node-1 holding S's 1000.3
+    # MB plus a residue, then Alg. 6 evicts S and retires node-1
+    (1, [(0.0, 800, 100.0, 100.0), (1.0, 300, 1000.3, None),
+         (2.0, 300, 100.7, 50.0)]),
 ]
 
 
@@ -951,9 +1084,13 @@ class TestAutoscaledLanes:
         serial = run_cells(cells, workers=1)
         with monkeypatch.context() as m:
             _no_serial_runs(m)
+            seen = _spy_ratios(m)
             rows = run_cells(cells, workers="lanes")
         _assert_rows_equal(serial, rows)
-        out_in_out, one_node, drained, tainted = serial
+        assert 0 < _smallest_ratio(seen) < 2.0 ** -50
+        out_in_out, one_node, drained, tainted, residue, retired = serial
+        assert (residue["scale_ins"], residue["max_nodes"]) == (1, 2)
+        assert (retired["evictions"], retired["scale_ins"]) == (1, 2)
         assert (out_in_out["scale_ins"], out_in_out["max_nodes"]) == (2, 2)
         assert out_in_out["node_seconds"] == 300 + 150 + 50
         assert (one_node["max_nodes"], one_node["scale_outs"]) == (1, 0)
