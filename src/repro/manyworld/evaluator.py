@@ -6,12 +6,12 @@ cell list and returns the same row dicts in the same order, but evaluates
 every *lane-eligible* cell inside batched JAX programs
 (`repro.manyworld.lanes`) instead of one serial simulation per cell.
 
-**Eligibility** is the lane engine's relaxed-semantics envelope — no
-rescheduler, on a static fleet or under the binding autoscaler
-(:func:`lane_eligible`).  Anything outside it (the other autoscalers,
-reschedulers, chaos, the object engine) falls back to the serial
-``run_cell`` transparently, so a mixed cell list still returns one
-complete row list.
+**Eligibility** is the lane engine's relaxed-semantics envelope — a
+static fleet, or the binding autoscaler with no rescheduler or with Alg.
+3, the non-binding one (:func:`lane_eligible`).  Anything outside it (the
+other autoscalers, Alg. 4, Alg. 3 on a static fleet, chaos, the object
+engine) falls back to the serial ``run_cell`` transparently, so a mixed
+cell list still returns one complete row list.
 
 **Exactness.**  For eligible cells the rows are bit-identical to
 ``run_cell`` (except ``wall_s``, which is wall time and is reported as
@@ -47,11 +47,14 @@ lane-pods; lanes with no pod take the same path, without the program):
 An autoscaled lane's replay adds its evictions (each closes an
 incarnation: its bind and its pending interval are recorded too) and
 its nodes' joins and removals, which change the set of nodes a sample
-averages over.
+averages over.  Alg. 3 evicts in the middle of a cycle's binds: there
+each eviction carries its place in the cycle's one sequence of binds
+and evictions (``ev_seq``).
 
-Buckets: lanes group by ``(scheduler, pod-pad, node-pad, autoscaled)``
-with power-of-two pads, so the jit cache stays small while mixed
-workloads share compilations.
+Buckets: lanes group by ``(scheduler, pod-pad, node-pad, autoscaled,
+Alg. 3)`` with power-of-two pads, so the jit cache stays small while
+mixed workloads share compilations; a lane's Alg. 3 age gate is its own
+column, so the gates of one bucket may differ.
 
 **Spans and counts.**  Each call is one ``lanes.call`` span of
 ``lanes.PROFILER`` (and of a JAX profiler trace, when one runs), with
@@ -127,15 +130,20 @@ def lane_calls(n: int) -> List[Dict]:
 
 def lane_eligible(cell) -> bool:
     """True when ``cell`` is inside the lane engine's relaxed envelope:
-    no rescheduler, no chaos, the array engine and a lane scheduler
-    (``lanes.SCHEDULERS``: k8s-default and weighted run on the serial
-    reference on every backend), on a static fleet (void autoscaler) or
-    an autoscaled one (the binding autoscaler with Alg. 6 ungated, on a
+    no chaos, the array engine and a lane scheduler (``lanes.SCHEDULERS``:
+    k8s-default and weighted run on the serial reference on every
+    backend), on a static fleet (void autoscaler, no rescheduler) or an
+    autoscaled one (the binding autoscaler with Alg. 6 ungated, on a
     template whose provisioning delay is a whole number of cycles longer
-    than a sample period).  The non-binding and predictive autoscalers
-    stay serial.  A weighted spec stays serial, so an invalid one raises
-    the serial error."""
-    if cell.rescheduler != "void" or cell.chaos:
+    than a sample period), with no rescheduler or with Alg. 3, the
+    non-binding one.  The non-binding and predictive autoscalers, Alg. 4
+    and Alg. 3 on a static fleet stay serial.  A weighted spec stays
+    serial, so an invalid one raises the serial error."""
+    if cell.chaos:
+        return False
+    if cell.rescheduler != "void" and not (
+            cell.rescheduler == "non-binding"
+            and cell.autoscaler == "binding"):
         return False
     if cell.autoscaler == "binding":
         if cell.scale_in_util_ceiling is not None:
@@ -428,7 +436,10 @@ def _bucket_metrics(entries: list, batch, out: dict):
     # (0) before the cycle's binds (1), its evictions (2) and node
     # removals (3) at equal times; equal-time completions fire in
     # scheduling-push order == ascending bind_seq, and a node's evictions
-    # in bind order.  Padding and missing events sort last.  Each event
+    # in bind order.  With Alg. 3 (``ev_seq`` in ``out``) evictions happen
+    # among the binds: they are of kind 1 there, and the cycle's one
+    # sequence of binds and evictions orders them.  Padding and missing
+    # events sort last.  Each event
     # carries the first sample (grid index k, time 20 k) that can see it:
     # * a cycle's effect at tc: :func:`_cycle_k`;
     # * a completion at td is visible from td itself when td is on-grid
@@ -464,11 +475,13 @@ def _bucket_metrics(entries: list, batch, out: dict):
         zero_n = np.zeros((L, N), np.int64)
         zero_f = np.zeros((L, N))
         node_n = np.broadcast_to(np.arange(N), (L, N))
+        ev_kind, ev_order = ((1, out["ev_seq"]) if "ev_seq" in out
+                             else (2, ev_seq))
         groups += [
             (ev_bind_t, 1, ev_seq, logged, _cycle_k(ev_bind_t), ev_node, 1,
              ev_mem, ev_cpu),
-            (ev_t, 2, ev_seq, logged, _cycle_k(ev_t), ev_node, -1, ev_mem,
-             ev_cpu),
+            (ev_t, ev_kind, ev_order, logged, _cycle_k(ev_t), ev_node, -1,
+             ev_mem, ev_cpu),
             (ready_t, -1, zero_n, launched, np.ceil(ready_t / SP), node_n,
              0, zero_f, zero_f),
             (gone_t, 3, zero_n, gone, _cycle_k(gone_t), node_n, 0, zero_f,
@@ -717,24 +730,27 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
     _count_compiles()
     compiles0 = _compiles
     span = _lanes.PROFILER.span
-    counts = dict.fromkeys(_lanes.COUNTERS + _lanes.FLEET_COUNTERS + (
+    program_counts = (_lanes.COUNTERS + _lanes.FLEET_COUNTERS
+                      + _lanes.RESCHED_COUNTERS)
+    counts = dict.fromkeys(program_counts + (
         "lane_steps", "node_cycles", "sample_states", "rebuild_fallbacks",
         "node_sum_fallbacks", "lane_fallbacks"), 0)
     n_lanes = 0
     with span("lanes.call") as root:
         with span("lanes.prepare"):
             buckets, idle = _prepare(cells, rows)
-        for (sched, p_pad, n_pad, fleet), entries in buckets.items():
+        for (sched, p_pad, n_pad, fleet, resched), entries in buckets.items():
             t0 = time.perf_counter()
             with span("lanes.stack"):
                 batch = _lanes.stack_lanes([e[4] for e in entries], sched,
                                            p_pad=p_pad,
-                                           node_pad=n_pad if fleet else None)
+                                           node_pad=n_pad if fleet else None,
+                                           resched=resched)
             out = _lanes.run_lane_batch(batch)
             share = (time.perf_counter() - t0) / len(entries)
             with span("lanes.rebuild"):
-                got = {key: int(out.pop(key)) for key in
-                       _lanes.COUNTERS + _lanes.FLEET_COUNTERS if key in out}
+                got = {key: int(out.pop(key)) for key in program_counts
+                       if key in out}
                 for key, val in got.items():
                     counts[key] += val
                 counts["lane_steps"] += len(entries) * (
@@ -768,8 +784,9 @@ def run_cells_lanes(cells: Sequence) -> List[dict]:
 
 def _prepare(cells: list, rows: list):
     """Fill ``rows`` for the cells that need no lane, and bucket the rest:
-    ``(scheduler, pod-pad, node-pad) -> [(idx, cell, trace, template,
-    lane arrays)]``; lanes with no pod go apart, to the second list."""
+    ``(scheduler, pod-pad, node-pad, autoscaled, Alg. 3) -> [(idx, cell,
+    trace, template, lane arrays)]``; lanes with no pod go apart, to the
+    second list."""
     from repro.search.runner import (CellError, _get_trace, _infeasible,
                                      run_cell)
     buckets, idle = {}, []
@@ -794,11 +811,13 @@ def _prepare(cells: list, rows: list):
                 continue
             if cell.autoscaler == "binding":
                 lane["boot_cycles"] = _boot_cycles(cell)
+                lane["max_pod_age_s"] = float(cell.max_pod_age_s)
                 key = (cell.scheduler, next_pow2(trace.n),
-                       _node_records(cell, trace), True)
+                       _node_records(cell, trace), True,
+                       cell.rescheduler == "non-binding")
             else:
                 key = (cell.scheduler, next_pow2(trace.n),
-                       next_pow2(cell.initial_workers), False)
+                       next_pow2(cell.initial_workers), False, False)
             buckets.setdefault(key, []).append(entry)
         except CellError:
             raise

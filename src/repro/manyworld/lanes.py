@@ -18,22 +18,25 @@ the cycle hot path of the serial engine lowered to fixed shapes:
   ``used += req`` / ``free = alloc - used``.
 
 **Names and counts.**  The jitted program is named after its scheduler
-(``lane_program_best_fit``, ``..._autoscaled`` for an autoscaled fleet:
-the ``XLA Modules`` line of a profiler trace), and its loop bodies run
-under the named scopes ``completions``, ``wave``, ``scale_in`` and
+(``lane_program_best_fit``, ``..._autoscaled`` for an autoscaled fleet,
+``..._autoscaled_resched`` with Alg. 3: the ``XLA Modules`` line of a
+profiler trace), and its loop bodies run under the named scopes
+``completions``, ``wave``, ``reschedule``, ``scale_in`` and
 ``cycle_end``.  Beside its outputs it returns the steps it took
-(:data:`COUNTERS`, and :data:`FLEET_COUNTERS` when autoscaled): the outer
-cycles, the iterations of each inner loop, how many lanes had work in
-them, and the nodes launched, removed and live.  The counts only describe
-the run; nothing reads them back into a decision.  :func:`run_lane_batch`
+(:data:`COUNTERS`, :data:`FLEET_COUNTERS` when autoscaled and
+:data:`RESCHED_COUNTERS` with Alg. 3): the outer cycles, the iterations
+of each inner loop, how many lanes had work in them, the nodes launched,
+removed and live, and Alg. 3's outcomes.  The counts only describe the
+run; nothing reads them back into a decision.  :func:`run_lane_batch`
 times its dispatch, wait and copy to the host as spans of
 :data:`PROFILER`, the lane path's process-wide span recorder.
 
-**Relaxed-semantics envelope.**  Lanes model no rescheduler, no chaos,
-a homogeneous fleet and speed factor 1, on a static fleet (void
-autoscaler: READY nodes billed from t=0) or an autoscaled one.
-Everything else — event ordering, tie-breaks, stuck detection,
-blocked-pod scale-out counting — follows the serial engine exactly;
+**Relaxed-semantics envelope.**  Lanes model no chaos, a homogeneous
+fleet and speed factor 1, on a static fleet (void autoscaler: READY
+nodes billed from t=0) or an autoscaled one; the one rescheduler they
+model is Alg. 3 (non-binding) on an autoscaled fleet.  Everything else
+— event ordering, tie-breaks, stuck detection, blocked-pod scale-out
+counting — follows the serial engine exactly;
 ``repro.manyworld.evaluator`` reconstructs full ``ExperimentResult``
 rows host-side from the lane outputs.  See ARCHITECTURE.md "Many-world
 lanes" for the contract and the enumerated divergences.
@@ -55,6 +58,18 @@ succeeds; evicted pods re-pend at the cycle's instant, so the wave picks
 by (pending since, row).  Each eviction records the incarnation it
 closes, for the host's rebuild.  A lane that runs past its node or
 eviction records stops (``overflow``) and is rerun serially.
+
+**Alg. 3 lanes.**  With the non-binding rescheduler a blocked pod first
+meets Alg. 3 inside the wave: younger than its lane's ``max_pod_age_s``
+it waits (no scale-out); else the READY nodes with room for its CPU that
+hold moveable pods are tried as victims, most free memory first, and a
+victim's moveable pods, largest first, are placed by the shadow
+best-fit that Alg. 6 uses, until they free the memory the pod lacks.
+A plan evicts its movers (they re-pend now and sit out the rest of this
+wave, as the serial engine walks a snapshot) and the wave goes on; only
+a failed plan asks the autoscaler.  Binds and evictions then share one
+sequence counter (``ev_seq``), which orders them within a cycle for the
+rebuild.
 
 **Float discipline.**  The serial engine's float64 values — arrival and
 completion times, memory requests and the per-node ``used_mem`` running
@@ -103,6 +118,13 @@ COUNTERS = ("n_cycles", "wave_steps", "completion_steps", "busy_lane_steps",
 #: and eviction loops.
 FLEET_COUNTERS = ("scale_out_nodes", "scale_in_nodes", "active_node_cycles",
                   "scale_in_steps")
+#: The Alg. 3 program's further counts, over all lanes: calls that waited
+#: out the age gate (``resched_waits``), calls past it that searched for a
+#: plan (``resched_plans``), plans found and carried out
+#: (``resched_done``), pods they evicted (``resched_evictions``), and
+#: ``resched_steps`` iterations of the plan and eviction loops.
+RESCHED_COUNTERS = ("resched_waits", "resched_plans", "resched_done",
+                    "resched_evictions", "resched_steps")
 
 #: Node record states of an autoscaled lane: no node yet, booting
 #: (PROVISIONING, with the binding autoscaler's tracker), READY, TAINTED
@@ -122,6 +144,7 @@ _MAG = np.int64(2**63 - 1)
 _FRAC = np.int64(2**52 - 1)
 _IMPL = np.int64(2**52)
 _KEY_MAX = np.int64(2**63 - 1)          # masked_argmin fill: above every key
+_KEY_MIN = np.int64(-2**63)             # masked max fill: below every key
 
 
 def f64_bits(x) -> np.ndarray:
@@ -158,7 +181,9 @@ class LaneBatch:
     records: its ``n_nodes`` static nodes and every node it launches,
     in node_id order (:func:`node_layout`); ``boot_cycles`` is the
     provisioning delay in cycles and ``moveable`` marks the pods Alg. 6
-    may move.  Build via :func:`stack_lanes`.
+    may move.  ``max_age`` (float64 per lane, autoscaled batches only)
+    makes the batch run Alg. 3 with that age gate.  Build via
+    :func:`stack_lanes`.
     """
 
     scheduler: str
@@ -174,6 +199,7 @@ class LaneBatch:
     moveable: np.ndarray      # (L, P) bool
     boot_cycles: np.ndarray   # (L,)  i32
     node_pad: Optional[int] = None
+    max_age: Optional[np.ndarray] = None    # (L,) f64
 
     @property
     def n_lanes(self) -> int:
@@ -188,6 +214,10 @@ class LaneBatch:
         return self.node_pad is not None
 
     @property
+    def resched(self) -> bool:
+        return self.max_age is not None
+
+    @property
     def n_pad(self) -> int:
         if self.node_pad is not None:
             return self.node_pad
@@ -195,15 +225,20 @@ class LaneBatch:
 
 
 def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None,
-                node_pad: Optional[int] = None) -> LaneBatch:
+                node_pad: Optional[int] = None,
+                resched: bool = False) -> LaneBatch:
     """Stack per-lane dicts (``TraceStore.to_lane_arrays`` output plus
     cluster scalars ``n_nodes`` / ``alloc_cpu`` / ``alloc_mem``) into one
     padded :class:`LaneBatch`.  CPU requests and allocatable must be whole
     milli-cores, as ``Resources.cpu_m`` is.  ``node_pad`` makes the batch
     autoscaled: each lane dict then also holds ``boot_cycles``, and
-    ``node_pad`` bounds the node records of every lane."""
+    ``node_pad`` bounds the node records of every lane.  ``resched`` (an
+    autoscaled batch only) adds Alg. 3, with each lane's gate from its
+    ``max_pod_age_s``."""
     if scheduler not in SCHEDULERS:
         raise ValueError(f"unsupported lane scheduler {scheduler!r}")
+    if resched and node_pad is None:
+        raise ValueError("Alg. 3 lanes need an autoscaled batch")
     n_max = max((int(d["arrival_t"].size) for d in lanes), default=0)
     P = p_pad if p_pad is not None else next_pow2(n_max)
     if n_max > P:
@@ -241,8 +276,10 @@ def stack_lanes(lanes, scheduler: str, p_pad: Optional[int] = None,
     if node_pad is not None and L and int(n_nodes.max()) > node_pad:
         raise ValueError(f"node_pad={node_pad} < largest static fleet "
                          f"({int(n_nodes.max())} nodes)")
+    ages = (np.array([float(d["max_pod_age_s"]) for d in lanes])
+            if resched else None)
     return LaneBatch(scheduler, arr, cpu, mem, dur, isb, val,
-                     n_nodes, a_cpu, a_mem, mov, boot, node_pad)
+                     n_nodes, a_cpu, a_mem, mov, boot, node_pad, ages)
 
 
 def node_layout(n_pad: int):
@@ -404,7 +441,8 @@ def _while(cond, body, state: dict, keys):
     return {**state, **as_dict(out)}
 
 
-def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
+def _program_factory(sched: str, n_pad: int, autoscale: bool = False,
+                     resched: bool = False):
     """Build the jitted many-world program for one (scheduler, padded node
     count, fleet kind); XLA retraces per (L, P) bucket.  ``arr_t`` /
     ``mem`` / ``dur`` / ``alloc_mem`` and the float outputs are float64
@@ -413,7 +451,10 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
 
     ``autoscale`` adds the binding autoscaler and Alg. 6 (module
     docstring, "Autoscaled lanes") and its outputs; without it the program
-    is the static-fleet one, which traces none of that code."""
+    is the static-fleet one, which traces none of that code.  ``resched``
+    (with ``autoscale``) adds Alg. 3 in the wave (module docstring, "Alg. 3
+    lanes"), its per-lane age gate ``max_age`` as a further argument, the
+    eviction sequence ``ev_seq`` and :data:`RESCHED_COUNTERS`."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -427,7 +468,8 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
         ac = alloc_cpu[:, None]
         am = alloc_mem[:, None]
         if autoscale:
-            moveable, boot = fleet
+            moveable, boot = fleet[:2]
+            max_age = fleet[2] if resched else None
             node_seq = jnp.asarray(seq_of_index)[None, :]         # (1, N)
             index_of = jnp.asarray(index_of_seq)
             autoscaled = node_seq >= n_nodes[:, None]             # (L, N)
@@ -544,9 +586,12 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
             counted (one scale-out request per blocked pod, as the serial
             engine counts them) and skipped — decision-identical to the
             serial blocked_keys latch, which only memoizes the same outcome
-            (working frees never grow inside a cycle).  ``wave_steps`` and
-            ``busy`` count the iterations and the lanes that attempted a
-            pod in them."""
+            between changes of the cluster (working frees grow inside a
+            cycle only by an Alg. 3 eviction, after which the serial engine
+            places on fresh arrays).  With Alg. 3 a blocked pod first meets
+            :func:`reschedule`, and only a failed plan asks for a node.
+            ``wave_steps`` and ``busy`` count the iterations and the lanes
+            that attempted a pod in them."""
             arrived = valid & (arr_t <= t)
             active = S["active"]
             keys = ["used_cpu", "used_mem", "bound", "bind_node", "bind_seq",
@@ -557,6 +602,10 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                          "pf_cpu", "pf_mem", "n_launched", "overflow",
                          "scale_out_nodes"]
                 pend = S["pend"]
+            if resched:
+                keys += ["pend", "ev_pod", "ev_node", "ev_bind_cycle",
+                         "ev_bind_seq", "ev_pend", "ev_cycle", "ev_seq",
+                         "ev_n", "failed", "moved", *RESCHED_COUNTERS]
 
             def cand_of(c):
                 return (arrived & ~c["bound"] & ~c["attempted"]
@@ -623,7 +672,14 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                                _INF_BITS)
                 c["done_t"] = _row_put(c["done_t"], p, td, do)
                 if autoscale:
-                    c = scale_out(k, p, pc, pm, blk, c)
+                    failed = blk
+                    if resched:
+                        with jax.named_scope("reschedule"):
+                            c, failed, moved = reschedule(t, k, p, pc, pm,
+                                                          blk, c)
+                        c["failed"] = c["failed"] + failed.astype(jnp.int32)
+                        c["moved"] = c["moved"] + moved.astype(jnp.int32)
+                    c = scale_out(k, p, pc, pm, failed, c)
                 c["seq_ctr"] = c["seq_ctr"] + do.astype(jnp.int32)
                 c["placed"] = c["placed"] + do.astype(jnp.int32)
                 c["blocked"] = c["blocked"] + blk.astype(jnp.int32)
@@ -633,12 +689,18 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 return c
 
             zeros_i = jnp.zeros(L, jnp.int32)
+            tally = {"placed": zeros_i, "blocked": zeros_i}
+            if resched:
+                tally.update(failed=zeros_i, moved=zeros_i)
             with jax.named_scope("wave"):
                 S = _while(cond, body, {**S, "attempted": jnp.zeros_like(
-                    S["bound"]), "placed": zeros_i, "blocked": zeros_i}, keys)
+                    S["bound"]), **tally}, keys)
             placed, blocked = S.pop("placed"), S.pop("blocked")
             S.pop("attempted")
-            S["scale_outs"] = S["scale_outs"] + blocked
+            # Scale-out requests: the blocked pods, or with Alg. 3 those
+            # whose plan failed.
+            failed = S.pop("failed") if resched else blocked
+            S["scale_outs"] = S["scale_outs"] + failed
             if autoscale:
                 with jax.named_scope("scale_in"):
                     S = scale_in(t, k, S, active & (blocked == 0))
@@ -660,11 +722,14 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 S["done_time"] = jnp.where(done_b, t, S["done_time"])
                 S["done_is_cycle"] = S["done_is_cycle"] | done_b
                 active = active & ~done_b
-                # _permanently_stuck: everything arrived, nothing placed,
-                # something blocked, nothing running, nothing booting.
+                # _permanently_stuck: everything arrived, nothing placed or
+                # rescheduled, a scale-out asked for, nothing running,
+                # nothing booting.
                 stuck_now = (active & all_arrived & (placed == 0)
-                             & (blocked > 0) & ~running_batch
+                             & (failed > 0) & ~running_batch
                              & pending_after)
+                if resched:
+                    stuck_now = stuck_now & (S.pop("moved") == 0)
                 if autoscale:
                     stuck_now = stuck_now & ~(
                         S["nstate"] == NODE_BOOTING).any(axis=1)
@@ -682,16 +747,191 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 S["active"] = active
             return S
 
+        def largest(rem, mem_key):
+            """Each lane's pod of ``rem`` with the most memory, ties to the
+            later row: the serial sort of movers by (memory, uid),
+            descending."""
+            mk = jnp.where(rem, mem_key, -1)
+            top = rem & (mk == mk.max(axis=1, keepdims=True))
+            return P - 1 - jnp.argmax(top[:, ::-1], axis=1)
+
+        def shadow_fit(others, sh_cpu, sh_mem, q, act):
+            """Shadow best-fit of each lane's pod ``q`` (in the lanes
+            ``act``) onto the free room ``sh_cpu`` / ``sh_mem`` of the
+            nodes ``others``: the fitting node with the least free memory,
+            the first on ties, loses the pod's request.  Returns where the
+            pod found room, its memory and the shadow after it.  Alg. 3 and
+            Alg. 6 place their movers by it."""
+            qc, qm = _row_pick(cpu, q), _row_pick(mem, q)
+            f = (others & (sh_cpu >= qc[:, None])
+                 & (_order_key(f64_add(sh_mem, _EPS_BITS))
+                    >= _order_key(qm)[:, None]))
+            b = masked_argmin(_order_key(sh_mem), f)
+            put = act & f.any(axis=1)
+            sc, sm = _row_pick(sh_cpu, b), _row_pick(sh_mem, b)
+            sh_cpu = _row_put(sh_cpu, b, sc - qc, put)
+            sh_mem = _row_put(sh_mem, b, f64_add(sm, qm ^ _SIGN), put)
+            return put, qm, sh_cpu, sh_mem
+
+        def evict(t, k, c, node, out, pick, bind_seq, bind_cycle, steps):
+            """Evict ``out`` (pods on ``node``), one a lane a step in the
+            order ``pick(out)`` gives: the node's usage drops one pod at a
+            time, and each pod re-pends now, its closed incarnation
+            recorded; with Alg. 3 each eviction also takes the next number
+            of the bind sequence (``ev_seq``).  ``steps`` names the counter
+            of the iterations."""
+            def cond(s):
+                return s[0].any()
+
+            def body(s):
+                out, c = s
+                c = dict(c)
+                act = out.any(axis=1)
+                q = pick(out)
+                old_c = _row_pick(c["used_cpu"], node)
+                old_m = _row_pick(c["used_mem"], node)
+                c["used_cpu"] = _row_put(c["used_cpu"], node,
+                                         old_c - _row_pick(cpu, q), act)
+                c["used_mem"] = _row_put(
+                    c["used_mem"], node,
+                    f64_add(old_m, _row_pick(mem, q) ^ _SIGN), act)
+                dec = act.astype(jnp.int32)
+                c["pcount"] = _row_add(c["pcount"], node, -1, act)
+                c["nmove"] = _row_add(c["nmove"], node, -1, act)
+                slot = c["ev_n"]
+                rec = act & (slot < X)
+                sl = jnp.minimum(slot, X - 1)
+                recs = (("ev_pod", q), ("ev_node", node),
+                        ("ev_bind_cycle", _row_pick(bind_cycle, q)),
+                        ("ev_bind_seq", _row_pick(bind_seq, q)),
+                        ("ev_pend", _row_pick(c["pend"], q)),
+                        ("ev_cycle", k))
+                if resched:
+                    recs += (("ev_seq", c["seq_ctr"]),)
+                for key, val in recs:
+                    c[key] = _row_put(c[key], sl, val, rec)
+                c["overflow"] = c["overflow"] | (act & (slot >= X))
+                c["ev_n"] = slot + dec
+                if resched:
+                    c["seq_ctr"] = c["seq_ctr"] + dec
+                c["bound"] = _row_put(c["bound"], q, False, act)
+                c["pend"] = _row_put(c["pend"], q, t, act)
+                c[steps] = c[steps] + 1
+                return _row_put(out, q, False, act), c
+
+            return lax.while_loop(cond, body, (out, c))[1]
+
+        def reschedule(t, k, p, pc, pm, blk, c):
+            """Alg. 3, the non-binding rescheduler, for each lane's blocked
+            pod ``p`` (in the lanes ``blk``).  A pod pending for less than
+            its lane's ``max_age`` waits.  Else the candidate victims are
+            the READY nodes with room for its CPU that hold moveable pods,
+            most free memory first, ties to the highest id rank (the
+            pseudocode's descending order).  A victim's moveable pods are
+            tried largest first, each placed by :func:`shadow_fit` on the
+            other READY nodes from the call's free room, or skipped where
+            none holds it, until they free ``needed = mem - free_mem`` of
+            the victim, less 1e-9.  The first victim that frees that much
+            with at least one mover is the plan: its movers are evicted in
+            plan order and sit out the rest of the wave.  One loop walks
+            victims and movers alike, one mover a lane a step.  Returns
+            ``c``, the lanes whose call FAILED (they ask the autoscaler)
+            and those that carried out a plan."""
+            ready = c["nstate"] == NODE_READY
+            age = f64_add(t, _row_pick(c["pend"], p) ^ _SIGN)
+            gated = blk & (_order_key(age) >= _order_key(max_age))
+            free_cpu = ac - c["used_cpu"]
+            free_mem = f64_add(am, c["used_mem"] ^ _SIGN)
+            cands = (gated[:, None] & ready & (free_cpu >= pc)
+                     & (c["nmove"] > 0))
+            movers = c["bound"] & valid & moveable
+            bind_node = c["bind_node"]
+            free_key = _order_key(free_mem)
+            mem_key = _order_key(mem)
+            node_ix = jnp.arange(n_pad, dtype=jnp.int32)[None, :]
+            zero = jnp.zeros(L, mem.dtype)
+            s = {"live": gated & cands.any(axis=1),
+                 "open": jnp.zeros(L, bool), "found": jnp.zeros(L, bool),
+                 "cands": cands, "victim": jnp.zeros(L, jnp.int32),
+                 "rem": jnp.zeros_like(movers), "sel": jnp.zeros_like(movers),
+                 "sh_cpu": free_cpu, "sh_mem": free_mem, "freed": zero,
+                 "lim": zero, "steps": c["resched_steps"]}
+
+            def cond(s):
+                return s["live"].any()
+
+            def body(s):
+                s = dict(s)
+                live, cands = s["live"], s["cands"]
+                # A lane with no victim open opens its next candidate, on
+                # a fresh shadow, with nothing freed yet.
+                opening = live & ~s["open"]
+                ck = jnp.where(cands, free_key, _KEY_MIN)
+                top = cands & (ck == ck.max(axis=1, keepdims=True))
+                v = (n_pad - 1 - jnp.argmax(top[:, ::-1], axis=1)).astype(
+                    jnp.int32)
+                s["cands"] = _row_put(cands, v, False, opening)
+                o2 = opening[:, None]
+                victim = jnp.where(opening, v, s["victim"])
+                rem = jnp.where(o2, movers & (bind_node == v[:, None]),
+                                s["rem"])
+                sel = s["sel"] & ~o2
+                sh_cpu = jnp.where(o2, free_cpu, s["sh_cpu"])
+                sh_mem = jnp.where(o2, free_mem, s["sh_mem"])
+                needed = f64_add(pm[:, 0], _row_pick(free_mem, v) ^ _SIGN)
+                freed = jnp.where(opening, 0, s["freed"])
+                lim = jnp.where(opening, f64_add(needed, _EPS_BITS ^ _SIGN),
+                                s["lim"])
+                # One mover while the victim frees too little; a mover
+                # with no room elsewhere is skipped.
+                act = (live & (_order_key(freed) < _order_key(lim))
+                       & rem.any(axis=1))
+                q = largest(rem, mem_key)
+                others = ready & (node_ix != victim[:, None])
+                put, qm, sh_cpu, sh_mem = shadow_fit(others, sh_cpu, sh_mem,
+                                                     q, act)
+                sel = _row_put(sel, q, True, put)
+                freed = jnp.where(put, f64_add(freed, qm), freed)
+                rem = _row_put(rem, q, False, act)
+                enough = _order_key(freed) >= _order_key(lim)
+                close = live & (enough | ~rem.any(axis=1))
+                ok = close & enough & sel.any(axis=1)
+                s.update(found=s["found"] | ok, open=live & ~close,
+                         live=live & ~ok & (~close | s["cands"].any(axis=1)),
+                         victim=victim, rem=rem, sel=sel, sh_cpu=sh_cpu,
+                         sh_mem=sh_mem, freed=freed, lim=lim,
+                         steps=s["steps"] + 1)
+                return s
+
+            s = _while(cond, body, s, list(s))
+            found = s["found"]
+            out = s["sel"] & found[:, None]
+            c["resched_steps"] = s["steps"]
+            keys = ["used_cpu", "used_mem", "pcount", "nmove", "bound", "pend",
+                    "ev_pod", "ev_node", "ev_bind_cycle", "ev_bind_seq",
+                    "ev_pend", "ev_cycle", "ev_seq", "ev_n", "overflow",
+                    "seq_ctr", "resched_steps"]
+            c.update(evict(t, k, {key: c[key] for key in keys}, s["victim"],
+                           out, lambda o: largest(o, mem_key), c["bind_seq"],
+                           c["bind_cycle"], "resched_steps"))
+            c["attempted"] = c["attempted"] | out
+            for key, n in (("resched_waits", blk & ~gated),
+                           ("resched_plans", gated), ("resched_done", found),
+                           ("resched_evictions", out)):
+                c[key] = c[key] + n.sum(dtype=jnp.int64)
+            return c, gated & ~found, found
+
         def scale_in(t, k, S, go):
             """Alg. 6 after a fully successful cycle (``go``): remove the
             empty autoscaled READY/TAINTED nodes, then visit the non-empty
             autoscaled READY nodes in launch order.  A node whose pods are
             all moveable, or whose moveable pods share it with batch pods,
             is consolidated when every moveable pod fits elsewhere by
-            best-fit on a shadow of the other READY nodes (pods by memory,
-            then row, descending): its moveable pods are evicted in bind
-            order and re-pend now; the first kind of node is removed, the
-            second tainted.  Later candidates see the earlier changes."""
+            :func:`shadow_fit` on a shadow of the other READY nodes (pods by
+            memory, then row, descending): its moveable pods are evicted in
+            bind order and re-pend now; the first kind of node is removed,
+            the second tainted.  Later candidates see the earlier
+            changes."""
             nstate = S["nstate"]
             step1 = (go[:, None] & autoscaled & (S["pcount"] == 0)
                      & ((nstate == NODE_READY) | (nstate == NODE_TAINTED)))
@@ -715,6 +955,8 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                     "ev_node", "ev_bind_cycle", "ev_bind_seq", "ev_pend",
                     "ev_cycle", "ev_n", "overflow", "scale_ins",
                     "scale_in_nodes", "scale_in_steps"]
+            if resched:
+                keys += ["ev_seq", "seq_ctr"]
             bind_node, bind_seq = S["bind_node"], S["bind_seq"]
             bind_cycle, nbatch = S["bind_cycle"], S["nbatch"]
             mem_key = _order_key(mem)
@@ -732,19 +974,9 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 def body(s):
                     rem, sh_cpu, sh_mem, ok, steps = s
                     act = rem.any(axis=1) & ok
-                    mk = jnp.where(rem, mem_key, -1)
-                    top = rem & (mk == mk.max(axis=1, keepdims=True))
-                    q = P - 1 - jnp.argmax(top[:, ::-1], axis=1)
-                    qc, qm = _row_pick(cpu, q), _row_pick(mem, q)
-                    f = (others & (sh_cpu >= qc[:, None])
-                         & (_order_key(f64_add(sh_mem, _EPS_BITS))
-                            >= _order_key(qm)[:, None]))
-                    b = masked_argmin(_order_key(sh_mem), f)
-                    put = act & f.any(axis=1)
-                    sc, sm = _row_pick(sh_cpu, b), _row_pick(sh_mem, b)
-                    sh_cpu = _row_put(sh_cpu, b, sc - qc, put)
-                    sh_mem = _row_put(sh_mem, b, f64_add(sm, qm ^ _SIGN),
-                                      put)
+                    q = largest(rem, mem_key)
+                    put, _, sh_cpu, sh_mem = shadow_fit(others, sh_cpu,
+                                                        sh_mem, q, act)
                     ok = ok & ~(act & ~put)
                     rem = _row_put(rem, q, False, act)
                     return rem, sh_cpu, sh_mem, ok, steps + 1
@@ -756,47 +988,8 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 c["scale_in_steps"] = s[4]
                 return s[3]
 
-            def evict(c, node, out):
-                """Evict ``out`` (pods on ``node``) in bind order: the
-                node's usage drops one pod at a time, and each pod re-pends
-                now, its closed incarnation recorded."""
-                def cond(s):
-                    return s[0].any()
-
-                def body(s):
-                    out, c = s
-                    c = dict(c)
-                    act = out.any(axis=1)
-                    q = jnp.argmin(jnp.where(out, bind_seq, _SEQ_INF),
-                                   axis=1)
-                    old_c = _row_pick(c["used_cpu"], node)
-                    old_m = _row_pick(c["used_mem"], node)
-                    c["used_cpu"] = _row_put(c["used_cpu"], node,
-                                             old_c - _row_pick(cpu, q), act)
-                    c["used_mem"] = _row_put(
-                        c["used_mem"], node,
-                        f64_add(old_m, _row_pick(mem, q) ^ _SIGN), act)
-                    dec = act.astype(jnp.int32)
-                    c["pcount"] = _row_add(c["pcount"], node, -1, act)
-                    c["nmove"] = _row_add(c["nmove"], node, -1, act)
-                    slot = c["ev_n"]
-                    rec = act & (slot < X)
-                    sl = jnp.minimum(slot, X - 1)
-                    for key, val in (("ev_pod", q), ("ev_node", node),
-                                     ("ev_bind_cycle",
-                                      _row_pick(bind_cycle, q)),
-                                     ("ev_bind_seq", _row_pick(bind_seq, q)),
-                                     ("ev_pend", _row_pick(c["pend"], q)),
-                                     ("ev_cycle", k)):
-                        c[key] = _row_put(c[key], sl, val, rec)
-                    c["overflow"] = c["overflow"] | (act & (slot >= X))
-                    c["ev_n"] = slot + dec
-                    c["bound"] = _row_put(c["bound"], q, False, act)
-                    c["pend"] = _row_put(c["pend"], q, t, act)
-                    c["scale_in_steps"] = c["scale_in_steps"] + 1
-                    return _row_put(out, q, False, act), c
-
-                return lax.while_loop(cond, body, (out, c))[1]
+            def in_bind_order(out):
+                return jnp.argmin(jnp.where(out, bind_seq, _SEQ_INF), axis=1)
 
             def cond(s):
                 return s[0].any()
@@ -814,7 +1007,8 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 movers = ((only | mixed)[:, None] & c["bound"] & valid
                           & moveable & (bind_node == node[:, None]))
                 ok = placeable(c, node, movers, only | mixed)
-                c = evict(c, node, movers & ok[:, None])
+                c = evict(t, k, c, node, movers & ok[:, None], in_bind_order,
+                          bind_seq, bind_cycle, "scale_in_steps")
                 # ``ok`` only stays True in lanes with a candidate.
                 c["nstate"] = _row_put(
                     c["nstate"], node,
@@ -914,6 +1108,10 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                 scale_in_nodes=jnp.zeros((), jnp.int64),
                 active_node_cycles=jnp.zeros((), jnp.int64),
                 scale_in_steps=jnp.zeros((), jnp.int64))
+        if resched:
+            S["ev_seq"] = jnp.zeros((L, X), jnp.int32)
+            S.update({key: jnp.zeros((), jnp.int64)
+                      for key in RESCHED_COUNTERS})
         S = _while(cycle_cond, cycle_body, S, list(S))
         out = {
             "bound": S["bound"], "done_committed": S["done_c"],
@@ -935,18 +1133,24 @@ def _program_factory(sched: str, n_pad: int, autoscale: bool = False):
                         "ev_pend", "ev_cycle", "ev_n", "overflow",
                         "scale_ins") + FLEET_COUNTERS:
                 out[key] = S[key]
+        if resched:
+            for key in ("ev_seq",) + RESCHED_COUNTERS:
+                out[key] = S[key]
         return out
 
     name = "lane_program_" + sched.replace("-", "_")
     if autoscale:
         name += "_autoscaled"
+    if resched:
+        name += "_resched"
     run.__name__ = run.__qualname__ = name
     return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_cache(sched: str, n_pad: int, autoscale: bool = False):
-    return _program_factory(sched, n_pad, autoscale)
+def _jit_cache(sched: str, n_pad: int, autoscale: bool = False,
+               resched: bool = False):
+    return _program_factory(sched, n_pad, autoscale, resched)
 
 
 def program_args(batch: LaneBatch) -> tuple:
@@ -956,6 +1160,8 @@ def program_args(batch: LaneBatch) -> tuple:
             batch.n_nodes, batch.alloc_cpu, f64_bits(batch.alloc_mem))
     if batch.autoscale:
         args += (batch.moveable, batch.boot_cycles)
+    if batch.resched:
+        args += (f64_bits(batch.max_age),)
     return args
 
 
@@ -986,7 +1192,8 @@ def run_lane_batch(batch: LaneBatch) -> dict:
     span = PROFILER.span
     with jax.enable_x64(True):
         with span("lanes.dispatch"):
-            run = _jit_cache(batch.scheduler, batch.n_pad, batch.autoscale)
+            run = _jit_cache(batch.scheduler, batch.n_pad, batch.autoscale,
+                             batch.resched)
             dev = run(*program_args(batch))
         with span("lanes.wait"):
             jax.block_until_ready(dev)

@@ -212,9 +212,10 @@ def run_cells(cells: Sequence[CellSpec], workers=1,
     `CellError` instead of hanging the remaining futures.
 
     ``workers="lanes"`` evaluates the list on the many-world lane engine
-    (`repro.manyworld`): cells with no rescheduler on a static fleet or
-    under the binding autoscaler run batched in one JAX program per
-    bucket, anything outside that envelope falls back
+    (`repro.manyworld`): cells with no rescheduler on a static fleet, and
+    cells under the binding autoscaler with no rescheduler or with Alg. 3
+    (the non-binding one), run batched in one JAX program per bucket,
+    anything outside that envelope falls back
     to the serial ``run_cell`` — same rows, same order, bit-identical
     metrics (``wall_s`` becomes the lane's share of its batch).
 

@@ -59,32 +59,36 @@ def _sds(tree, sharding):
 
 @pytest.fixture(scope="module")
 def lane_program(one_chip):
-    """``get(sched, autoscale)``: the lane program compiled for one v5e
-    chip, once per module.  Static: 1024 lanes x 2048 pods x 64 nodes;
-    autoscaled: the policy-search population of its benchmark cell, 4096
-    lanes x 64 pods x 64 node records."""
+    """``get(sched, autoscale, resched)``: the lane program compiled for
+    one v5e chip, once per module.  Static: 1024 lanes x 2048 pods x 64
+    nodes; autoscaled, with or without Alg. 3: the policy-search
+    population of its benchmark cells, 4096 lanes x 64 pods x 64 node
+    records."""
     cache = {}
 
-    def get(sched, autoscale=False):
-        if (sched, autoscale) not in cache:
+    def get(sched, autoscale=False, resched=False):
+        key = (sched, autoscale, resched)
+        if key not in cache:
             lane = {"arrival_t": np.zeros(1), "cpu_m": np.zeros(1),
                     "mem_mb": np.zeros(1), "duration_s": np.zeros(1),
                     "is_batch": np.ones(1, bool),
                     "moveable": np.zeros(1, bool),
                     "n_nodes": 1 if autoscale else NODES, "alloc_cpu": 940,
-                    "alloc_mem": 3584.0, "boot_cycles": 5}
+                    "alloc_mem": 3584.0, "boot_cycles": 5,
+                    "max_pod_age_s": 60.0}
             shape = (4096, 64) if autoscale else (LANES, PODS)
             tiny = ml.stack_lanes([lane], sched,
-                                  node_pad=64 if autoscale else None)
+                                  node_pad=64 if autoscale else None,
+                                  resched=resched)
             with jax.enable_x64(True):
                 args = [jax.ShapeDtypeStruct(shape if a.ndim == 2
                                              else shape[:1], a.dtype,
                                              sharding=one_chip)
                         for a in ml.program_args(tiny)]
-                cache[sched, autoscale] = ml._program_factory(
+                cache[key] = ml._program_factory(
                     sched, 64 if autoscale else NODES,
-                    autoscale).lower(*args).compile()
-        return cache[sched, autoscale]
+                    autoscale, resched).lower(*args).compile()
+        return cache[key]
 
     return get
 
@@ -107,11 +111,21 @@ def test_autoscaled_lane_program_compiles_for_v5e(lane_program):
     assert "f64[" not in compiled.as_text()      # no float64 operand
 
 
+def test_resched_lane_program_compiles_for_v5e(lane_program):
+    """The autoscaled lane program with Alg. 3 in its wave, at the same
+    population: int64 bit patterns only, and one more named program."""
+    compiled = lane_program("best-fit", True, True)
+    _fits_one_chip(compiled)
+    text = compiled.as_text()
+    assert "f64[" not in text                    # no float64 operand
+    assert "lane_program_best_fit_autoscaled_resched" in text
+
+
 _COMP_HEAD = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
 _INDEXED = re.compile(r" (scatter|gather)\(%")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
-STEP_LOOPS = ("/wave/", "/completions/", "/scale_in/")
+STEP_LOOPS = ("/wave/", "/completions/", "/reschedule/", "/scale_in/")
 
 
 def indexed_ops(hlo: str):
@@ -148,20 +162,79 @@ def indexed_ops(hlo: str):
             for m in [_INDEXED.search(line)] if m]
 
 
-@pytest.mark.parametrize("sched,autoscale", [
-    ("best-fit", False), ("worst-fit", False), ("best-fit", True)])
+@pytest.mark.parametrize("sched,autoscale,resched", [
+    ("best-fit", False, False), ("worst-fit", False, False),
+    ("best-fit", True, False), ("best-fit", True, True)])
 def test_lane_step_loops_have_no_scatter_or_gather(lane_program, sched,
-                                                   autoscale):
-    """Inside the step loops (the completions, the wave with its
-    scale-out, Alg. 6) a lane reads and writes its row element by a
+                                                   autoscale, resched):
+    """Inside the step loops (the completions, the wave with Alg. 3 and
+    the scale-out, Alg. 6) a lane reads and writes its row element by a
     one-hot select: a TPU scatter or gather with one index per lane runs
     serially over the lanes."""
-    ops = indexed_ops(lane_program(sched, autoscale).as_text())
+    ops = indexed_ops(lane_program(sched, autoscale, resched).as_text())
     in_loops = [(op, names) for op, names in ops
                 if any(s in n for n in names for s in STEP_LOOPS)]
     assert not in_loops, in_loops
     # Nor once a cycle: a joining node frees its pods by a one-hot too.
     assert not ops, ops
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = \S+ ([\w\-]+)\(")
+
+#: Instructions of each program without Alg. 3, by opcode, in its
+#: v5e-compiled HLO text (fused computations included), as the program
+#: compiled before Alg. 3 was added to the lane program: adding a program
+#: leaves the others as they were.
+OP_COUNTS = {
+    ("best-fit", False): dict(
+        add=106, bitcast=13, broadcast=301, compare=336, constant=224,
+        convert=17, copy=19, fusion=17, iota=16, maximum=1, not_=17,
+        or_=165, parameter=380, reduce=17, select=302, subtract=41, xor=4,
+        **{"and": 213, "bitcast-convert": 106, "copy-done": 54,
+           "count-leading-zeros": 10, "custom-call": 24,
+           "dynamic-slice": 1, "get-tuple-element": 321, "shift-left": 80,
+           "shift-right-arithmetic": 45, "shift-right-logical": 40,
+           "slice-done": 24}),
+    ("worst-fit", False): dict(
+        add=107, bitcast=13, broadcast=301, compare=337, constant=224,
+        convert=17, copy=19, fusion=17, iota=16, maximum=1, not_=17,
+        or_=165, parameter=380, reduce=17, select=303, subtract=43, xor=4,
+        **{"and": 213, "bitcast-convert": 106, "copy-done": 54,
+           "count-leading-zeros": 10, "custom-call": 24,
+           "dynamic-slice": 1, "get-tuple-element": 321, "shift-left": 80,
+           "shift-right-arithmetic": 45, "shift-right-logical": 40,
+           "slice-done": 24}),
+    ("best-fit", True): dict(
+        add=256, bitcast=29, broadcast=740, compare=826, constant=537,
+        convert=45, copy=82, fusion=40, iota=44, maximum=7, minimum=2,
+        not_=26, or_=387, parameter=881, reduce=40, reverse=1, select=747,
+        subtract=107, xor=12,
+        **{"and": 482, "bitcast-convert": 252, "copy-done": 62,
+           "count-leading-zeros": 24, "custom-call": 51,
+           "dynamic-slice": 1, "get-tuple-element": 735, "shift-left": 190,
+           "shift-right-arithmetic": 109, "shift-right-logical": 96,
+           "slice-done": 108}),
+}
+
+
+def op_counts(hlo: str) -> dict:
+    """Instructions of compiled HLO text by opcode."""
+    counts = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            counts[m.group(1)] = counts.get(m.group(1), 0) + 1
+    return counts
+
+
+@pytest.mark.parametrize("sched,autoscale", sorted(OP_COUNTS))
+def test_programs_without_alg3_keep_their_op_counts(lane_program, sched,
+                                                    autoscale):
+    """The static and autoscaled programs compile to the instructions
+    they had before Alg. 3 shared their code: its stage traces only into
+    its own program."""
+    want = {op.rstrip("_"): n for op, n in OP_COUNTS[sched, autoscale].items()}
+    assert op_counts(lane_program(sched, autoscale).as_text()) == want
 
 
 def test_indexed_ops_finds_a_scatter_in_a_step_loop(one_chip):

@@ -192,6 +192,9 @@ class TestEvaluatorRows:
         assert not lane_eligible(CellSpec(**{**base, "autoscaler": "binding",
                                              "rescheduler": "binding"}))
         assert not lane_eligible(CellSpec(**{**base, "rescheduler": "non-binding"}))
+        # Alg. 3 runs as lanes under the binding autoscaler.
+        assert lane_eligible(CellSpec(**{**base, "autoscaler": "binding",
+                                         "rescheduler": "non-binding"}))
         assert not lane_eligible(CellSpec(**{**base, "engine": "object"}))
         assert not lane_eligible(
             CellSpec(**{**base, "scenario": "zone-outage", "chaos": True}))
@@ -1151,3 +1154,190 @@ class TestAutoscaledLanes:
         assert names == sorted(names)
         assert list(index_of[seq_of]) == list(range(16))
         assert seq_of[:3].tolist() == [0, 1, 10]
+
+
+# Hand-built lanes for Alg. 3 (node-0 .. node-<n-1> static m2.small
+# workers, 3584 MB): (static nodes, pods as (arrival, cpu, mem, batch
+# duration or None for a moveable service)).
+RESCHED_LANES = [
+    # node-0 holds S1 and S2 (383.6 MB free), node-1 and node-2 one batch
+    # pod each.  P blocks at 10: Alg. 3 skips S1 (no room elsewhere) and
+    # moves S2's 1200.3 MB to node-2's shadow, enough for P's 1016.9 MB
+    # shortfall; S2 is evicted, and Q, later in the same wave, binds into
+    # the room it left.  Node-0's usage after the cycle depends on that
+    # order ((u - S2) + Q != (u + Q) - S2 in float64).
+    (3, [(0.0, 100, 2000.1, None), (0.0, 100, 1200.3, None),
+         (0.0, 100, 2400.7, 600.0), (0.0, 100, 2300.2, 600.0),
+         (1.0, 100, 1400.5, 300.0), (2.0, 100, 1500.9, 300.0)]),
+    # node-0 and node-1 hold the same two services each, 584 MB free: the
+    # tie between the victims goes to node-1, the name that sorts last,
+    # whose 800 MB service moves to node-2 for P.
+    (3, [(0.0, 100, 2200.0, None), (0.0, 100, 800.0, None),
+         (0.0, 100, 2200.0, None), (0.0, 100, 800.0, None),
+         (0.0, 100, 2300.0, 600.0), (1.0, 100, 1350.0, 300.0)]),
+]
+MID_WAVE, TIED = range(len(RESCHED_LANES))
+
+
+def _resched_trace(seed, _n_jobs):
+    _nodes, pods = RESCHED_LANES[seed % len(RESCHED_LANES)]
+    specs = [PodSpec(f"p{i}", PodKind.SERVICE if dur is None
+                     else PodKind.BATCH, Resources(cpu, mem),
+                     duration_s=dur or 0.0, moveable=dur is None)
+             for i, (_t, cpu, mem, dur) in enumerate(pods)]
+    return TraceStore(specs, np.arange(len(pods)), [p[0] for p in pods],
+                      duration_s=[p[3] or 0.0 for p in pods],
+                      name="resched-corners")
+
+
+def _resched_cells():
+    """The hand-built lanes as cells with no age gate."""
+    register("resched-corners", _resched_trace, overwrite=True)
+    return [CellSpec(scenario="resched-corners", scheduler="best-fit",
+                     autoscaler="binding", rescheduler="non-binding",
+                     seed=i, engine="array", initial_workers=nodes,
+                     max_pod_age_s=0.0)
+            for i, (nodes, _pods) in enumerate(RESCHED_LANES)]
+
+
+def _resched_lane(i, age=0.0):
+    trace = _resched_trace(i, None)
+    return {**_lane_of(trace, RESCHED_LANES[i][0]), "boot_cycles": 5,
+            "max_pod_age_s": age}
+
+
+class TestReschedLanes:
+    """Alg. 3, the non-binding rescheduler, in the autoscaled lane
+    program: rows equal the serial engine's, which is the reference."""
+
+    @pytest.mark.parametrize("scen", ["paper-bursty", "paper-slow"])
+    @pytest.mark.parametrize("block", range(13))
+    def test_rows_bitwise_equal_serial(self, scen, block, monkeypatch):
+        """Eight seeded paper traces a call on the paper's chain with its
+        60 s age gate (208 traces over the blocks): every lane row equals
+        the serial ``run_cell`` row, field by field, and no cell ran
+        serially."""
+        cells = [CellSpec(scenario=scen, scheduler="best-fit",
+                          autoscaler="binding", rescheduler="non-binding",
+                          seed=8 * block + s, initial_workers=1)
+                 for s in range(8)]
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        rec = lane_calls(1)[0]
+        assert (rec["lanes"], rec["counts"]["lane_fallbacks"]) == (8, 0)
+        assert rec["counts"]["resched_plans"] > 0
+        _assert_rows_equal(serial, rows)
+
+    def test_rescheduled_lanes_equal_serial(self, monkeypatch):
+        """Seeded bursty traces in which Alg. 3 carries out plans, with no
+        age gate and from three workers, and other schedulers in the
+        wave: rows equal serial."""
+        cells = [CellSpec(scenario="paper-bursty", scheduler=sched,
+                          autoscaler="binding", rescheduler="non-binding",
+                          seed=s, initial_workers=3, max_pod_age_s=0.0)
+                 for s in range(4) for sched in ("best-fit", "worst-fit")]
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        assert sum(lane_calls(2)[i]["counts"]["resched_done"]
+                   for i in range(2)) > 0
+
+    def test_mid_wave_eviction_then_bind_in_the_freed_room(self,
+                                                         monkeypatch):
+        """The evicted service re-pends at 10 and sits out that wave; the
+        later pod Q binds into the room it freed in the same cycle, after
+        the eviction in the cycle's sequence; rows equal serial."""
+        lane = _resched_lane(MID_WAVE)
+        out = ml.run_lane_batch(ml.stack_lanes([lane], "best-fit",
+                                               node_pad=8, resched=True))
+        _seq, index_of = ml.node_layout(8)
+        assert int(out["ev_n"][0]) == 1 and int(out["resched_done"]) == 1
+        s2, q = 1, 5
+        assert (int(out["ev_pod"][0, 0]), int(out["ev_node"][0, 0]),
+                int(out["ev_cycle"][0, 0])) == (s2, index_of[0], 1)
+        assert (int(out["bind_node"][0, q]), int(out["bind_cycle"][0, q])
+                ) == (index_of[0], 1)
+        assert int(out["ev_seq"][0, 0]) < int(out["bind_seq"][0, q])
+        # The evicted service waits for the next cycle.
+        assert int(out["bind_cycle"][0, s2]) == 2
+        cells = _resched_cells()[MID_WAVE:MID_WAVE + 1]
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        assert serial[0]["evictions"] == 1
+
+    def test_tied_victims_go_to_the_last_name(self, monkeypatch):
+        """Two victims with the same free memory: Alg. 3 tries node-1
+        first (descending by free memory, then name), so its service
+        moves; rows equal serial."""
+        lane = _resched_lane(TIED)
+        out = ml.run_lane_batch(ml.stack_lanes([lane], "best-fit",
+                                               node_pad=8, resched=True))
+        _seq, index_of = ml.node_layout(8)
+        assert int(out["resched_done"]) >= 1
+        assert (int(out["ev_pod"][0, 0]), int(out["ev_node"][0, 0])) == (
+            3, index_of[1])
+        cells = _resched_cells()[TIED:TIED + 1]
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+
+    def test_per_lane_ages_share_one_bucket(self, monkeypatch):
+        """Age gates of 0, 60 and 240 s on the same traces run in one
+        bucket, one program, each lane with its own gate; the gate moves
+        the rows, and each equals its serial row."""
+        cells = [CellSpec(scenario="paper-bursty", scheduler="best-fit",
+                          autoscaler="binding", rescheduler="non-binding",
+                          seed=s, initial_workers=1, max_pod_age_s=age)
+                 for s in range(2) for age in (0.0, 60.0, 240.0)]
+        serial = run_cells(cells, workers=1)
+        with monkeypatch.context() as m:
+            _no_serial_runs(m)
+            rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        rec = lane_calls(1)[0]
+        assert (rec["lanes"], rec["buckets"]) == (len(cells), 1)
+        assert rec["counts"]["resched_waits"] > 0
+        for s in range(2):
+            by_age = [{f: r[f] for f in _RESULT_FIELDS}
+                      for r in rows[3 * s:3 * s + 3]]
+            assert by_age[0] != by_age[1] != by_age[2]
+
+    def test_lane_past_its_records_runs_serially(self, monkeypatch):
+        """Node records too few for a lane's launches: the lane stops on
+        the device and its row comes from the serial engine, counted."""
+        cells = [CellSpec(scenario="paper-bursty", scheduler="best-fit",
+                          autoscaler="binding", rescheduler="non-binding",
+                          seed=s, initial_workers=1) for s in range(3)]
+        serial = run_cells(cells, workers=1)
+        monkeypatch.setattr(ev, "_node_records", lambda cell, trace: 4)
+        rows = run_cells(cells, workers="lanes")
+        _assert_rows_equal(serial, rows)
+        assert lane_calls(1)[0]["counts"]["lane_fallbacks"] == 3
+
+    def test_resched_counters_on_one_lane(self):
+        """With no age gate every blocked pod plans: plans are the failed
+        plans (the scale-out requests) and the one carried out; with a
+        60 s gate the first blocked calls wait.  Only this program
+        returns the counts."""
+        run = {age: ml.run_lane_batch(ml.stack_lanes(
+            [_resched_lane(MID_WAVE, age)], "best-fit", node_pad=8,
+            resched=True)) for age in (0.0, 60.0)}
+        out = run[0.0]
+        assert int(out["resched_waits"]) == 0
+        assert (int(out["resched_done"]), int(out["resched_evictions"])
+                ) == (1, 1)
+        assert int(out["resched_plans"]) == int(out["scale_outs"][0]) + 1
+        assert int(out["resched_steps"]) >= 2
+        assert int(run[60.0]["resched_waits"]) > 0
+        plain = ml.run_lane_batch(ml.stack_lanes(
+            [_resched_lane(MID_WAVE)], "best-fit", node_pad=8))
+        assert not set(ml.RESCHED_COUNTERS + ("ev_seq",)) & set(plain)
